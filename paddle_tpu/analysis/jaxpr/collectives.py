@@ -16,8 +16,9 @@ value-dependent-control-flow depth. Three checks:
   trip count is static.
 * TPC203 — ppermute (src, dst) pairs must form a partial permutation of
   the axis: in-range, no duplicate source, no duplicate destination.
-  jax traces violations without complaint (verified on 0.4.37); the
-  chip hangs or silently drops data.
+  jax traces violations without complaint (the coll_bad_ppermute
+  fixture still traces on jax 0.9.0); the chip hangs or silently drops
+  data.
 
 ``pbroadcast`` and ``axis_index`` eqns are exempt from TPC202 (the
 ``_BLOCKING`` subset below): shard_map's replication rewrite inserts

@@ -48,32 +48,18 @@ from typing import Dict, List, Optional, Tuple
 from . import rules as R
 from .core import (FlatOp, Finding, PassContext, flatten, materialize,
                    mesh_axis_sizes)
-from .cost import (DEFAULT_DEVICE_KIND, _cost_op, CostRollup, hbm_bw,
-                   peak_flops, _lookup)
+from .cost import (DEFAULT_DEVICE_KIND, _cost_op, CostRollup,
+                   device_peaks, hbm_bw, peak_flops)
 from .liveness import _fmt_bytes
 
 __all__ = ["CommCostPass", "CommEstimate", "KindTraffic", "comm_kind",
-           "comm_rollup", "ICI_BYTES_PER_SEC", "ICI_LATENCY_S",
+           "comm_rollup", "ICI_LATENCY_S",
            "ICI_COLLECTIVE_OVERHEAD_S", "ici_bw", "ici_latency",
            "predicted_step_seconds", "collective_cost"]
 
-# ------------------------------------------------------------- ICI tables
+# ------------------------------------------------------------- ICI terms
 #
-# Per-chip AGGREGATE ICI bandwidth across all links (datasheet Gbps / 8).
-# Provenance (README "Program analysis" carries the same table):
-#   v4   — 3D torus, 6 links x 400 Gbps  = 2400 Gbps   = 300 GB/s
-#   v5e  — 2D torus, 4 links x 400 Gbps  = 1600 Gbps   = 200 GB/s
-#   v5p  — 3D torus, 6 links x 800 Gbps  = 4800 Gbps   = 600 GB/s
-#   v6e  — 2D torus, 4 links x 896 Gbps  = 3584 Gbps   = 448 GB/s
-ICI_BYTES_PER_SEC = {
-    "TPU v4": 300e9,
-    "TPU v5 lite": 200e9,
-    "TPU v5e": 200e9,
-    "TPU v5": 600e9,
-    "TPU v5p": 600e9,
-    "TPU v6 lite": 448e9,
-    "TPU v6e": 448e9,
-}
+# Per-chip aggregate ICI bandwidth is a column of cost.DEVICE_PEAKS.
 
 # per-step (per-hop) collective latency: ~1us on ICI across generations
 ICI_LATENCY_S = 1e-6
@@ -88,8 +74,7 @@ ICI_COLLECTIVE_OVERHEAD_S = 2e-6
 
 
 def ici_bw(device_or_kind) -> float:
-    kind = getattr(device_or_kind, "device_kind", device_or_kind) or ""
-    return _lookup(ICI_BYTES_PER_SEC, str(kind), 200e9)
+    return device_peaks(device_or_kind).ici_bytes_per_sec
 
 
 def ici_latency(device_or_kind) -> float:

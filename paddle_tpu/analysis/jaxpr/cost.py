@@ -10,8 +10,9 @@ convention ``bench.py``'s measured rooflines use via
 streams count their packed sizes and predicted-vs-measured divide by
 the same byte model.
 
-The device peak tables live HERE and bench.py imports them — one source
-of truth for "what the hardware allows" (ROADMAP north star).
+The device peak table lives HERE (``DEVICE_PEAKS``) and everything else —
+comm model, planner, profiler.mfu, bench.py — reads it: one source of
+truth for "what the hardware allows" (ROADMAP north star).
 """
 from __future__ import annotations
 
@@ -25,51 +26,64 @@ from . import rules as R
 from .liveness import _fmt_bytes
 
 __all__ = ["CostModelPass", "CostRollup", "rollup", "rollup_fn",
-           "PEAK_BF16_FLOPS", "HBM_BYTES_PER_SEC", "peak_flops", "hbm_bw",
-           "DEFAULT_DEVICE_KIND"]
+           "DevicePeaks", "DEVICE_PEAKS", "device_peaks", "peak_flops",
+           "hbm_bw", "DEFAULT_DEVICE_KIND"]
 
 # ---------------------------------------------------------------- devices
 
-PEAK_BF16_FLOPS = {
-    # per-chip dense bf16 peak
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5": 459e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
+@dataclass(frozen=True)
+class DevicePeaks:
+    bf16_flops: float          # per-chip dense bf16 peak
+    hbm_bytes_per_sec: float   # per-chip HBM bandwidth
+    ici_bytes_per_sec: float   # per-chip AGGREGATE ICI bandwidth, all links
+    hbm_capacity_bytes: int    # per-chip HBM (the liveness gate's budget)
+
+
+# THE table of per-chip peaks (datasheets), keyed by a prefix of
+# ``device.device_kind``. ICI provenance (Gbps / 8):
+#   v4   — 3D torus, 6 links x 400 Gbps  = 2400 Gbps   = 300 GB/s
+#   v5e  — 2D torus, 4 links x 400 Gbps  = 1600 Gbps   = 200 GB/s
+#   v5p  — 3D torus, 6 links x 800 Gbps  = 4800 Gbps   = 600 GB/s
+#   v6e  — 2D torus, 4 links x 896 Gbps  = 3584 Gbps   = 448 GB/s
+_V4 = DevicePeaks(275e12, 1.2e12, 300e9, 32 << 30)
+_V5E = DevicePeaks(197e12, 819e9, 200e9, 16 << 30)
+_V5P = DevicePeaks(459e12, 2.77e12, 600e9, 95 << 30)
+_V6E = DevicePeaks(918e12, 1.64e12, 448e9, 32 << 30)
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v4": _V4,
+    "TPU v5 lite": _V5E,
+    "TPU v5e": _V5E,
+    "TPU v5": _V5P,
+    "TPU v5p": _V5P,
+    "TPU v6 lite": _V6E,
+    "TPU v6e": _V6E,
 }
 
-HBM_BYTES_PER_SEC = {
-    # per-chip HBM bandwidth (datasheet)
-    "TPU v4": 1.2e12,
-    "TPU v5 lite": 819e9,
-    "TPU v5e": 819e9,
-    "TPU v5": 2.77e12,
-    "TPU v5p": 2.77e12,
-    "TPU v6 lite": 1.64e12,
-    "TPU v6e": 1.64e12,
-}
-
+# what static analysis prices when it is TOLD no target (tools/analyze_tpu,
+# plan_tpu). Never a stand-in for an attached device the table lacks.
 DEFAULT_DEVICE_KIND = "TPU v5e"
 
 
-def _lookup(table: Dict[str, float], kind: str, default: float) -> float:
-    for key, val in sorted(table.items(), key=lambda kv: -len(kv[0])):
+def device_peaks(device_or_kind) -> DevicePeaks:
+    """Peaks of a device (or a ``device_kind`` string), longest matching
+    prefix first. A kind the table does not hold is an error, not a
+    default: a rate divided by another chip's peak is not a utilisation."""
+    kind = str(getattr(device_or_kind, "device_kind", device_or_kind) or "")
+    for key in sorted(DEVICE_PEAKS, key=len, reverse=True):
         if kind.startswith(key):
-            return val
-    return default
+            return DEVICE_PEAKS[key]
+    raise ValueError(
+        f"unknown device kind {kind!r}: no peak rates for it in "
+        f"analysis.jaxpr.cost.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)}); "
+        "add a row with its datasheet source")
 
 
 def peak_flops(device_or_kind) -> float:
-    kind = getattr(device_or_kind, "device_kind", device_or_kind) or ""
-    return _lookup(PEAK_BF16_FLOPS, str(kind), 197e12)
+    return device_peaks(device_or_kind).bf16_flops
 
 
 def hbm_bw(device_or_kind) -> float:
-    kind = getattr(device_or_kind, "device_kind", device_or_kind) or ""
-    return _lookup(HBM_BYTES_PER_SEC, str(kind), 819e9)
+    return device_peaks(device_or_kind).hbm_bytes_per_sec
 
 
 # ---------------------------------------------------------------- rollup

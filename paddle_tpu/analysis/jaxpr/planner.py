@@ -44,12 +44,13 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from .comm import (ICI_COLLECTIVE_OVERHEAD_S, ICI_LATENCY_S, CommEstimate,
                    _merge as _merge_comm, collective_cost, comm_rollup,
                    ici_bw)
-from .cost import DEFAULT_DEVICE_KIND, _lookup, hbm_bw, peak_flops, rollup
+from .cost import (DEFAULT_DEVICE_KIND, device_peaks, hbm_bw, peak_flops,
+                   rollup)
 from .liveness import _fmt_bytes, estimate_memory
-from .sharding import normalize_names
+from .sharding import spec_to_names
 
 __all__ = ["PlanProblem", "Candidate", "PlanCost", "PlanReport",
-           "DEVICE_ALIASES", "HBM_CAPACITY_BYTES", "extract_problem",
+           "DEVICE_ALIASES", "extract_problem",
            "enumerate_candidates", "price_candidate", "audit_candidate",
            "plan_program", "spec_str"]
 
@@ -62,17 +63,6 @@ DEVICE_ALIASES = {
     "v6e": "TPU v6e",
 }
 
-# per-chip HBM capacity (datasheet GiB); the liveness gate's budget
-HBM_CAPACITY_BYTES = {
-    "TPU v4": 32 << 30,
-    "TPU v5 lite": 16 << 30,
-    "TPU v5e": 16 << 30,
-    "TPU v5": 95 << 30,
-    "TPU v5p": 95 << 30,
-    "TPU v6 lite": 32 << 30,
-    "TPU v6e": 32 << 30,
-}
-
 # operands below this size never gate a plan on replication (mirrors
 # the sharding pass's TPC501 floor)
 MIN_SHARDING_BYTES = 1 << 20
@@ -83,7 +73,7 @@ def device_kind(name: str) -> str:
 
 
 def hbm_capacity(kind: str) -> int:
-    return int(_lookup(HBM_CAPACITY_BYTES, kind, 16 << 30))
+    return device_peaks(kind).hbm_capacity_bytes
 
 
 # ------------------------------------------------------------- problem
@@ -269,7 +259,7 @@ def _walk_roles(jaxpr, env: Dict, problem: PlanProblem,
 
 
 def _pairs_to_dims(pairs, ndim: int) -> Tuple:
-    """normalize_names ((dim, axes), ...) pairs -> the planner's per-dim
+    """spec_to_names ((dim, axes), ...) pairs -> the planner's per-dim
     tuple form used by spec_str/_shard_factor."""
     entries: List[Tuple] = [() for _ in range(ndim)]
     for dim, axes in pairs:
@@ -282,20 +272,15 @@ def _harvest_oracle_specs(closed) -> Tuple[Optional[List], Optional[List],
                                            Optional[str]]:
     """Pull the hand-written in/out specs from the outermost shard_map
     of the mesh-N trace (the registry convention: one top-level region),
-    as normalize_names pairs aligned to that region's operands. Falls
+    as spec_to_names pairs aligned to that region's operands. Falls
     back to "gspmd" mode when the entry shards via sharding_constraint
     instead of shard_map."""
     jx = getattr(closed, "jaxpr", closed)
     saw_gspmd = False
     for eqn in jx.eqns:
         if eqn.primitive.name == "shard_map":
-            in_names = eqn.params.get("in_names")
-            out_names = eqn.params.get("out_names")
-            if in_names is None:
-                return None, None, None
-            ins = [normalize_names(n) for n in in_names]
-            outs = ([normalize_names(n) for n in out_names]
-                    if out_names is not None else None)
+            ins = [spec_to_names(s) for s in eqn.params["in_specs"]]
+            outs = [spec_to_names(s) for s in eqn.params["out_specs"]]
             return ins, outs, "shard_map"
         if eqn.primitive.name == "sharding_constraint":
             saw_gspmd = True

@@ -37,7 +37,7 @@ from .core import (Finding, PassContext, bytes_of_aval, eqn_source,
                    mesh_axis_sizes, subjaxprs, _raw)
 from .liveness import _fmt_bytes
 
-__all__ = ["ShardingPass", "normalize_names", "spec_to_names"]
+__all__ = ["ShardingPass", "spec_to_names"]
 
 # collectives whose operand sharding TPC503 inspects (jaxpr-level names)
 _GATHERING = {"all_gather", "pgather"}
@@ -57,21 +57,11 @@ def _axis_names_of(params: dict) -> Tuple[str, ...]:
     return tuple(n for n in names if isinstance(n, str))
 
 
-def normalize_names(names: Any) -> Tuple[Tuple[int, Tuple[str, ...]], ...]:
-    """Canonical form of a shard_map ``in_names``/``out_names`` entry
-    (``{dim: (axes,)}``): sorted, empty dims dropped — so two specs
-    compare equal iff they shard the same dims over the same axes."""
-    if not names:
-        return ()
-    try:
-        return tuple(sorted((int(d), tuple(ax)) for d, ax in names.items()
-                            if ax))
-    except Exception:
-        return ()
-
-
 def spec_to_names(spec) -> Tuple[Tuple[int, Tuple[str, ...]], ...]:
-    """PartitionSpec -> the same canonical form as :func:`normalize_names`."""
+    """Canonical form of a PartitionSpec (a shard_map ``in_specs`` /
+    ``out_specs`` entry, a sharding constraint's spec): ``((dim, axes),
+    ...)`` with unsharded dims dropped — so two specs compare equal iff
+    they shard the same dims over the same axes."""
     out = []
     try:
         for dim, entry in enumerate(tuple(spec)):
@@ -146,9 +136,9 @@ class ShardingPass:
     def _check_replication(self, eqn, binder: Dict[str, Optional[int]]):
         if _total(binder) <= 1:
             return  # a 1-device mesh replicates everything trivially
-        in_names = eqn.params.get("in_names") or ()
-        for pos, (var, names) in enumerate(zip(eqn.invars, in_names)):
-            if normalize_names(names):
+        in_specs = eqn.params.get("in_specs") or ()
+        for pos, (var, spec) in enumerate(zip(eqn.invars, in_specs)):
+            if spec_to_names(spec):
                 continue  # sharded on at least one dim
             nbytes = bytes_of_aval(getattr(var, "aval", None))
             if nbytes < self._floor:
@@ -210,11 +200,11 @@ class ShardingPass:
             if op.prim == "shard_map":
                 sizes = mesh_axis_sizes(op.params.get("mesh"))
                 key = _mesh_key(sizes)
-                in_names = op.params.get("in_names") or ()
-                for pos, (rec, names) in enumerate(zip(op.invars, in_names)):
+                in_specs = op.params.get("in_specs") or ()
+                for pos, (rec, spec) in enumerate(zip(op.invars, in_specs)):
                     if rec is None or rec.nbytes < self._floor:
                         continue
-                    want = normalize_names(names)
+                    want = spec_to_names(spec)
                     got = spec_of.get(rec.uid)
                     if got is not None and got[0] == key and got[1] != want:
                         self._finding(
@@ -228,9 +218,9 @@ class ShardingPass:
                             operand=pos, op_index=op.index,
                             produced=list(got[1]), consumed=list(want),
                             nbytes=rec.nbytes)
-                out_names = op.params.get("out_names") or ()
-                for rec, names in zip(op.outvars, out_names):
-                    spec_of[rec.uid] = (key, normalize_names(names))
+                out_specs = op.params.get("out_specs") or ()
+                for rec, spec in zip(op.outvars, out_specs):
+                    spec_of[rec.uid] = (key, spec_to_names(spec))
             elif op.prim == "sharding_constraint":
                 sh = op.params.get("sharding")
                 mesh = getattr(sh, "mesh", None)

@@ -30,8 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ...jax_compat import (axis_size as compat_axis_size,
-                           shard_map as compat_shard_map)
+from ...jax_compat import shard_map as compat_shard_map
 
 __all__ = [
     "ring_attention",
@@ -107,7 +106,7 @@ def _ring_drive(k, v, kv_pos, axis_name, attend, merge):
     attend; merge).  ``attend(k_c, v_c, kv_pos_c) -> partial`` and
     ``merge(acc, partial) -> acc`` define the per-impl math; jax transposes
     the ring for gradients."""
-    world = compat_axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % world) for i in range(world)]
     acc = attend(k, v, kv_pos)
 

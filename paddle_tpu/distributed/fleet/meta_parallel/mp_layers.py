@@ -29,37 +29,6 @@ __all__ = [
     "ParallelCrossEntropy", "parallel_cross_entropy_shardmap",
 ]
 
-# ParallelCrossEntropy must know whether it is being traced inside an
-# already-manual (shard_map) region to avoid a rejected nested shard_map.
-# Two detection generations, resolved ONCE at import (no per-call
-# hasattr):
-#
-# * jax >= 0.5-era: the public abstract-mesh API
-#   (jax.sharding.get_abstract_mesh + AxisType.Manual).
-# * jax 0.4.x (this image ships 0.4.37, which predates that API): the
-#   axis environment — inside a shard_map trace every mesh axis the map
-#   binds appears in ``jax._src.core.get_axis_env().axis_sizes``; outside
-#   it is empty. Narrow private probe, version-gated, and NOT silent: if
-#   neither generation's hook exists the import still hard-fails below,
-#   and a detection miss at run time is caught + counted by
-#   ParallelCrossEntropy's loud fallback path rather than swallowed.
-_NEW_MANUAL_API = (hasattr(jax.sharding, "get_abstract_mesh")
-                   and hasattr(jax.sharding, "AxisType"))
-if not _NEW_MANUAL_API:
-    try:
-        from jax._src.core import get_axis_env as _get_axis_env
-
-        _get_axis_env().axis_sizes  # probe the shape we rely on
-    except Exception as _e:  # pragma: no cover
-        raise ImportError(
-            "paddle_tpu.distributed.fleet.meta_parallel.mp_layers needs a "
-            "manual-region detection hook: jax.sharding.get_abstract_mesh/"
-            f"AxisType (jax >= 0.4.35-era) or the 0.4.x axis env (probe "
-            f"failed: {_e!r}; installed jax {jax.__version__}). "
-            "ParallelCrossEntropy cannot avoid nested shard_map — install "
-            "a compatible jax rather than risking a silent fallback to "
-            "full-vocab-logits cross entropy.") from _e
-
 
 class VocabParallelEmbedding(nn.Layer):
     def __init__(self, num_embeddings, embedding_dim, weight_attr=None,
@@ -194,14 +163,10 @@ class ParallelCrossEntropy(nn.Layer):
 
     @staticmethod
     def _inside_manual_region() -> bool:
-        if _NEW_MANUAL_API:
-            cur = jax.sharding.get_abstract_mesh()
-            return bool(cur is not None and getattr(cur, "axis_types", None)
-                        and jax.sharding.AxisType.Manual in cur.axis_types)
-        # jax 0.4.x: a nonempty axis env means some enclosing map
-        # (shard_map / pmap / named vmap) already binds named axes —
-        # a nested shard_map over the original mesh would be rejected
-        return bool(_get_axis_env().axis_sizes)
+        """True when traced inside an already-manual (shard_map) region,
+        where a nested shard_map over the original mesh is rejected."""
+        return (jax.sharding.AxisType.Manual
+                in jax.sharding.get_abstract_mesh().axis_types)
 
     @classmethod
     def reset_fallback_count(cls):

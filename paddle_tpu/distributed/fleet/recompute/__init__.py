@@ -25,11 +25,7 @@ from ....framework.tensor import Tensor, apply_op, pause_tape
 
 __all__ = ["recompute", "recompute_sequential", "POLICY_MAP"]
 
-_save_dots = None
-try:  # jax.checkpoint_policies names vary slightly across versions
-    _save_dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-except AttributeError:  # pragma: no cover
-    pass
+_save_dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
 
 #: recompute_granularity → jax.checkpoint policy (reference knob:
 #: DistributedStrategy.recompute_configs["granularity"]); "full" re-runs

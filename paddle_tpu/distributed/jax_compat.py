@@ -1,26 +1,13 @@
-"""Compatibility layer over the two generations of jax's manual-sharding
-API.
+"""Thin names over jax's manual-sharding API, kept where the call sites
+read better with them.
 
-The distributed stack targets the current public surface — ``jax.shard_map``
-with ``axis_names=``/``check_vma=`` and ``jax.sharding.get_abstract_mesh``
-— but the image this repo develops against ships jax 0.4.37, which
-predates all three. Every module that needs them goes through this shim so
-the generation dispatch lives in ONE place, resolved at import:
-
-* :func:`shard_map` — new API verbatim when present; on 0.4.x the
-  ``jax.experimental.shard_map`` original. Partial-manual (``axis_names``
-  a strict subset of the mesh) is intentionally degraded to FULLY manual
-  on 0.4.x: ``auto=`` there lowers ``axis_index`` to a PartitionId
-  instruction XLA rejects under SPMD partitioning, whereas fully-manual
-  binding of the extra axes only costs redundant per-rank compute on
-  axes the in/out specs never shard.
+* :func:`shard_map` — ``jax.shard_map`` with the two keywords this repo
+  uses everywhere: ``axis_names`` (the mesh axes the body handles
+  manually; None = all) and ``check`` (``check_vma``, off by default —
+  the bodies here mix replicated and per-shard values on purpose).
+* :func:`virtual_mesh` — a mesh for *tracing* at any device count.
 * :func:`ambient_mesh_axis_names` — axis names of the mesh surrounding
-  the current trace (abstract mesh on new jax, the ``with mesh:``
-  thread-resources context on 0.4.x), for "is this constraint legal
-  here" checks.
-
-If neither generation's hook exists the import of the USING module should
-fail loudly (see mp_layers) — this shim never silently no-ops.
+  the current trace, for "is this constraint legal here" checks.
 """
 from __future__ import annotations
 
@@ -28,51 +15,17 @@ from typing import Dict, Iterable, Optional, Tuple
 
 import jax
 
-__all__ = ["shard_map", "axis_size", "ambient_mesh_axis_names",
-           "distributed_is_initialized", "virtual_mesh",
-           "NEW_SHARD_MAP_API"]
-
-NEW_SHARD_MAP_API = hasattr(jax, "shard_map")
+__all__ = ["shard_map", "ambient_mesh_axis_names", "virtual_mesh"]
 
 
 def shard_map(f, mesh, in_specs, out_specs,
               axis_names: Optional[Iterable[str]] = None,
               check: bool = False):
-    """Generation-portable ``shard_map``.
-
-    ``axis_names``: the mesh axes the body handles manually (None = all).
-    ``check``: replication/VMA checking (``check_vma`` / ``check_rep``).
-    """
-    if NEW_SHARD_MAP_API:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a bound mapped axis (``jax.lax.axis_size`` on new
-    jax; the axis env on 0.4.x — same value, both are trace-time ints)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax._src.core import get_axis_env
-
-    return int(get_axis_env().axis_sizes[axis_name])
-
-
-def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` (added after 0.4.37); on 0.4.x
-    the same fact read from the distributed global state."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return bool(jax.distributed.is_initialized())
-    from jax._src import distributed as _distributed
-
-    return getattr(_distributed.global_state, "client", None) is not None
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 def virtual_mesh(axes: Dict[str, int]):
@@ -83,8 +36,8 @@ def virtual_mesh(axes: Dict[str, int]):
     a real slice) this returns a concrete ``Mesh`` — everything works:
     shard_map, NamedSharding constraints, actual execution. When the
     requested shape exceeds the local device count it falls back to
-    ``AbstractMesh`` (device-free; 0.4.37 already traces shard_map over
-    it), which supports ``jax.make_jaxpr`` analysis but not execution.
+    ``AbstractMesh`` (device-free), which supports ``jax.make_jaxpr``
+    analysis but not execution.
     """
     import numpy as np
 
@@ -100,24 +53,10 @@ def virtual_mesh(axes: Dict[str, int]):
                     tuple(axes.keys()))
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple((k, int(v)) for k, v in axes.items()))
-    except TypeError:
-        # newer ctor signature: AbstractMesh(shape_tuple, axis_names)
-        return AbstractMesh(tuple(int(v) for v in axes.values()),
-                            tuple(axes.keys()))
+    return AbstractMesh(tuple(int(v) for v in axes.values()),
+                        tuple(axes.keys()))
 
 
 def ambient_mesh_axis_names() -> Tuple[str, ...]:
     """Axis names of the mesh enclosing the current trace, or ``()``."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and getattr(m, "axis_names", None):
-            return tuple(m.axis_names)
-        return ()
-    from jax._src import mesh as _mesh_lib
-
-    m = _mesh_lib.thread_resources.env.physical_mesh
-    if m is None or m.empty:
-        return ()
-    return tuple(m.axis_names)
+    return tuple(jax.sharding.get_abstract_mesh().axis_names)

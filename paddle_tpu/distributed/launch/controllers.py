@@ -113,8 +113,11 @@ class ElasticSupervisor:
         self.max_restarts = max_restarts
         self.log_dir = log_dir
         # persistent XLA compilation cache shared across restarts (restart
-        # goodput, SURVEY.md §7 hard part 6): defaults next to the logs
-        if compile_cache_dir is None and log_dir:
+        # goodput, SURVEY.md §7 hard part 6): where the environment already
+        # places it, there; else next to the logs
+        if compile_cache_dir is None:
+            compile_cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not compile_cache_dir and log_dir:
             compile_cache_dir = os.path.join(log_dir, "xla_cache")
         self.compile_cache_dir = compile_cache_dir
         self.restarts = 0
@@ -125,7 +128,7 @@ class ElasticSupervisor:
         for rank in range(self.world_size):
             env = build_env(rank, self.world_size, self.endpoints)
             if self.compile_cache_dir:
-                env["PADDLE_COMPILATION_CACHE_DIR"] = self.compile_cache_dir
+                env["JAX_COMPILATION_CACHE_DIR"] = self.compile_cache_dir
             stdout = stderr = None
             if self.log_dir:
                 os.makedirs(self.log_dir, exist_ok=True)

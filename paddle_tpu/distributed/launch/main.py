@@ -31,6 +31,24 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
+def _require_cpu_workers(nproc_per_node: int) -> None:
+    """One process drives all chips of a host: every local worker is handed
+    the same devices, so several of them on a chip host are several claims
+    on the same chips — the first wins and the rest fail or hang. Refuse at
+    once unless the workers are pinned to the CPU (``JAX_PLATFORMS=cpu`` in
+    the environment they inherit). The launcher itself never touches jax,
+    so it cannot ask whether a chip is attached; the variable is what it
+    can observe."""
+    if nproc_per_node > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise SystemExit(
+            f"paddle_tpu.distributed.launch: --nproc_per_node "
+            f"{nproc_per_node} would start {nproc_per_node} processes that "
+            "each claim this host's accelerators; a chip belongs to one "
+            "process. Use --nproc_per_node 1 (one process sees every local "
+            "chip; scale out with --nnodes), or set JAX_PLATFORMS=cpu for "
+            "a CPU-only multi-process run.")
+
+
 def _parse(argv: Optional[Sequence[str]] = None):
     p = argparse.ArgumentParser(
         prog="paddle_tpu.distributed.launch",
@@ -42,7 +60,8 @@ def _parse(argv: Optional[Sequence[str]] = None):
     p.add_argument("--master", type=str,
                    default=os.environ.get("PADDLE_MASTER", ""))
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="processes per node (1 on TPU; >1 for CPU testing)")
+                   help="processes per node (1 on TPU; >1 only with "
+                        "JAX_PLATFORMS=cpu, for CPU testing)")
     p.add_argument("--devices", "--gpus", "--xpus", type=str, default="",
                    help="compat: visible device ids for this node")
     p.add_argument("--log_dir", type=str, default="log")
@@ -65,6 +84,7 @@ def launch(script: str, script_args: Sequence[str] = (),
            master: str = "", log_dir: Optional[str] = "log",
            elastic: int = 0, devices: str = "") -> int:
     """Programmatic entry (what main() calls; usable from tests)."""
+    _require_cpu_workers(nproc_per_node)
     world_size = nnodes * nproc_per_node
     if world_size == 1 and not master:
         # degenerate single-process: exec in-process environment, run script
@@ -152,6 +172,8 @@ def launch_with_master(script: str, script_args: Sequence[str] = (),
     import time as _time
 
     from .master import NodeAgent
+
+    _require_cpu_workers(nproc_per_node)
 
     if not node_endpoint:
         node_endpoint = f"{socket.gethostbyname(socket.gethostname())}:" \

@@ -60,13 +60,11 @@ def init_parallel_env(strategy=None):
     if _env.initialized:
         return _default_group
     # restart goodput: workers (re)spawned by the elastic supervisor carry
-    # PADDLE_COMPILATION_CACHE_DIR so recompiles after a failure are disk hits
+    # JAX_COMPILATION_CACHE_DIR so recompiles after a failure are disk hits
     from ..framework.compile_cache import maybe_enable_from_env
 
     maybe_enable_from_env()
-    from .jax_compat import distributed_is_initialized
-
-    if _env.world_size > 1 and not distributed_is_initialized():
+    if _env.world_size > 1 and not jax.distributed.is_initialized():
         coordinator = _env.master or _env.trainer_endpoints[0]
         jax.distributed.initialize(
             coordinator_address=coordinator,
@@ -117,13 +115,19 @@ def set_mesh(mesh):
     _global_mesh = mesh
 
 
+def mesh_if_set():
+    """The mesh ``set_mesh`` / ``fleet.init`` installed, or None: what code
+    that must not invent a multi-device layout asks (``get_mesh`` answers
+    with a pure-dp default over every device when none was set)."""
+    return _global_mesh
+
+
 def get_mesh():
-    global _global_mesh
     if _global_mesh is None:
         from .topology import build_mesh
 
-        n = jax.device_count()
-        _global_mesh = build_mesh(dp=n)
+        # not stored: "no mesh was set" stays observable (mesh_if_set)
+        return build_mesh(dp=jax.device_count())
     return _global_mesh
 
 

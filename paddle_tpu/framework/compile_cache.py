@@ -5,8 +5,9 @@ program it already compiled before the failure).
 The reference has no equivalent (CUDA kernels are precompiled; its restart
 cost is NCCL re-init). On TPU the compile IS the restart cost, so the cache
 is wired into the elastic path: ``ElasticSupervisor`` exports
-``PADDLE_COMPILATION_CACHE_DIR`` to every (re)spawned worker and
-``init_parallel_env`` picks it up.
+``JAX_COMPILATION_CACHE_DIR`` to every (re)spawned worker — JAX reads that
+variable itself at import — and ``init_parallel_env`` lowers the caching
+thresholds.
 
 Also home to the in-process kernel-choice memo (``memoize_kernel_choice``):
 hand-written Pallas kernels pick launch geometry (block shapes, grid
@@ -22,7 +23,14 @@ import os
 import threading
 from typing import Any, Callable, Dict, Hashable, Optional, Tuple
 
-ENV_VAR = "PADDLE_COMPILATION_CACHE_DIR"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+# Where the cache lives when ENV_VAR is not set: one fixed path inside the
+# checkout (git-ignored). The path is part of a cache entry's key, so a
+# directory named after $HOME, a pid, a time or a temporary name never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _enabled_dir: Optional[str] = None
 
@@ -123,35 +131,32 @@ def clear_kernel_choices() -> None:
         _KERNEL_CHOICES.clear()
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (default:
-    $PADDLE_COMPILATION_CACHE_DIR or ~/.cache/paddle_tpu/xla). Thresholds are
-    lowered so even small programs are cached — restart goodput beats the
-    few MB of disk. Idempotent; returns the directory."""
+def enable_compilation_cache() -> str:
+    """Turn jax's persistent compilation cache on and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own handling of the
+    variable decides the directory and this function sets none in code;
+    where it is not, the cache goes to ``DEFAULT_CACHE_DIR``. Thresholds
+    are lowered so even small programs are cached — restart goodput beats
+    the few MB of disk. Idempotent."""
     global _enabled_dir
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as jcc
 
-    cache_dir = (cache_dir or os.environ.get(ENV_VAR)
-                 or os.path.join(os.path.expanduser("~"), ".cache",
-                                 "paddle_tpu", "xla"))
-    cache_dir = os.path.abspath(cache_dir)
-    if _enabled_dir == cache_dir:
-        return cache_dir
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if _enabled_dir is not None:
+        return _enabled_dir
+    if os.environ.get(ENV_VAR):
+        cache_dir = jax.config.jax_compilation_cache_dir
+    else:
+        cache_dir = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # jax initializes its cache singleton lazily at the FIRST compile and
-    # never re-reads the dir config: if anything compiled before this
-    # call (typical in a warm process), the new dir would silently never
-    # be written. Reset so the next compile re-initializes against it.
-    try:
-        from jax._src import compilation_cache as _jcc
-
-        _jcc.reset_cache()
-    except Exception:
-        pass  # no singleton yet (nothing compiled) or API drift — the
-        # config above is then picked up at first initialization anyway
+    # never re-reads the config: if anything compiled before this call
+    # (typical in a warm process), the settings above would silently
+    # never apply. Reset so the next compile re-initializes against them.
+    jcc.reset_cache()
     _enabled_dir = cache_dir
     return cache_dir
 
@@ -162,7 +167,7 @@ def compilation_cache_dir() -> Optional[str]:
 
 
 def maybe_enable_from_env() -> Optional[str]:
-    """Enable iff PADDLE_COMPILATION_CACHE_DIR is set (the elastic
+    """Enable iff JAX_COMPILATION_CACHE_DIR is set (the elastic
     supervisor's contract with restarted workers)."""
     if os.environ.get(ENV_VAR):
         return enable_compilation_cache()

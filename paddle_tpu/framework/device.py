@@ -74,7 +74,9 @@ def cuda_device_count() -> int:
     return 0
 
 
-def synchronize():
-    """Block until all dispatched work is done (paddle.device.synchronize)."""
+def synchronize(device=None):
+    """Block until all dispatched work is done (paddle.device.synchronize):
+    wait on what was dispatched, every live array. ``device`` is accepted
+    for API parity; XLA owns the streams."""
     for d in jax.live_arrays():
         d.block_until_ready()

@@ -399,6 +399,24 @@ class ModelRunner:
             self.decode_fns[key] = fn
         return fn
 
+    def decode_program_text(self, nb: int, k: int, sampling: bool) -> str:
+        """Compiled text of the decode-chain program for bucket
+        ``(nb, k, sampling)``: the jit entry the engine dispatches,
+        lowered at the shapes it dispatches it at. What ``chip_smoke.py``
+        reads to show that the program which ran holds the Mosaic kernel
+        (``tpu_custom_call``) and, at tp>1, the Megatron collective."""
+        eng = self.engine
+
+        def host(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        return self.get_decode(nb, k, sampling).lower(
+            eng._params, eng._pages_flat(),
+            host((nb, eng.max_pages_per_seq), jnp.int32),
+            host((nb,), jnp.int32), host((nb,), jnp.int32),
+            host((nb,), jnp.float32), host((nb, 2), jnp.uint32),
+        ).compile().as_text()
+
     def get_prefill(self, bucket, sampling: bool, suffix: bool = False):
         key = (bucket, sampling, suffix)
         fn = self.prefill_fns.get(key)

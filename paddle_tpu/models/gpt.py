@@ -128,16 +128,27 @@ class GPTAttention(nn.Layer):
         transpose/unbind materialization). hpb=2 pairs D=64 heads into full
         128-lane tiles so no operand carries a 2x-padded layout."""
         from ..ops.pallas.causal_flash import causal_flash_qkv, heads_per_block
+        from ..ops.pallas.sharded import per_shard
 
         nh, hd = self.num_heads, self.head_dim
         hpb = heads_per_block(nh, hd)
         lanes = hpb * hd
 
+        def attend(qkv5):
+            # [b, 3, G, s, l] of whatever batch rows and head groups this
+            # shard holds -> the kernel's [b, 3G, s, l] (q, then k, then v)
+            b, _, g, s, _ = qkv5.shape
+            return causal_flash_qkv(qkv5.reshape(b, 3 * g, s, lanes),
+                                    g * hpb, hd)
+
         def fn(xa, wq, bq, wo, bo):
             w3 = wq.reshape(xa.shape[-1], 3 * nh // hpb, lanes).astype(xa.dtype)
             b3 = bq.reshape(3 * nh // hpb, 1, lanes).astype(xa.dtype)
             qkv = jnp.einsum("bsi,ipl->bpsl", xa, w3) + b3
-            o = causal_flash_qkv(qkv, nh, hd)
+            o = per_shard(
+                attend, [qkv.reshape(qkv.shape[0], 3, nh // hpb,
+                                     *qkv.shape[2:])],
+                dims=[(0, 2)], out_dims=(0, 1), out_ndim=4)
             wo3 = wo.reshape(nh // hpb, lanes, wo.shape[-1]).astype(xa.dtype)
             return jnp.einsum("bpsl,plo->bso", o, wo3) + bo.astype(xa.dtype)
 

@@ -1,14 +1,16 @@
 """Native (C++) runtime components, loaded via ctypes (SURVEY.md stance:
 pybind11 is absent from this image — C ABI + ctypes is the binding layer).
 
-Build-on-first-import with g++; artifacts cached under
-``paddle_tpu/native/_build/``. Every native component has a pure-Python
+Build-on-first-use with g++ from the ``*.cc`` beside this file; artifacts
+cached under ``paddle_tpu/native/_build/`` (git-ignored), keyed by a digest
+of their sources. Every native component has a pure-Python
 fallback so the framework works without a toolchain (the reference requires
 a full CMake build; we degrade gracefully instead).
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -24,14 +26,20 @@ _libs = {}
 def _compile(name: str, sources) -> Optional[str]:
     """g++ -O2 -shared; returns .so path or None when unavailable.
 
-    Compiles to a per-process temp path and os.rename()s into place so
-    sibling processes racing on a cold cache never dlopen a half-written
-    .so (rename is atomic within a filesystem)."""
-    so = os.path.join(_BUILD, f"lib{name}.so")
+    The artifact is named after a digest of its sources, so a build
+    product left in ``_build/`` by other sources (an earlier commit, a
+    copied tree) is never what runs — file times say nothing after a
+    checkout or a copy. Compiles to a per-process temp path and
+    os.rename()s into place so sibling processes racing on a cold cache
+    never dlopen a half-written .so (rename is atomic within a
+    filesystem)."""
     srcs = [os.path.join(_HERE, s) for s in sources]
-    if os.path.exists(so) and all(
-        os.path.getmtime(so) >= os.path.getmtime(s) for s in srcs
-    ):
+    digest = hashlib.sha256()
+    for src in srcs:
+        with open(src, "rb") as f:
+            digest.update(f.read())
+    so = os.path.join(_BUILD, f"lib{name}.{digest.hexdigest()[:16]}.so")
+    if os.path.exists(so):
         return so
     os.makedirs(_BUILD, exist_ok=True)
     tmp = f"{so}.tmp.{os.getpid()}"

@@ -54,8 +54,14 @@ def flash_attention(query, key, value, dropout=0.0, causal=False, return_softmax
     def fn(qa, ka, va):
         if use_pallas and _pallas_ok(qa, ka):
             from ...ops.pallas.flash_attention import flash_attention_fused
+            from ...ops.pallas.sharded import per_shard
 
-            return flash_attention_fused(qa, ka, va, causal=causal)
+            # [B, S, H, D]: batch rows and heads are independent
+            return per_shard(
+                lambda q, k, v: flash_attention_fused(q, k, v,
+                                                      causal=causal),
+                [qa, ka, va], dims=[(0, 2)] * 3, out_dims=(0, 2),
+                out_ndim=4)
         return naive_attention(qa, ka, va, causal=causal)
 
     out = apply_op(fn, q, k, v)

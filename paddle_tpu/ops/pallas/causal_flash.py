@@ -63,10 +63,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed upstream: TPUCompilerParams (jax 0.4.x) -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1.0e30
 
 # [S, S] fp32 logits + exp + bf16 copy resident per program: 1024 -> ~12 MB
@@ -365,7 +361,7 @@ def _fwd_row(qkv, num_heads, head_dim, scale, blk):
     # S=4096 sits 1 MB over the default 16 MB scoped-VMEM budget (the
     # whole-seq-resident K/V grow with S); raise the cap — v5e has the
     # physical VMEM, 16 MB is just the compiler's conservative default
-    params = (_CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
+    params = (pltpu.CompilerParams(vmem_limit_bytes=32 * 1024 * 1024)
               if seq > 2048 else None)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_row_kernel, scale=scale, seq=seq,
@@ -605,7 +601,7 @@ def _bwd(num_heads, head_dim, scale, res, do):
                           hpb=hpb),
         # f32 operands at S=1024 sit ~1 MB over the default 16 MB scoped
         # VMEM (the [S,S] f32 temps double); raise the cap like _fwd_row
-        compiler_params=(_CompilerParams(
+        compiler_params=(pltpu.CompilerParams(
             vmem_limit_bytes=32 * 1024 * 1024)
             if seq >= 1024 and jnp.dtype(qkv.dtype).itemsize > 2
             else None),

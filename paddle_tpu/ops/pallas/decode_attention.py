@@ -168,37 +168,112 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None):
 # its minor dimension (Hkv*D, a multiple of 128 for real configs) takes an
 # unpadded tiled layout — the reference-parity [2,B,H,S,D] layout has a
 # 64-wide minor that XLA pads 2x (T(8,128)), and inside the decode scan the
-# in-place update + padded relayout cost ~0.13 ms/(layer*token) at GPT-2
+# in-place update + padded re-layout cost ~0.13 ms/(layer*token) at GPT-2
 # scale where the pure bandwidth floor is ~0.03 ms. One program per batch
 # element keeps per-program overhead off the critical path (the per-(b,h)
 # kernel above pays ~0.5 us x B*H programs).
 
 
-def _slab_kernel(len_ref, q_ref, kv_ref, o_ref, *, scale, num_heads,
-                 head_dim, max_seq):
+# ----------------------------------------- windowed online softmax (shared)
+# The slab kernels (this file's contiguous one, paged_attention's decode and
+# verify ones) score a sequence one WINDOW of tokens at a time and carry the
+# softmax across windows in VMEM scratch: a whole sequence resident at once
+# does not fit fast memory at serving widths (llama2_7b: 4096 lanes x 2048
+# tokens = 16 MiB for K and again for V, against a 16 MiB default budget).
+# A sequence short enough for one window takes one step, and the arithmetic
+# is then what the whole-resident kernels did. The window is sized so that
+# K and V of one window hold _WINDOW_BYTES; this makes the kernels compile,
+# it is not tuned.
+_WINDOW_BYTES = 8 << 20
+
+
+def _stat_lanes(num_heads: int) -> int:
+    """Lanes of the running max / denominator scratch: one column a head."""
+    return -(-num_heads // 128) * 128
+
+
+def _softmax_scratch(rows: int, num_heads: int, head_dim: int):
+    """(m, l, acc) VMEM scratch carried across the windows of a sequence."""
+    return [pltpu.VMEM((rows, _stat_lanes(num_heads)), jnp.float32),
+            pltpu.VMEM((rows, _stat_lanes(num_heads)), jnp.float32),
+            pltpu.VMEM((rows, num_heads * head_dim), jnp.float32)]
+
+
+def _softmax_init(m_sc, l_sc, acc_sc):
+    m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+    l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+    acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+
+def _softmax_window(q_ref, k_of, v_of, mask, m_sc, l_sc, acc_sc, *, scale,
+                    num_heads, head_dim, group):
+    """Fold one window into the running softmax of every head.
+
+    ``q_ref`` [1, R, H*D]; ``k_of(kv_head)`` / ``v_of(kv_head)`` return that
+    head's [W, D] f32 window; ``mask`` [R, W] marks the live columns. Per
+    64/128-lane head slices, like the whole-resident kernels before: a
+    full-lane-width formulation multiplies every head against ALL kv lanes."""
+    for h in range(num_heads):
+        lo = h * head_dim
+        qh = q_ref[0, :, lo:lo + head_dim].astype(jnp.float32)  # [R, D]
+        s = jax.lax.dot_general(
+            qh, k_of(h // group), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [R, W]
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_sc[:, h:h + 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row can be fully masked in this window (with m still at NEG_INF
+        # exp(s - m) would be 1 there) — guard
+        p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m_new), 0.0)
+        l_sc[:, h:h + 1] = alpha * l_sc[:, h:h + 1] + jnp.sum(
+            p, axis=-1, keepdims=True)
+        m_sc[:, h:h + 1] = m_new
+        acc_sc[:, lo:lo + head_dim] = (
+            acc_sc[:, lo:lo + head_dim] * alpha + jax.lax.dot_general(
+                p, v_of(h // group), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+
+
+def _softmax_finish(o_ref, l_sc, acc_sc, *, num_heads, head_dim):
+    for h in range(num_heads):
+        lo = h * head_dim
+        o_ref[0, :, lo:lo + head_dim] = (
+            acc_sc[:, lo:lo + head_dim]
+            / jnp.maximum(l_sc[:, h:h + 1], 1e-37)).astype(o_ref.dtype)
+
+
+def _slab_kernel(len_ref, q_ref, kv_ref, o_ref, m_sc, l_sc, acc_sc, *, scale,
+                 num_heads, head_dim, window):
     b = pl.program_id(0)
+    w = pl.program_id(1)
     length = len_ref[b]
     h_kv = kv_ref.shape[-1] // head_dim
-    group = num_heads // h_kv
-    ids = jax.lax.broadcasted_iota(jnp.int32, (_Q_ROWS, max_seq), 1)
-    mask = ids < length
-    for h in range(num_heads):
-        lo_q = h * head_dim
-        lo_kv = (h // group) * head_dim
-        qh = q_ref[0, :, lo_q:lo_q + head_dim].astype(jnp.float32)  # [8, D]
-        kh = kv_ref[0, 0, :, lo_kv:lo_kv + head_dim]  # [S, D]
-        s = jax.lax.dot_general(
-            qh, kh.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [8, S]
-        s = jnp.where(mask, s, NEG_INF)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        vh = kv_ref[1, 0, :, lo_kv:lo_kv + head_dim]
-        out = jax.lax.dot_general(
-            p, vh.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) / jnp.maximum(l, 1e-37)
-        o_ref[0, :, lo_q:lo_q + head_dim] = out.astype(o_ref.dtype)
+
+    @pl.when(w == 0)
+    def _init():
+        _softmax_init(m_sc, l_sc, acc_sc)
+
+    # windows wholly past the sequence contribute nothing: skip their math
+    # (their block was still fetched by the pipeline)
+    @pl.when(w * window < length)
+    def _window():
+        ids = w * window + jax.lax.broadcasted_iota(
+            jnp.int32, (_Q_ROWS, window), 1)
+
+        def part(which):
+            return lambda kh: kv_ref[
+                which, 0, :, kh * head_dim:(kh + 1) * head_dim].astype(
+                    jnp.float32)  # [W, D]
+
+        _softmax_window(q_ref, part(0), part(1), ids < length, m_sc, l_sc,
+                        acc_sc, scale=scale, num_heads=num_heads,
+                        head_dim=head_dim, group=num_heads // h_kv)
+
+    @pl.when(w == pl.num_programs(1) - 1)
+    def _finish():
+        _softmax_finish(o_ref, l_sc, acc_sc, num_heads=num_heads,
+                        head_dim=head_dim)
 
 
 def _slab_ref(q, kv_slab, lengths, scale):
@@ -238,25 +313,52 @@ def decode_attention_slab(q, kv_slab, lengths, scale=None):
     return _slab_dispatch(q, kv_slab, jnp.asarray(lengths), scale)
 
 
+def _slab_window(s_max: int, lanes: int, itemsize: int) -> int:
+    """Tokens of the slab resident at once: the whole sequence when its K
+    and V fit _WINDOW_BYTES, else the largest divisor of ``s_max`` that
+    does and keeps blocks sublane-tile aligned (a ragged last block would
+    read past the slab, and 0 * garbage is not 0)."""
+    # the pipeline double-buffers the block: half the budget a buffer
+    fit = _WINDOW_BYTES // 2 // (2 * lanes * itemsize)
+    # tpulint: disable=TPL301 -- s_max/lanes/itemsize are static python
+    # ints (array shapes at pallas_call build time), not traced values
+    if s_max <= fit:
+        return s_max
+    tile = 32 // itemsize  # sublanes of one packed tile: 8 f32, 16 bf16
+    for w in range(fit - fit % tile, 0, -tile):
+        # tpulint: disable=TPL301 -- same static shape arithmetic
+        if s_max % w == 0:
+            return w
+    raise ValueError(
+        f"decode_attention_slab: no {tile}-aligned window of at most {fit} "
+        f"tokens divides max_seq={s_max} (lanes={lanes}); allocate the "
+        "cache with a max_seq that has such a divisor")
+
+
 def _slab_pallas(q, kv_slab, lengths, scale):
     b, h, d = q.shape
-    s_max = kv_slab.shape[2]
+    s_max, lanes = kv_slab.shape[2], kv_slab.shape[3]
+    window = _slab_window(s_max, lanes, jnp.dtype(kv_slab.dtype).itemsize)
     qr = jnp.broadcast_to(q.reshape(b, 1, h * d), (b, _Q_ROWS, h * d))
     out = pl.pallas_call(
         functools.partial(_slab_kernel, scale=scale, num_heads=h,
-                          head_dim=d, max_seq=s_max),
+                          head_dim=d, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b,),
+            grid=(b, s_max // window),
             in_specs=[
-                pl.BlockSpec((1, _Q_ROWS, h * d), lambda i, lens: (i, 0, 0)),
-                pl.BlockSpec((2, 1, s_max, kv_slab.shape[-1]),
-                             lambda i, lens: (0, i, 0, 0)),
+                pl.BlockSpec((1, _Q_ROWS, h * d),
+                             lambda i, w, lens: (i, 0, 0)),
+                pl.BlockSpec((2, 1, window, lanes),
+                             lambda i, w, lens: (0, i, w, 0)),
             ],
             out_specs=pl.BlockSpec((1, _Q_ROWS, h * d),
-                                   lambda i, lens: (i, 0, 0)),
+                                   lambda i, w, lens: (i, 0, 0)),
+            scratch_shapes=_softmax_scratch(_Q_ROWS, h, d),
         ),
         out_shape=jax.ShapeDtypeStruct((b, _Q_ROWS, h * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(jnp.asarray(lengths, jnp.int32), qr, kv_slab)
     return out[:, 0].reshape(b, h, d)

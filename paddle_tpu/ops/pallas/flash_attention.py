@@ -216,7 +216,7 @@ def fused_bwd_math(q, k, v, out, do, lse_col, *, scale, causal, kv_valid):
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, out_ref, do_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, *, scale, causal, kv_valid,
                       sq, sk):
-    # lse arrives as a [1, 1, sq] row; relayout to a [sq, 1] column
+    # lse arrives as a [1, 1, sq] row; re-layout to a [sq, 1] column
     lse_col = jnp.transpose(lse_ref[0], (1, 0))
     dq, dk, dv = fused_bwd_math(
         q_ref[0], k_ref[0], v_ref[0], out_ref[0], do_ref[0], lse_col,

@@ -152,7 +152,7 @@ def grouped_matmul_pallas(lhs, rhs, group_sizes, valid_sizes=None,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((ma, np_), lhs.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(blk2grp, blk_rows, xa, wp)

@@ -39,8 +39,16 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .decode_attention import (_WINDOW_BYTES, _softmax_finish,
+                               _softmax_init, _softmax_scratch,
+                               _softmax_window)
+
 NEG_INF = -1.0e30
 _Q_ROWS = 8  # pad the single q row to a full sublane tile
+# safely under the 16 MiB of fast memory the chip's compiler grants a kernel
+# unasked (v5e), with slack for what the estimate below leaves out; a kernel
+# whose resident set is larger names its own limit
+_DEFAULT_VMEM_BYTES = 12 << 20
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_ref",
            "PagedKVCache", "quantize_rows_int8",
@@ -331,29 +339,36 @@ class PagedKVCache:
 
 # ------------------------------------------------ slab-paged kernel (v2)
 # The engine's throughput path. Pages are stored slab-style
-# [P, page_size, Hkv*D] (contiguous 128-lane-aligned rows), and ONE program
-# per batch element gathers that sequence's LIVE pages HBM→VMEM with
-# explicit async DMA (block table scalar-prefetched, copies all issued
-# before one wait), then runs slab attention over the contiguous window.
+# [P, page_size, Hkv*D] (contiguous 128-lane-aligned rows). One program per
+# (batch element, window of its block table) gathers that window's LIVE
+# pages HBM→VMEM with explicit async DMA (block table scalar-prefetched,
+# copies all issued before one wait), then folds the window into an online
+# softmax carried in VMEM scratch (decode_attention._softmax_window). A
+# sequence whose K and V fit _WINDOW_BYTES is one window — the GPT-2 small
+# geometry the kernel was written at; at llama2_7b widths (4096 lanes) a
+# 2048-token sequence is 4 windows, where the whole sequence resident at
+# once (2 x 16 MiB) is refused by the chip's compiler.
 # The v1 kernel above runs grid (B, H, max_pages) — at GPT-2 serving shapes
 # that is ~6000 programs/layer whose per-program cost (~0.5 us) dwarfs the
 # ~30 us of actual bandwidth, measured 18x slower than the contiguous slab
-# path; this design needs B programs and copies only ceil(len/ps) pages.
+# path; this design needs B x windows programs and copies only
+# ceil(len/ps) pages.
 
 
-def _paged_slab_kernel(len_ref, bt_ref, q_ref, kp_ref, vp_ref, sc_ref,
-                       o_ref, kwin, vwin, scwin, kv_sem, sc_sem, *, scale,
-                       num_heads, head_dim, page_size, max_pages,
-                       quantized):
-    b = pl.program_id(0)
-    length = len_ref[b]
-    # defensive clamp: a length beyond the table capacity (a buggy or
-    # overshooting caller) must not drive OOB block-table reads / DMA
-    # writes past the VMEM scratch window
-    npages = jnp.minimum((length + page_size - 1) // page_size, max_pages)
+def _window_pages(max_pages, page_size, lanes, itemsize):
+    """Pages of one window: as many as hold _WINDOW_BYTES of K plus V."""
+    return max(1, min(max_pages,
+                      _WINDOW_BYTES // (2 * page_size * lanes * itemsize)))
+
+
+def _gather_window(b, first, live, bt_ref, kp_ref, vp_ref, sc_ref, kwin,
+                   vwin, scwin, kv_sem, sc_sem, *, page_size, quantized):
+    """DMA logical pages [first, first + live) of row ``b`` into the window
+    scratch and zero the rest of it."""
+    win_pages = kwin.shape[0]
 
     def issue(j, _):
-        pg = bt_ref[b, j]
+        pg = bt_ref[b, first + j]
         pltpu.make_async_copy(
             kp_ref.at[pl.ds(pg, 1)], kwin.at[pl.ds(j, 1)], kv_sem).start()
         pltpu.make_async_copy(
@@ -364,7 +379,7 @@ def _paged_slab_kernel(len_ref, bt_ref, q_ref, kp_ref, vp_ref, sc_ref,
                 sc_sem).start()
         return _
 
-    jax.lax.fori_loop(0, npages, issue, 0)
+    jax.lax.fori_loop(0, live, issue, 0)
 
     # scratch persists across grid steps: zero the dead tail while the live
     # DMAs fly (stale NaN patterns would poison the PV dot via 0*NaN)
@@ -382,7 +397,7 @@ def _paged_slab_kernel(len_ref, bt_ref, q_ref, kp_ref, vp_ref, sc_ref,
             scwin[pl.ds(j, 1)] = jnp.zeros((1, page_size, 128), scwin.dtype)
         return _
 
-    jax.lax.fori_loop(npages, max_pages, ztail, 0)
+    jax.lax.fori_loop(live, win_pages, ztail, 0)
 
     # DMA semaphores count bytes: drain with same-sized descriptors, one
     # wait per issued copy
@@ -391,7 +406,7 @@ def _paged_slab_kernel(len_ref, bt_ref, q_ref, kp_ref, vp_ref, sc_ref,
             kp_ref.at[pl.ds(0, 1)], kwin.at[pl.ds(0, 1)], kv_sem).wait()
         return _
 
-    jax.lax.fori_loop(0, 2 * npages, drain_kv, 0)
+    jax.lax.fori_loop(0, 2 * live, drain_kv, 0)
     if quantized:
         def drain_sc(i, _):
             pltpu.make_async_copy(
@@ -399,48 +414,141 @@ def _paged_slab_kernel(len_ref, bt_ref, q_ref, kp_ref, vp_ref, sc_ref,
                 sc_sem).wait()
             return _
 
-        jax.lax.fori_loop(0, npages, drain_sc, 0)
+        jax.lax.fori_loop(0, live, drain_sc, 0)
 
+
+def _window_heads(kwin, vwin, scwin, *, head_dim, quantized):
+    """Per-KV-head [W, D] f32 loaders over the gathered window
+    (dequantizing int8 pages in place)."""
+    win_tokens = kwin.shape[0] * kwin.shape[1]
+    h_kv = kwin.shape[-1] // head_dim
+    scw = scwin[...].reshape(win_tokens, 128) if quantized else None
+
+    def part(win, sc_lo):
+        def load(kh):
+            x = win[:, :, kh * head_dim:(kh + 1) * head_dim].reshape(
+                win_tokens, head_dim).astype(jnp.float32)
+            if quantized:
+                # [W, 1] scale broadcast along lanes (a [W,1]→[1,W]
+                # transpose of the scale row, the previous scheme, is a
+                # lane↔sublane re-layout per head — measured 2x slowdown
+                # of the whole int8 decode step)
+                x = x * scw[:, sc_lo + kh:sc_lo + kh + 1]
+            return x
+        return load
+
+    return part(kwin, 0), part(vwin, h_kv)
+
+
+def _paged_window_kernel(lim_ref, bt_ref, q_ref, kp_ref, vp_ref, sc_ref,
+                         o_ref, kwin, vwin, scwin, m_sc, l_sc, acc_sc,
+                         kv_sem, sc_sem, *, scale, num_heads, head_dim, m,
+                         page_size, max_pages, quantized):
+    """Decode (m = 0: every query row attends tokens < lim[b], the row's
+    length) and verify/suffix (m >= 1: query row j attends tokens
+    < lim[b] + j + 1, lim the row's base length) over one window."""
+    b = pl.program_id(0)
+    w = pl.program_id(1)
+    win_pages = kwin.shape[0]
+    win_tokens = win_pages * page_size
     seq = max_pages * page_size
-    mask_ids = jax.lax.broadcasted_iota(jnp.int32, (_Q_ROWS, seq), 1)
-    mask = mask_ids < length
-    khd = kwin.shape[-1]
-    h_kv = khd // head_dim
-    group = num_heads // h_kv
-    # per-head 64-lane ref slices, exactly like the contiguous _slab_kernel
-    # (measured fast there) — the previous full-lane-width roll/select
-    # scheme multiplied every head against ALL kv lanes, ~h_kv x the MACs,
-    # and was the reason paged decode ran ~2.5x slower than contiguous
-    if quantized:
-        scw = scwin[...].reshape(seq, 128)
-    for h in range(num_heads):
-        kh_ix = h // group
-        lo_q = h * head_dim
-        lo_kv = kh_ix * head_dim
-        qh = q_ref[0, :, lo_q:lo_q + head_dim].astype(jnp.float32)  # [8, D]
-        kh = kwin[:, :, lo_kv:lo_kv + head_dim].reshape(
-            seq, head_dim).astype(jnp.float32)
-        if quantized:
-            # dequantize the K slice in place: [seq, 1] scale broadcast
-            # along lanes (a [seq,1]→[1,seq] transpose of the scale row,
-            # the previous scheme, is a lane↔sublane relayout per head —
-            # measured 2x slowdown of the whole int8 decode step)
-            kh = kh * scw[:, kh_ix:kh_ix + 1]
-        s = jax.lax.dot_general(
-            qh, kh, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [8, seq]
-        s = jnp.where(mask, s, NEG_INF)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m), 0.0)
-        l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-37)
-        vh = vwin[:, :, lo_kv:lo_kv + head_dim].reshape(
-            seq, head_dim).astype(jnp.float32)
-        if quantized:
-            vh = vh * scw[:, h_kv + kh_ix:h_kv + kh_ix + 1]
-        out = jax.lax.dot_general(
-            p, vh, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) / l  # [8, D]
-        o_ref[0, :, lo_q:lo_q + head_dim] = out.astype(o_ref.dtype)
+    # the window set must cover every live token: the cached context plus,
+    # for verify, the freshly written slab. Clamped at the table capacity
+    # like the refs, so an overshooting row (a length or base + m past it)
+    # never drives OOB block-table reads or DMA writes past the scratch
+    covered = jnp.minimum(lim_ref[b] + m, seq)
+    npages = (covered + page_size - 1) // page_size
+    first = w * win_pages
+    live = jnp.clip(npages - first, 0, win_pages)
+
+    @pl.when(w == 0)
+    def _init():
+        _softmax_init(m_sc, l_sc, acc_sc)
+
+    # a window past the row's last live page holds nothing: no DMA, no math
+    @pl.when(live > 0)
+    def _window():
+        _gather_window(b, first, live, bt_ref, kp_ref, vp_ref, sc_ref, kwin,
+                       vwin, scwin, kv_sem, sc_sem, page_size=page_size,
+                       quantized=quantized)
+        rows = q_ref.shape[1]
+        col = first * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, win_tokens), 1)
+        if m:
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, win_tokens), 0)
+            # causal per-position limits — the ref's `limit` expression
+            mask = col < jnp.minimum(lim_ref[b] + row + 1, seq)
+        else:
+            mask = col < lim_ref[b]
+        k_of, v_of = _window_heads(kwin, vwin, scwin, head_dim=head_dim,
+                                   quantized=quantized)
+        _softmax_window(q_ref, k_of, v_of, mask, m_sc, l_sc, acc_sc,
+                        scale=scale, num_heads=num_heads, head_dim=head_dim,
+                        group=num_heads * head_dim // kwin.shape[-1])
+
+    @pl.when(w == pl.num_programs(1) - 1)
+    def _finish():
+        _softmax_finish(o_ref, l_sc, acc_sc, num_heads=num_heads,
+                        head_dim=head_dim)
+
+
+def _paged_window_call(lim, block_tables, qr, k_pages, v_pages, scale_pages,
+                       out_dtype, *, scale, num_heads, head_dim, m,
+                       interpret):
+    """The one pallas_call behind the decode and verify slab kernels.
+    ``qr`` [B, R, H*D] (R a sublane-tile multiple of query rows)."""
+    b, rows, hd = qr.shape
+    _, page_size, khd = k_pages.shape
+    max_pages = block_tables.shape[1]
+    quantized = scale_pages is not None
+    if scale_pages is None:
+        scale_pages = jnp.zeros((1, page_size, 128), jnp.bfloat16)
+    itemsize = jnp.dtype(k_pages.dtype).itemsize
+    win_pages = _window_pages(max_pages, page_size, khd, itemsize)
+    # resident at once: the window, the double-buffered q and out blocks,
+    # the accumulator, and per-head f32 temporaries ([W, D] K/V slices,
+    # [R, W] scores). The compiler's default budget (16 MiB on v5e) holds
+    # a decode step; a wide verify slab (chunked prefill's m = hundreds of
+    # rows x 4096 lanes) needs the explicit limit
+    win_tokens = win_pages * page_size
+    resident = (2 * win_tokens * khd * itemsize
+                + 2 * rows * hd * (jnp.dtype(qr.dtype).itemsize
+                                   + jnp.dtype(out_dtype).itemsize)
+                + rows * hd * 4
+                + 4 * win_tokens * (head_dim + rows) * 4)
+    return pl.pallas_call(
+        functools.partial(
+            _paged_window_kernel, scale=scale, num_heads=num_heads,
+            head_dim=head_dim, m=m, page_size=page_size,
+            max_pages=max_pages, quantized=quantized),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, -(-max_pages // win_pages)),
+            in_specs=[
+                pl.BlockSpec((1, rows, hd), lambda i, w, lim, bt: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, rows, hd),
+                                   lambda i, w, lim, bt: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((win_pages, page_size, khd), k_pages.dtype),
+                pltpu.VMEM((win_pages, page_size, khd), k_pages.dtype),
+                pltpu.VMEM((win_pages, page_size, 128), jnp.bfloat16),
+                *_softmax_scratch(rows, num_heads, head_dim),
+                pltpu.SemaphoreType.DMA,
+                pltpu.SemaphoreType.DMA,
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, rows, hd), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=(None if resident <= _DEFAULT_VMEM_BYTES
+                              else resident + (resident >> 2))),
+        interpret=interpret,
+    )(jnp.asarray(lim, jnp.int32), jnp.asarray(block_tables, jnp.int32),
+      qr, k_pages, v_pages, scale_pages)
 
 
 def paged_slab_decode_attention(q, k_pages, v_pages, block_tables, lengths,
@@ -462,14 +570,12 @@ def paged_slab_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     dispatch serves both; the guard below catches a mis-sharded pool
     (lanes that split a head) before it becomes silent garbage."""
     b, h, d = q.shape
-    p_total, page_size, khd = k_pages.shape
+    khd = k_pages.shape[-1]
     if khd % d:
         raise ValueError(
             f"page lanes ({khd}) must hold whole KV heads of head_dim="
             f"{d} — a TP shard that splits a head mid-lane cannot "
             "attend (tp must divide num_kv_heads)")
-    max_pages = block_tables.shape[1]
-    quantized = scale_pages is not None
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if _interpret() or khd % 128 or (h * d) % 128:
@@ -478,36 +584,9 @@ def paged_slab_decode_attention(q, k_pages, v_pages, block_tables, lengths,
         return _paged_slab_ref(q, k_pages, v_pages, block_tables, lengths,
                                scale, scale_pages)
     qr = jnp.broadcast_to(q.reshape(b, 1, h * d), (b, _Q_ROWS, h * d))
-    if scale_pages is None:
-        scale_pages = jnp.zeros((1, page_size, 128), jnp.bfloat16)
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_slab_kernel, scale=scale, num_heads=h, head_dim=d,
-            page_size=page_size, max_pages=max_pages, quantized=quantized),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b,),
-            in_specs=[
-                pl.BlockSpec((1, _Q_ROWS, h * d),
-                             lambda i, lens, bt: (i, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, _Q_ROWS, h * d),
-                                   lambda i, lens, bt: (i, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((max_pages, page_size, khd), k_pages.dtype),
-                pltpu.VMEM((max_pages, page_size, khd), k_pages.dtype),
-                pltpu.VMEM((max_pages, page_size, 128), jnp.bfloat16),
-                pltpu.SemaphoreType.DMA,
-                pltpu.SemaphoreType.DMA,
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, _Q_ROWS, h * d), q.dtype),
-        interpret=False,
-    )(jnp.asarray(lengths, jnp.int32), jnp.asarray(block_tables, jnp.int32),
-      qr, k_pages, v_pages, scale_pages)
+    out = _paged_window_call(
+        lengths, block_tables, qr, k_pages, v_pages, scale_pages, q.dtype,
+        scale=scale, num_heads=h, head_dim=d, m=0, interpret=False)
     return out[:, 0].reshape(b, h, d)
 
 
@@ -539,123 +618,19 @@ def _paged_slab_ref(q, k_pages, v_pages, block_tables, lengths, scale,
 
 
 # ------------------------------------------ verify/suffix slab kernel (v3)
-# The multi-query twin of the slab decode kernel (ISSUE 9 tentpole a): one
-# program per batch element DMA-gathers that row's live pages into VMEM
-# (cached prefix PLUS the freshly written slab) and scores a slab of m
-# query positions against the window — query j of row b attends tokens
-# < base_len[b] + j + 1, exactly `_paged_multi_query_ref`'s causal-window
-# semantics. ONE kernel replaces the jnp window-gather for spec-decode
-# verify (m = k+1), prefix-cache suffix prefill (per-row widths, base 0
-# on miss rows) and chunked prefill (m = chunk, decode rows at width 1):
-# the gather of pages moves the same bytes the decode kernel moves per
-# step, amortized over all m positions, with zero XLA gathers.
+# The multi-query twin of the slab decode kernel (ISSUE 9 tentpole a), the
+# same windowed program with m query rows: row j of batch element b attends
+# tokens < base_len[b] + j + 1, exactly `_paged_multi_query_ref`'s
+# causal-window semantics, over the cached prefix PLUS the freshly written
+# slab. ONE kernel replaces the jnp window-gather for spec-decode verify
+# (m = k+1), prefix-cache suffix prefill (per-row widths, base 0 on miss
+# rows) and chunked prefill (m = chunk, decode rows at width 1): the gather
+# of pages moves the same bytes the decode kernel moves per step, amortized
+# over all m positions, with zero XLA gathers.
 #
-# Softmax is computed in the exact elementwise order of jax.nn.softmax
-# (exp(s - max) normalized BEFORE the PV dot), so interpret-mode output
-# is bitwise identical to the jnp reference — the parity tests assert
-# equality, not closeness.
-
-
-def _paged_verify_slab_kernel(base_ref, bt_ref, q_ref, kp_ref, vp_ref,
-                              sc_ref, o_ref, kwin, vwin, scwin, kv_sem,
-                              sc_sem, *, scale, num_heads, head_dim, m,
-                              page_size, max_pages, quantized):
-    b = pl.program_id(0)
-    base = base_ref[b]
-    seq = max_pages * page_size
-    # the window must cover the cached prefix plus the freshly written
-    # slab; clamp like the ref so an overshooting row (base + m past the
-    # table capacity) never drives OOB block-table reads or DMA writes
-    limit_max = jnp.minimum(base + m, seq)
-    npages = jnp.minimum((limit_max + page_size - 1) // page_size,
-                         max_pages)
-
-    def issue(j, _):
-        pg = bt_ref[b, j]
-        pltpu.make_async_copy(
-            kp_ref.at[pl.ds(pg, 1)], kwin.at[pl.ds(j, 1)], kv_sem).start()
-        pltpu.make_async_copy(
-            vp_ref.at[pl.ds(pg, 1)], vwin.at[pl.ds(j, 1)], kv_sem).start()
-        if quantized:
-            pltpu.make_async_copy(
-                sc_ref.at[pl.ds(pg, 1)], scwin.at[pl.ds(j, 1)],
-                sc_sem).start()
-        return _
-
-    jax.lax.fori_loop(0, npages, issue, 0)
-
-    # zero the dead tail while the live DMAs fly (stale NaN patterns
-    # would poison the PV dot via 0*NaN)
-    def ztail(j, _):
-        # tpulint: disable=TPL402 -- kwin/vwin/scwin are Pallas VMEM
-        # scratch Refs: in-place Ref stores ARE the kernel-side memory
-        # model, the closure is over memory handles, not traced values
-        kwin[pl.ds(j, 1)] = jnp.zeros((1, page_size, kwin.shape[-1]),
-                                      kwin.dtype)
-        # tpulint: disable=TPL402 -- same scratch-Ref store as above
-        vwin[pl.ds(j, 1)] = jnp.zeros((1, page_size, vwin.shape[-1]),
-                                      vwin.dtype)
-        if quantized:
-            # tpulint: disable=TPL402 -- same scratch-Ref store as above
-            scwin[pl.ds(j, 1)] = jnp.zeros((1, page_size, 128), scwin.dtype)
-        return _
-
-    jax.lax.fori_loop(npages, max_pages, ztail, 0)
-
-    # DMA semaphores count bytes: drain with same-sized descriptors
-    def drain_kv(i, _):
-        pltpu.make_async_copy(
-            kp_ref.at[pl.ds(0, 1)], kwin.at[pl.ds(0, 1)], kv_sem).wait()
-        return _
-
-    jax.lax.fori_loop(0, 2 * npages, drain_kv, 0)
-    if quantized:
-        def drain_sc(i, _):
-            pltpu.make_async_copy(
-                sc_ref.at[pl.ds(0, 1)], scwin.at[pl.ds(0, 1)],
-                sc_sem).wait()
-            return _
-
-        jax.lax.fori_loop(0, npages, drain_sc, 0)
-
-    mp = q_ref.shape[1]  # m rounded up to a sublane tile
-    col = jax.lax.broadcasted_iota(jnp.int32, (mp, seq), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (mp, seq), 0)
-    # causal per-position limits, clamped at the table capacity — the
-    # ref's `limit` expression verbatim
-    mask = col < jnp.minimum(base + row + 1, seq)
-    khd = kwin.shape[-1]
-    h_kv = khd // head_dim
-    group = num_heads // h_kv
-    if quantized:
-        scw = scwin[...].reshape(seq, 128)
-    for h in range(num_heads):
-        kh_ix = h // group
-        lo_q = h * head_dim
-        lo_kv = kh_ix * head_dim
-        qh = q_ref[0, :, lo_q:lo_q + head_dim].astype(jnp.float32)  # [mp,D]
-        kh = kwin[:, :, lo_kv:lo_kv + head_dim].reshape(
-            seq, head_dim).astype(jnp.float32)
-        if quantized:
-            kh = kh * scw[:, kh_ix:kh_ix + 1]
-        s = jax.lax.dot_general(
-            qh, kh, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [mp, seq]
-        s = jnp.where(mask, s, NEG_INF)
-        mx = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - mx)
-        # normalize BEFORE the dot — jax.nn.softmax's order, so the
-        # interpret-mode kernel is bitwise the jnp reference; fully
-        # masked rows degrade to the same uniform distribution
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
-        vh = vwin[:, :, lo_kv:lo_kv + head_dim].reshape(
-            seq, head_dim).astype(jnp.float32)
-        if quantized:
-            vh = vh * scw[:, h_kv + kh_ix:h_kv + kh_ix + 1]
-        out = jax.lax.dot_general(
-            p, vh, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [mp, D]
-        o_ref[0, :, lo_q:lo_q + head_dim] = out
+# The softmax is carried across windows and normalized after the PV dot,
+# where jax.nn.softmax normalizes before it: the kernel agrees with the jnp
+# reference to a few ulp of f32, not bitwise.
 
 
 def paged_verify_slab_attention(q, k_pages, v_pages, block_tables,
@@ -666,55 +641,25 @@ def paged_verify_slab_attention(q, k_pages, v_pages, block_tables,
     q [B, m, H, D] against slab pages [P, page_size, Hkv*D]; query j of
     row b attends the window tokens ``< base_len[b] + j + 1`` (cached
     context + causal prefix of the freshly written slab). Returns
-    [B, m, H, D] f32 — bitwise ``_paged_multi_query_ref`` in interpret
-    mode. ``scale_pages`` [P, ps, 128] bf16 activates the int8 path (k
+    [B, m, H, D] f32 — ``_paged_multi_query_ref`` to a few ulp.
+    ``scale_pages`` [P, ps, 128] bf16 activates the int8 path (k
     scales at lanes [0, Hkv), v at [Hkv, 2Hkv), the decode-slab layout).
 
-    VMEM: the window scratch matches the decode slab kernel; on top of
-    it the per-head score slab is [m_pad, max_pages*page_size] f32, so m
-    is engine-bounded (spec k+1, prefill_chunk, or the suffix bucket
-    ≤ max_position)."""
+    VMEM: one window of pages like the decode slab kernel; on top of it
+    the q/out blocks and the accumulator are [m_pad, H*D] and the per-head
+    score slab [m_pad, window] f32, so m is engine-bounded (spec k+1,
+    prefill_chunk, or the suffix bucket ≤ max_position)."""
     b, m, h, d = q.shape
-    p_total, page_size, khd = k_pages.shape
-    max_pages = block_tables.shape[1]
-    quantized = scale_pages is not None
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     mp = -(-m // _Q_ROWS) * _Q_ROWS
     qr = q.reshape(b, m, h * d)
     if mp != m:
         qr = jnp.pad(qr, ((0, 0), (0, mp - m), (0, 0)))
-    if scale_pages is None:
-        scale_pages = jnp.zeros((1, page_size, 128), jnp.bfloat16)
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_verify_slab_kernel, scale=scale, num_heads=h,
-            head_dim=d, m=m, page_size=page_size, max_pages=max_pages,
-            quantized=quantized),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b,),
-            in_specs=[
-                pl.BlockSpec((1, mp, h * d), lambda i, bl, bt: (i, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, mp, h * d),
-                                   lambda i, bl, bt: (i, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((max_pages, page_size, khd), k_pages.dtype),
-                pltpu.VMEM((max_pages, page_size, khd), k_pages.dtype),
-                pltpu.VMEM((max_pages, page_size, 128), jnp.bfloat16),
-                pltpu.SemaphoreType.DMA,
-                pltpu.SemaphoreType.DMA,
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, mp, h * d), jnp.float32),
-        interpret=interpret,
-    )(jnp.asarray(base_len, jnp.int32),
-      jnp.asarray(block_tables, jnp.int32), qr, k_pages, v_pages,
-      scale_pages)
+    out = _paged_window_call(
+        base_len, block_tables, qr, k_pages, v_pages, scale_pages,
+        jnp.float32, scale=scale, num_heads=h, head_dim=d, m=m,
+        interpret=interpret)
     return out[:, :m].reshape(b, m, h, d)
 
 

@@ -167,7 +167,7 @@ def _int4_kernel(xe_ref, xo_ref, w_ref, s_ref, *rest, grid_k):
     # ONE load of the packed bytes; both nibbles dequant in VMEM into a
     # single [bk, bn] slab — low-nibble rows stacked over high-nibble
     # rows (a tile-aligned sublane concat, not an interleave Mosaic
-    # would relayout), contracted by ONE full-depth MXU dot against the
+    # would re-layout), contracted by ONE full-depth MXU dot against the
     # activation's matching (even ‖ odd) K-column halves
     w = w_ref[:].astype(jnp.int32)  # [bk//2, bn]
     lo = jnp.right_shift(jnp.left_shift(w, 28), 28)
@@ -221,7 +221,7 @@ def quant_matmul_pallas(x, wq, scales, bias=None, weight_dtype="int8",
     if weight_dtype == "int4":
         wp = jnp.pad(wq, ((0, (kp - k) // 2), (0, np_ - n)))
         # even/odd activation columns split OUTSIDE the kernel — a cheap
-        # relayout of the tiny decode activation, never of the weight
+        # re-layout of the tiny decode activation, never of the weight
         operands += [x2[:, 0::2], x2[:, 1::2], wp, sc]
         in_specs += [
             pl.BlockSpec((rows_p, bk // 2), lambda j, kk: (0, kk)),
@@ -252,7 +252,7 @@ def quant_matmul_pallas(x, wq, scales, bias=None, weight_dtype="int8",
         out_specs=pl.BlockSpec((rows_p, bn), lambda j, kk: (0, j)),
         out_shape=jax.ShapeDtypeStruct((rows_p, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((rows_p, bn), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -194,11 +194,10 @@ def mfu(n_params: int, tokens_per_sec_per_chip: float,
     """North-star runtime readout (BASELINE.md convention: 6N model FLOPs,
     remat excluded, per-chip over per-chip)."""
     if peak_flops_per_chip is None:
-        kind = getattr(jax.devices()[0], "device_kind", "")
-        table = {"TPU v6": 918e12, "TPU v5p": 459e12, "TPU v5 lite": 197e12,
-                 "TPU v5e": 197e12, "TPU v4": 275e12}
-        peak_flops_per_chip = next(
-            (v for k, v in table.items() if kind.startswith(k)), 197e12
-        )
+        from ..analysis.jaxpr.cost import peak_flops
+
+        # raises on a device kind the table does not hold (CPU included):
+        # an MFU against another chip's peak is not an MFU
+        peak_flops_per_chip = peak_flops(jax.devices()[0])
     fpt = flops_per_token if flops_per_token is not None else 6.0 * n_params
     return tokens_per_sec_per_chip * fpt / peak_flops_per_chip

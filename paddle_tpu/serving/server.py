@@ -82,6 +82,9 @@ class ApiServer:
         self.model_name = model_name
         self.grace_s = float(grace_s)
         self._server: Optional[asyncio.AbstractServer] = None
+        # open connections (event-loop thread only): shutdown closes the
+        # ones a client left idle on keep-alive
+        self._conns: set = set()
         self._stop: Optional[asyncio.Event] = None
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self.vocab_size = int(frontend.engine.cfg.vocab_size)
@@ -117,6 +120,11 @@ class ApiServer:
         await loop.run_in_executor(None, self.frontend.drain, self.grace_s)
         if self._server is not None:
             self._server.close()
+            # wait_closed() waits for every connection (Python >= 3.12):
+            # the streams are drained, what is still open is a client
+            # idling on keep-alive, and it would hold shutdown forever
+            for writer in list(self._conns):
+                writer.close()
             await self._server.wait_closed()
 
     def request_stop(self):
@@ -128,6 +136,7 @@ class ApiServer:
     # ------------------------------------------------------------ plumbing
     async def _handle_conn(self, reader: asyncio.StreamReader,
                            writer: asyncio.StreamWriter):
+        self._conns.add(writer)
         try:
             while True:
                 req = await self._read_request(reader)
@@ -142,6 +151,7 @@ class ApiServer:
                 asyncio.IncompleteReadError):
             pass  # client went away; per-request cancel already handled
         finally:
+            self._conns.discard(writer)
             try:
                 writer.close()
                 await writer.wait_closed()

@@ -91,11 +91,7 @@ def load_ffi(name: str, sources: Sequence[str], functions: Sequence[str],
     the host even in TPU programs (TPU device code stays Pallas)."""
     import jax
 
-    # jax.ffi graduated from jax.extend.ffi after 0.4.x; same surface
-    try:
-        jax_ffi = jax.ffi
-    except AttributeError:
-        from jax.extend import ffi as jax_ffi
+    jax_ffi = jax.ffi
 
     inc = list(load_kwargs.pop("extra_include_paths", []) or [])
     inc.append(jax_ffi.include_dir())

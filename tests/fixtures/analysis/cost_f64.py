@@ -7,7 +7,7 @@ from paddle_tpu.analysis.jaxpr import analyze_fn
 
 
 def run():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def f(x, w):
             return jnp.dot(x, w)  # f64 in, f64 dot
 

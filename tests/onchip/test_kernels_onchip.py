@@ -157,6 +157,23 @@ class TestDecodeOnChip:
         want = _slab_ref(q, slab, lengths, 1 / 8.0)
         assert _err(got, want) < 2e-2
 
+    def test_slab_decode_llama_widths_several_windows(self, rng):
+        """32 heads x 128 = 4096 lanes, S=2048: K+V of the whole slab are
+        32 MiB a row, so the kernel walks it in 8 windows and carries the
+        softmax across them; short rows skip the windows past their end."""
+        from paddle_tpu.ops.pallas.decode_attention import (
+            _slab_pallas, _slab_ref, _slab_window)
+
+        B, H, D, S = 4, 32, 128, 2048
+        assert S // _slab_window(S, H * D, 2) > 1
+        q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
+        slab = jnp.asarray(rng.standard_normal((2, B, S, H * D)),
+                           jnp.bfloat16)
+        lengths = jnp.asarray([1, 300, 1500, S], jnp.int32)
+        got = _slab_pallas(q, slab, lengths, 1 / np.sqrt(D))
+        want = _slab_ref(q, slab, lengths, 1 / np.sqrt(D))
+        assert _err(got, want) < 2e-2
+
 
 class TestPagedOnChip:
     def _tables(self, rng, B, NP, PS, MAXP):
@@ -212,6 +229,40 @@ class TestPagedOnChip:
         assert _err(got, want) < 5e-2
 
 
+    @pytest.mark.parametrize("quantized", [False, True])
+    def test_slab_paged_llama_widths_several_windows(self, rng, quantized):
+        """llama2_7b() geometry: 4096 lanes, page 16, context 2048 = 128
+        pages a row, walked in windows of 32 (bf16) / 64 (int8) pages."""
+        from paddle_tpu.ops.pallas.paged_attention import (
+            _paged_slab_ref, _window_pages, paged_slab_decode_attention,
+            quantize_rows_int8)
+
+        B, H, D, PS, NP, MAXP = 4, 32, 128, 16, 600, 128
+        assert _window_pages(MAXP, PS, H * D, 1 if quantized else 2) < MAXP
+        q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.bfloat16)
+        bt, lengths = self._tables(rng, B, NP, PS, MAXP)
+        sc = None
+        if quantized:
+            kp, ks = quantize_rows_int8(jnp.asarray(
+                rng.standard_normal((NP, PS, H, D)), jnp.float32))
+            vp, vs = quantize_rows_int8(jnp.asarray(
+                rng.standard_normal((NP, PS, H, D)), jnp.float32))
+            sc = (jnp.zeros((NP, PS, 128), jnp.bfloat16)
+                  .at[..., :H].set(ks.astype(jnp.bfloat16))
+                  .at[..., H:2 * H].set(vs.astype(jnp.bfloat16)))
+            kp, vp = kp.reshape(NP, PS, H * D), vp.reshape(NP, PS, H * D)
+        else:
+            kp = jnp.asarray(rng.standard_normal((NP, PS, H * D)),
+                             jnp.bfloat16)
+            vp = jnp.asarray(rng.standard_normal((NP, PS, H * D)),
+                             jnp.bfloat16)
+        got = paged_slab_decode_attention(q, kp, vp, bt, lengths, H,
+                                          scale_pages=sc)
+        want = _paged_slab_ref(q, kp, vp, bt, lengths, 1 / np.sqrt(D),
+                               scale_pages=sc)
+        assert _err(got, want) < 5e-2
+
+
 class TestVerifySlabOnChip:
     """Mosaic-lowered fused verify/suffix slab attention (ISSUE 9) vs
     the jnp window-gather reference, plus the dispatch-shape contract:
@@ -259,6 +310,22 @@ class TestVerifySlabOnChip:
         got = paged_verify_slab_attention(
             q, st.k_pages, st.v_pages, st.block_tables, base,
             scale_pages=st.scale_pages)
+        want = _paged_multi_query_ref(q, st, base)
+        assert _err(got, want) < 5e-2
+
+    def test_kernel_llama_widths_several_windows(self, rng):
+        """m=5 (spec verify's k+1) at llama2_7b() geometry: 4 windows."""
+        from paddle_tpu.ops.pallas.paged_attention import (
+            _paged_multi_query_ref, _window_pages,
+            paged_verify_slab_attention)
+
+        B, H, D, PS, NP, MAXP, m = 4, 32, 128, 16, 600, 128, 5
+        assert _window_pages(MAXP, PS, H * D, 2) < MAXP
+        st = self._state(rng, B, H, D, PS, NP, MAXP)
+        base = jnp.asarray([0, 17, 1000, MAXP * PS - m], jnp.int32)
+        q = jnp.asarray(rng.standard_normal((B, m, H, D)), jnp.bfloat16)
+        got = paged_verify_slab_attention(
+            q, st.k_pages, st.v_pages, st.block_tables, base)
         want = _paged_multi_query_ref(q, st, base)
         assert _err(got, want) < 5e-2
 

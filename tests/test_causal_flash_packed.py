@@ -184,3 +184,57 @@ class TestPackedInModel:
         for name in g0:
             np.testing.assert_allclose(g0[name], g1[name], atol=2e-3,
                                        rtol=2e-3, err_msg=name)
+
+
+class TestPackedUnderFleetMesh:
+    """A Mosaic kernel cannot be partitioned by GSPMD: under the mesh fleet
+    sets, the packed path runs the kernel once per shard (batch over dp,
+    head groups over mp — ops/pallas/sharded.py). Same loss and gradients
+    as the plain call; the compile for the chip is tests/test_chip_compile."""
+
+    def test_gpt_loss_and_grads_match_the_unsharded_step(self, rng,
+                                                         monkeypatch):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from paddle_tpu.distributed import parallel
+        from paddle_tpu.distributed.topology import build_mesh
+        from paddle_tpu.framework.flags import set_flags
+        from paddle_tpu.framework.tensor import Tensor
+        from paddle_tpu.jit import functional_call, param_arrays
+        from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+        # 4 heads of 64 pair-pack into 2 groups: mp=2 gives each shard one
+        cfg = GPTConfig(hidden_size=256, num_layers=1, num_heads=4,
+                        max_position=128, vocab_size=128)
+        model = GPTForCausalLM(cfg)
+        model.eval()
+        params = param_arrays(model)
+        ids = jnp.asarray(rng.integers(0, 128, (4, 128)), jnp.int32)
+
+        def loss_fn(p, ids):
+            logits = functional_call(model, p, Tensor._wrap(ids))
+            return jnp.mean(jax.nn.logsumexp(logits, axis=-1)
+                            - logits[..., 0])
+
+        # the mesh is process-global (an earlier test's fleet.init leaves
+        # one behind): no mesh for the plain step, then dp2 x mp2
+        monkeypatch.setattr(parallel, "_global_mesh", None)
+        set_flags({"FLAGS_use_packed_attention": True})
+        try:
+            l0, g0 = jax.jit(jax.value_and_grad(loss_fn))(params, ids)
+            mesh = build_mesh(dp=2, mp=2)
+            monkeypatch.setattr(parallel, "_global_mesh", mesh)
+            assert parallel.mesh_if_set() is mesh
+            jaxpr = str(jax.make_jaxpr(loss_fn)(params, ids))
+            assert "shard_map" in jaxpr and "pallas_call" in jaxpr
+            with mesh:
+                l1, g1 = jax.jit(jax.value_and_grad(loss_fn))(
+                    params, jax.device_put(
+                        ids, NamedSharding(mesh, P("dp", None))))
+        finally:
+            set_flags({"FLAGS_use_packed_attention": None})
+        assert abs(float(l0) - float(l1)) < 1e-5, (l0, l1)
+        for name in g0:
+            np.testing.assert_allclose(np.asarray(g0[name]),
+                                       np.asarray(g1[name]), atol=1e-5,
+                                       rtol=1e-4, err_msg=name)

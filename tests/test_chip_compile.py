@@ -1,0 +1,235 @@
+"""The chip's compiler, asked without the chip: the Pallas kernels of the
+served and trained paths compile for a DESCRIBED TPU v5e at the widths
+``chip_smoke.py`` runs them at (``llama2_7b()``: 32 heads x 128 = 4096
+lanes, FF 11008; ``gpt2_medium()``: 16 heads x 64, S=1024), at the
+2048-wide / 16 x 128 geometry ROADMAP B1 needs next, and at the GPT-2
+small geometry the kernels were first written at.
+
+Interpret mode cannot show what this shows: a kernel that keeps more in
+fast memory than the chip grants, or slices off the tiling, passes every
+interpret-mode test and is refused here (the whole-sequence-resident slab
+kernels were, at 4096 lanes x 2048 tokens). A compile that passes is not
+a chip run — nothing executes; ``chip_smoke.py`` is the run.
+
+The topology is described inside a module-scoped fixture, in this file
+only, and the compiles run in the test's own process: one process at a
+time may load the TPU's library, so nothing here touches it at import, in
+a ``skipif`` or in ``parametrize``, and no other file may do the same.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BF16 = jnp.bfloat16
+# (heads, kv_heads, head_dim, page_size, context)
+LLAMA_7B = (32, 32, 128, 16, 2048)
+LLAMA_7B_MAXPOS = (32, 32, 128, 16, 4096)  # llama2_7b().max_position
+WIDE_2048 = (16, 16, 128, 16, 2048)
+GPT2_SMALL = (12, 12, 64, 16, 1024)
+GEOMETRIES = pytest.mark.parametrize(
+    "geom", [LLAMA_7B, LLAMA_7B_MAXPOS, WIDE_2048, GPT2_SMALL],
+    ids=["llama7b-ctx2048", "llama7b-ctx4096", "16x128-ctx2048",
+         "gpt2small-ctx1024"])
+BATCH, POOL_PAGES = 8, 1024
+
+
+def _mod(name):
+    # the package re-exports functions under some of its modules' names
+    return importlib.import_module(f"paddle_tpu.ops.pallas.{name}")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever the plugin raises when it cannot
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    jcc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    jcc.reset_cache()
+
+
+@pytest.fixture
+def chip(one_chip, no_persistent_cache, monkeypatch):
+    """``compiles(fn, *shapes)``: lower ``fn`` for the described chip and
+    require the Mosaic kernel in the compiled program. The dispatchers ask
+    ``jax.default_backend()`` (the CPU here) and would take their jnp
+    twins, so the test steers their ``_interpret`` to the chip branch."""
+    for name in ("paged_attention", "decode_attention", "causal_flash",
+                 "flash_attention"):
+        monkeypatch.setattr(_mod(name), "_interpret", lambda: False)
+
+    def compiles(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text, \
+            "compiled, but without the Mosaic kernel (a jnp twin ran)"
+
+    return compiles
+
+
+def _pages(geom, dtype=BF16):
+    h, hkv, d, ps, ctx = geom
+    page = ((POOL_PAGES, ps, hkv * d), dtype)
+    return [page, page, ((BATCH, ctx // ps), jnp.int32),
+            ((BATCH,), jnp.int32)]
+
+
+@GEOMETRIES
+def test_paged_slab_decode(chip, geom):
+    pa = _mod("paged_attention")
+    h, hkv, d, ps, ctx = geom
+    chip(lambda q, k, v, bt, ln: pa.paged_slab_decode_attention(
+        q, k, v, bt, ln, h), ((BATCH, h, d), BF16), *_pages(geom))
+
+
+def test_paged_slab_decode_int8_pages(chip):
+    pa = _mod("paged_attention")
+    h, hkv, d, ps, ctx = LLAMA_7B
+    chip(lambda q, k, v, bt, ln, sc: pa.paged_slab_decode_attention(
+        q, k, v, bt, ln, h, scale_pages=sc),
+        ((BATCH, h, d), BF16), *_pages(LLAMA_7B, jnp.int8),
+        ((POOL_PAGES, ps, 128), BF16))
+
+
+@GEOMETRIES
+def test_paged_verify_slab_m4(chip, geom):
+    pa = _mod("paged_attention")
+    h, hkv, d, ps, ctx = geom
+    chip(lambda q, k, v, bt, base: pa.paged_verify_slab_attention(
+        q, k, v, bt, base), ((BATCH, 4, h, d), BF16), *_pages(geom))
+
+
+@GEOMETRIES
+def test_contiguous_slab_decode(chip, geom):
+    da = _mod("decode_attention")
+    h, hkv, d, ps, ctx = geom
+    chip(lambda q, kv, ln: da.decode_attention_slab(q, kv, ln),
+         ((BATCH, h, d), BF16), ((2, BATCH, ctx, hkv * d), BF16),
+         ((BATCH,), jnp.int32))
+
+
+# decode-shaped GEMMs of the two widths: [rows, K] x [K, N]
+GEMMS = pytest.mark.parametrize(
+    "k,n", [(4096, 11008), (11008, 4096), (4096, 32000), (2048, 5632)],
+    ids=["llama7b-up", "llama7b-down", "llama7b-head", "2048-wide-up"])
+
+
+@GEMMS
+@pytest.mark.parametrize("weight_dtype", ["int8", "int4"])
+def test_quant_matmul(chip, weight_dtype, k, n):
+    qm = _mod("quant_matmul")
+    rows_k = k // 2 if weight_dtype == "int4" else k  # two nibbles a byte
+    chip(lambda x, w, s: qm.quant_matmul_pallas(
+        x, w, s, weight_dtype=weight_dtype, interpret=False),
+        ((BATCH, k), BF16), ((rows_k, n), jnp.int8), ((n,), jnp.float32))
+
+
+@pytest.mark.parametrize("k,n", [(4096, 11008), (2048, 5632)],
+                         ids=["llama7b-ff", "2048-wide-ff"])
+def test_grouped_matmul(chip, k, n):
+    gm = _mod("grouped_matmul")
+    e, rows = 8, 1024
+    chip(lambda x, w, g: gm.grouped_matmul_pallas(x, w, g, interpret=False),
+         ((rows, k), BF16), ((e, k, n), BF16), ((e,), jnp.int32))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_causal_flash_qkv_gpt_medium(chip, grad):
+    """The packed-attention path of the train phase: gpt2_medium(), batch
+    12, S=1024 — 16 heads x 64 pair-packed into 128-lane blocks."""
+    cf = _mod("causal_flash")
+    heads, d, seq = 16, 64, 1024
+    hpb = cf.heads_per_block(heads, d)
+
+    def fwd(qkv):
+        return cf.causal_flash_qkv(qkv, heads, d)
+
+    fn = fwd if not grad else jax.grad(
+        lambda qkv: fwd(qkv).astype(jnp.float32).sum())
+    chip(fn, ((12, 3 * heads // hpb, seq, hpb * d), BF16))
+
+
+@pytest.mark.parametrize("batch,seq", [(1, 2048), (8, 1024), (8, 128)])
+def test_flash_attention_llama_prefill(chip, batch, seq):
+    """The serve phase's prefill: ``F.flash_attention`` at head_dim 128,
+    one prompt-length bucket a case."""
+    fa = _mod("flash_attention")
+    q = ((batch, seq, 32, 128), BF16)
+    chip(lambda q, k, v: fa.flash_attention_fused(q, k, v, causal=True),
+         q, q, q)
+
+
+def test_attention_kernels_under_the_fleet_mesh(topo, no_persistent_cache,
+                                                monkeypatch):
+    """Across chips: a Mosaic kernel cannot be partitioned automatically, so
+    a jit over the 2x2 mesh that reaches one is refused when lowered for the
+    chip — unless its dispatcher runs it per shard (ops/pallas/sharded.py).
+    dp=2 x mp=2 as ``chip_smoke.py --chips 4`` trains: the packed kernel at
+    gpt2_medium() widths, ``F.flash_attention``'s at llama widths."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.distributed import parallel
+    from paddle_tpu.distributed.topology import HYBRID_AXES
+    from paddle_tpu.ops.pallas.sharded import per_shard
+
+    cf, fa = _mod("causal_flash"), _mod("flash_attention")
+    monkeypatch.setattr(cf, "_interpret", lambda: False)
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 1, 1, 1, 2),
+                HYBRID_AXES)
+
+    def on_mesh(shape, *spec):
+        return jax.ShapeDtypeStruct(shape, BF16,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    # gpt2_medium(): 16 heads x 64 pair-packed -> [B, 3, 8 groups, S, 128]
+    qkv5 = on_mesh((12, 3, 8, 1024, 128), "dp", None, "mp")
+
+    def packed(qkv5):
+        b, _, g, s, lanes = qkv5.shape
+        return cf.causal_flash_qkv(qkv5.reshape(b, 3 * g, s, lanes),
+                                   2 * g, 64)
+
+    qkv = on_mesh((8, 1024, 32, 128), "dp", None, "mp")
+
+    def flash(q, k, v):
+        return fa.flash_attention_fused(q, k, v, causal=True)
+
+    monkeypatch.setattr(parallel, "_global_mesh", None)
+    with pytest.raises(NotImplementedError, match="automatically partition"):
+        jax.jit(packed).lower(qkv5)
+
+    monkeypatch.setattr(parallel, "_global_mesh", mesh)
+    for fn, args in (
+            (lambda x: per_shard(packed, [x], dims=[(0, 2)],
+                                 out_dims=(0, 1), out_ndim=4), (qkv5,)),
+            (lambda q, k, v: per_shard(flash, [q, k, v], dims=[(0, 2)] * 3,
+                                       out_dims=(0, 2), out_ndim=4),
+             (qkv,) * 3)):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text

@@ -69,10 +69,23 @@ class TestSlabKernel:
     fast path; _slab_pallas exercised in interpret mode, plus the
     layout-polymorphic cache_decode_step dispatch."""
 
-    @pytest.mark.parametrize("b,h,hkv,s,d", [(2, 4, 4, 16, 32),
-                                             (1, 4, 2, 24, 64)])
-    def test_slab_pallas_interpret(self, rng, b, h, hkv, s, d):
-        from paddle_tpu.ops.pallas.decode_attention import _slab_pallas
+    # window_bytes: None = the sequence fits one window (the whole-resident
+    # arithmetic); a small budget forces 16-token windows, so the softmax is
+    # carried across 4 of them and windows past a short row are skipped
+    @pytest.mark.parametrize("b,h,hkv,s,d,window_bytes", [
+        (2, 4, 4, 16, 32, None), (1, 4, 2, 24, 64, None),
+        (3, 4, 2, 64, 32, 16 * 2 * 2 * 64 * 4)])
+    def test_slab_pallas_interpret(self, rng, monkeypatch, b, h, hkv, s, d,
+                                   window_bytes):
+        import importlib
+
+        # the package re-exports a FUNCTION under the module's name
+        mod = importlib.import_module(
+            "paddle_tpu.ops.pallas.decode_attention")
+        _slab_pallas = mod._slab_pallas
+        if window_bytes is not None:
+            monkeypatch.setattr(mod, "_WINDOW_BYTES", window_bytes)
+            assert mod._slab_window(s, hkv * d, 4) == 16
 
         q = rng.standard_normal((b, h, d)).astype(np.float32)
         kc = rng.standard_normal((b, hkv, s, d)).astype(np.float32)

@@ -404,17 +404,27 @@ class TestCommModel:
         assert e1.overlap_fraction > 0.9
         assert e2.overlap_fraction < e1.overlap_fraction
 
-    def test_ici_tables_cover_device_kinds(self):
-        from paddle_tpu.analysis.jaxpr import hbm_bw, ici_bw
-        from paddle_tpu.analysis.jaxpr.cost import HBM_BYTES_PER_SEC
-        from paddle_tpu.analysis.jaxpr.comm import ICI_BYTES_PER_SEC
+    def test_one_peak_table_and_unknown_kind_raises(self):
+        from paddle_tpu.analysis.jaxpr import hbm_bw, ici_bw, peak_flops
+        from paddle_tpu.analysis.jaxpr.cost import DEVICE_PEAKS
+        from paddle_tpu.analysis.jaxpr.planner import hbm_capacity
+        from paddle_tpu.profiler import mfu
 
-        # one source of truth: every compute-table device has an ICI row
-        assert set(ICI_BYTES_PER_SEC) == set(HBM_BYTES_PER_SEC)
-        for kind in ICI_BYTES_PER_SEC:
+        for kind in DEVICE_PEAKS:
             # ICI is always the slower fabric — a sanity invariant the
             # comm-bound advisory depends on
             assert ici_bw(kind) < hbm_bw(kind)
+        # the attached v5e reports itself as "TPU v5 lite"
+        assert peak_flops("TPU v5 lite") == peak_flops("TPU v5e") == 197e12
+        # a device the table does not hold is an error, never another
+        # chip's numbers: an unknown TPU, the CPU, an empty kind
+        for kind in ("TPU v9x", "cpu", ""):
+            for fn in (peak_flops, hbm_bw, ici_bw, hbm_capacity):
+                with pytest.raises(ValueError, match="unknown device kind"):
+                    fn(kind)
+        # profiler.mfu asks the attached device (the CPU here)
+        with pytest.raises(ValueError, match="unknown device kind"):
+            mfu(n_params=1e9, tokens_per_sec_per_chip=1000)
 
 
 class TestHostDivergence:

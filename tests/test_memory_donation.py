@@ -159,24 +159,74 @@ class TestDonationCorrectness:
                                       np.asarray(b.numpy()))
 
 
-class TestCompilationCache:
-    def test_enable_and_populate(self, tmp_path):
-        from paddle_tpu.framework.compile_cache import (
-            compilation_cache_dir, enable_compilation_cache)
+@pytest.fixture
+def fresh_cache_state(monkeypatch):
+    """enable_compilation_cache() is process-global: hand each test a
+    not-yet-enabled module and put jax's settings back afterwards, so the
+    rest of this worker's tests do not run against a persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache as jcc
 
-        d = enable_compilation_cache(str(tmp_path / "xla"))
-        assert compilation_cache_dir() == d
-        f = jax.jit(lambda x: x * 3 + 1)
-        f(jnp.arange(17.0)).block_until_ready()
+    from paddle_tpu.framework import compile_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+    yield compile_cache
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    jcc.reset_cache()
+
+
+class TestCompilationCache:
+    def test_env_var_set_no_directory_set_in_code(self, fresh_cache_state,
+                                                  monkeypatch, tmp_path):
+        """Where JAX_COMPILATION_CACHE_DIR is set, jax's own handling of
+        it decides: the program names no directory."""
+        cc = fresh_cache_state
+        monkeypatch.setenv(cc.ENV_VAR, str(tmp_path / "outside"))
+        updated = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda name, val: (updated.append(name),
+                               real_update(name, val))[1])
+        d = cc.enable_compilation_cache()
+        assert "jax_compilation_cache_dir" not in updated, updated
+        assert d == jax.config.jax_compilation_cache_dir
+        assert cc.compilation_cache_dir() == d
+        assert cc.maybe_enable_from_env() == d
+
+    def test_env_var_unset_fixed_path_in_checkout(self, fresh_cache_state,
+                                                  monkeypatch):
         import os
 
-        entries = os.listdir(d)
-        assert entries, "compilation cache not populated"
+        cc = fresh_cache_state
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+        assert cc.maybe_enable_from_env() is None
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+        d = cc.enable_compilation_cache()
+        assert d == cc.DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == d
+        assert cc.enable_compilation_cache() == d  # idempotent
+        f = jax.jit(lambda x: x * 3 + 1)
+        f(jnp.arange(17.0)).block_until_ready()
+        assert os.listdir(d), "compilation cache not populated"
 
-    def test_supervisor_exports_cache_env(self, tmp_path):
+    def test_supervisor_exports_cache_env(self, tmp_path, monkeypatch):
         from paddle_tpu.distributed.launch.controllers import (
             ElasticSupervisor)
 
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         sup = ElasticSupervisor(lambda r: ["true"], 1, ["127.0.0.1:0"],
                                 log_dir=str(tmp_path))
         assert sup.compile_cache_dir == str(tmp_path / "xla_cache")
+        # a cache placed from outside wins over the next-to-the-logs default
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        sup = ElasticSupervisor(lambda r: ["true"], 1, ["127.0.0.1:0"],
+                                log_dir=str(tmp_path))
+        assert sup.compile_cache_dir == "/placed/outside"

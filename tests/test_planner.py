@@ -116,11 +116,11 @@ class TestCommittedCalibration:
         import jax.numpy as jnp
 
         from paddle_tpu.analysis.jaxpr.comm import comm_rollup
-        from paddle_tpu.distributed.jax_compat import virtual_mesh
+        from paddle_tpu.distributed.jax_compat import (shard_map,
+                                                       virtual_mesh)
 
         mesh = virtual_mesh({"dp": 8})
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
         def body(x):
             def step(c, _):
@@ -129,8 +129,7 @@ class TestCommittedCalibration:
             out, _ = jax.lax.scan(step, x, None, length=5)
             return out
 
-        fn = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                       check_rep=False)
+        fn = shard_map(body, mesh, in_specs=P(), out_specs=P())
         closed = jax.make_jaxpr(fn)(jnp.ones((4, 4), jnp.float32))
         est = comm_rollup(closed, mesh=mesh)
         assert est.n_collectives == 5.0

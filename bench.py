@@ -920,10 +920,8 @@ def main():
             metric_total("paddle_tpu_replica_quarantines_total")),
         "integrity_overhead_frac": integrity.get(
             "integrity_overhead_frac", 0.0),
-        # request-tracing surface (ISSUE 18): spans committed to the
-        # ring across the whole run and the overhead block's own gate
-        "trace_spans_total": int(
-            metric_total("paddle_tpu_trace_spans_total")),
+        # request-tracing surface (ISSUE 18): the overhead block's own
+        # gate
         "trace_overhead_frac": trace.get("trace_overhead_frac", 0.0),
         # thread-ownership guard surface (ISSUE 19): the runtime twin
         # of `make races` — armed-vs-disarmed step overhead on a fully

@@ -215,6 +215,21 @@ from .errors import (
 from .watchdog import Watchdog
 
 
+def _phase(name):
+    """Run the method inside the tracer's nested span ``name``: one phase
+    of a scheduling step on the engine thread, a child of ``engine.step``
+    (or of the phase that called it), so a phase's self time is its span
+    less its children. With the tracer off the span is the profiler's
+    annotation alone, which is what joins the host to a device trace."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def run(self, *args, **kwargs):
+            with _TRACER.nested(name, "engine"):
+                return fn(self, *args, **kwargs)
+        return run
+    return deco
+
+
 @jax.jit
 def _advance_sample_key(key, burns):
     """Replay ``burns`` sampling-key splits host-free (one fori_loop
@@ -1887,6 +1902,7 @@ class Engine:
                 [req.prompt, np.asarray(req.tokens, np.int32)])
         return req.prompt
 
+    @_phase("engine.admit")
     def _admit_dispatch(self):
         """Dispatch one bucketed prefill for ALL admissible queued
         requests WITHOUT blocking (rows pad to pow2, prompts to a shared
@@ -1984,6 +2000,7 @@ class Engine:
                             slot=req.slot,
                             promote_wait_s=req._t_promote_wait)
 
+    @_phase("engine.prefill_dispatch")
     def _prefill_wave(self, rows):
         """Dispatch ONE bucketed prefill for ``rows`` of (req, prefix,
         table_row, base) — shared by admission and pre-admission. Returns
@@ -2361,6 +2378,7 @@ class Engine:
             if p:
                 self._release_page(int(p))
 
+    @_phase("engine.admit")
     def _preadmit_dispatch(self, k, exclude=()):
         """PRE-ADMISSION (VERDICT r4 #2, the last serve-vs-steady gap):
         while the just-dispatched chain runs, prefill the queue heads
@@ -2585,6 +2603,7 @@ class Engine:
             (tok_d, keys_d, bad_d)))
         self._mixed_harvest(slots, widths, tok, keys_h, bad_h)
 
+    @_phase("engine.mixed_dispatch")
     def _mixed_dispatch(self, slots):
         """Build + dispatch ONE mixed chunk+decode program over exactly
         ``slots`` (rows pad to the fixed max_slots bucket; slots not
@@ -2639,6 +2658,7 @@ class Engine:
         self._note_moe_stats(ex)
         return slots, widths, tok_d, keys_d, bad_d
 
+    @_phase("engine.harvest")
     def _mixed_harvest(self, slots, widths, tok, keys_h, bad_h):
         """Host harvest of a mixed dispatch: advance chunk state, take
         tokens from emitting rows, per-request fault isolation."""
@@ -2687,6 +2707,7 @@ class Engine:
                 self._fail_request(req, self._wrap_step_fault(e, req))
 
     # ------------------------------- prefill/decode disaggregation (ISSUE 11)
+    @_phase("engine.chain_dispatch")
     def _chain_dispatch(self, slots, k):
         """Dispatch a decode chain over exactly ``slots`` (compacted to
         their own pow2 bucket) — the decode-role half of a disaggregated
@@ -2719,6 +2740,7 @@ class Engine:
         self._note_moe_stats(ex)
         return (slots, slot_reqs, toks_d, lengths_d, keys_d, bad_d)
 
+    @_phase("engine.harvest")
     def _chain_harvest(self, slots, slot_reqs, toks, lengths_h, keys_h,
                        bad_h):
         """Host harvest of a decode chain (per-request isolation — the
@@ -2878,54 +2900,60 @@ class Engine:
             self._expire_deadlines()
         budget = self.multi_step if n is None else max(1, int(n))
         batched = 1
-        try:
-            # KV-tier completions land at the step boundary (ISSUE 15):
-            # even a step that admits nothing applies finished spills/
-            # promotions, so the tier converges while the engine decodes
-            self._cache.drain_tier()
-            if self._wants_mixed():
-                if self.disaggregate:
+        # which of the five step paths this round trip takes
+        if self._wants_mixed():
+            path = "disagg" if self.disaggregate else "mixed"
+        elif self._spec is not None and self._spec_enabled:
+            path = "spec"
+        elif budget > 1 and self._active and not self._queue:
+            path = "multi_chained"
+        else:
+            path = "chained"
+        # the ONE engine.step span site, whatever the path: open for the
+        # whole round trip, its phases (engine.admit, .prefill_dispatch,
+        # .chain_dispatch, .harvest) nest under it
+        with _TRACER.nested("engine.step", "engine",
+                            path=path) as step_span:
+            try:
+                # KV-tier completions land at the step boundary (ISSUE 15):
+                # even a step that admits nothing applies finished spills/
+                # promotions, so the tier converges while the engine decodes
+                self._cache.drain_tier()
+                if path == "disagg":
                     self._disagg_step()
-                else:
+                elif path == "mixed":
                     self._mixed_step()
-            elif self._spec is not None and self._spec_enabled:
-                self._spec_step()
-            elif budget > 1 and self._active and not self._queue:
-                batched = self._multi_chained_step(budget)
-            else:
-                self._chained_step(t0)
-            self._watchdog.note_step_ok()
-            if self._integrity is not None:
-                # online SDC audits (ISSUE 14): weight-shard probe on
-                # idle steps, shadow recompute every N — host-side,
-                # never raises (detections route through quarantine /
-                # _fail_request inside the sentinel)
-                self._integrity.on_step()
-        except Exception as e:
-            self._recover_step_fault(e)
-        if self._moe_stats_n:
-            # router-stats handles fold at the step boundary: their
-            # producing programs were fenced by the harvest above, so
-            # this never blocks on in-flight compute
-            self._drain_moe_stats()
-        if self._m is not None:
-            self._m.steps_per_roundtrip.observe(batched)
-            self._m.step_seconds.observe(time.perf_counter() - t0)
-            self._m.active_slots.set(len(self._active))
-            self._m.queue_depth.set(len(self._queue))
-            self._m.pages_in_use.set(
-                self.num_pages - 1 - len(self._free_pages))
-            if self._pcache is not None:
-                self._m.pc_pages.set(self._pcache.n_pages)
-        if _TRACER.enabled:
-            # retroactive step span: start + duration are both known
-            # here, so no open-span bookkeeping rides the hot path
-            _TRACER.complete(
-                "engine.step", "engine",
-                time.time() - (time.perf_counter() - t0),
-                time.perf_counter() - t0,
-                active=len(self._active), queued=len(self._queue),
-                batched=batched)
+                elif path == "spec":
+                    self._spec_step()
+                elif path == "multi_chained":
+                    batched = self._multi_chained_step(budget)
+                else:
+                    self._chained_step(t0)
+                self._watchdog.note_step_ok()
+                if self._integrity is not None:
+                    # online SDC audits (ISSUE 14): weight-shard probe on
+                    # idle steps, shadow recompute every N — host-side,
+                    # never raises (detections route through quarantine /
+                    # _fail_request inside the sentinel)
+                    self._integrity.on_step()
+            except Exception as e:
+                self._recover_step_fault(e)
+            if self._moe_stats_n:
+                # router-stats handles fold at the step boundary: their
+                # producing programs were fenced by the harvest above, so
+                # this never blocks on in-flight compute
+                self._drain_moe_stats()
+            if self._m is not None:
+                self._m.steps_per_roundtrip.observe(batched)
+                self._m.step_seconds.observe(time.perf_counter() - t0)
+                self._m.active_slots.set(len(self._active))
+                self._m.queue_depth.set(len(self._queue))
+                self._m.pages_in_use.set(
+                    self.num_pages - 1 - len(self._free_pages))
+                if self._pcache is not None:
+                    self._m.pc_pages.set(self._pcache.n_pages)
+            step_span.set(active=len(self._active),
+                          queued=len(self._queue), batched=batched)
         return len(self._queue) + len(self._active)
 
     def _recover_step_fault(self, exc: BaseException):
@@ -2993,60 +3021,62 @@ class Engine:
                 self._chain_depth(),
                 lambda slot, req, kk: self._alloc_len(req, kk))
         if self._active:
-            # compact active slots into a pow2 bucket: per-token cost
-            # follows load, not max_slots capacity
-            slots = sorted(self._active)
-            slot_reqs = [self._active[s] for s in slots]
-            n = len(slots)
-            nb = _pow2ceil(n)
-            if self._m is not None:
-                self._m.chain_depth_at(k).inc()
-                self._m.decode_batch.observe(n)
-            tables_c = np.zeros((nb, self.max_pages_per_seq), np.int32)
-            lengths_c = np.zeros((nb,), np.int32)
-            last_c = np.zeros((nb,), np.int32)
-            temps_c = np.zeros((nb,), np.float32)
-            keys_c = np.zeros((nb, 2), np.uint32)
-            tables_c[:n] = self.tables[slots]
-            lengths_c[:n] = self.lengths[slots]
-            last_c[:n] = self._last_tok[slots]
-            temps_c[:n] = self._temps[slots]
-            keys_c[:n] = self._keys[slots]
-            last_in = jnp.asarray(last_c)
-            keys_in = jnp.asarray(keys_c)
-            if admits:
-                # admitted slots' first token / key state live ONLY on
-                # device (prefill outputs): splice them into the chain
-                # inputs with a tiny scatter — still no host sync
-                row_of = {s: i for i, s in enumerate(slots)}
-                nba = int(pre_tok.shape[0])
-                rows = np.full((nba,), nb, np.int32)  # OOB pads drop
-                for i, (_, slot, *_rest) in enumerate(admits):
-                    rows[i] = row_of.get(slot, nb)  # preempted → drop
-                last_in, keys_in = _patch_rows(
-                    last_in, keys_in, jnp.asarray(rows), pre_tok,
-                    pre_keys)
-            sampling = bool(np.any(temps_c > 0.0))
-            fresh = (nb, k, sampling) not in self._decode_fns
-            decode = self._get_decode(nb, k, sampling)
-            # the whole chain is ONE compiled scan: one dispatch; the ONLY
-            # blocking fetch of the step happens below and covers the
-            # prefill results too
-            toks_d, pages, lengths_d, keys_d, bad_d, *ex = decode(
-                self._params, self._pages_flat(), jnp.asarray(tables_c),
-                jnp.asarray(lengths_c), last_in,
-                jnp.asarray(temps_c), keys_in)
-            self._set_pages(pages)
-            self._note_moe_stats(ex)
-            chain = (slots, slot_reqs, nb, k, fresh, toks_d, lengths_d,
-                     keys_d, bad_d)
-            # queue heads whose slots this chain will free prefill NOW,
-            # in the chain's shadow
-            pending, pend_tok, pend_keys, pend_bad = self._preadmit_dispatch(
-                k, exclude=[r for r, *_ in admits])
-            # registered for step-fault recovery: pending requests live
-            # outside queue AND active until _activate_pending commits
-            self._pending_inflight = pending
+            with _TRACER.nested("engine.chain_dispatch", "engine"):
+                # compact active slots into a pow2 bucket: per-token cost
+                # follows load, not max_slots capacity
+                slots = sorted(self._active)
+                slot_reqs = [self._active[s] for s in slots]
+                n = len(slots)
+                nb = _pow2ceil(n)
+                if self._m is not None:
+                    self._m.chain_depth_at(k).inc()
+                    self._m.decode_batch.observe(n)
+                tables_c = np.zeros((nb, self.max_pages_per_seq), np.int32)
+                lengths_c = np.zeros((nb,), np.int32)
+                last_c = np.zeros((nb,), np.int32)
+                temps_c = np.zeros((nb,), np.float32)
+                keys_c = np.zeros((nb, 2), np.uint32)
+                tables_c[:n] = self.tables[slots]
+                lengths_c[:n] = self.lengths[slots]
+                last_c[:n] = self._last_tok[slots]
+                temps_c[:n] = self._temps[slots]
+                keys_c[:n] = self._keys[slots]
+                last_in = jnp.asarray(last_c)
+                keys_in = jnp.asarray(keys_c)
+                if admits:
+                    # admitted slots' first token / key state live ONLY on
+                    # device (prefill outputs): splice them into the chain
+                    # inputs with a tiny scatter — still no host sync
+                    row_of = {s: i for i, s in enumerate(slots)}
+                    nba = int(pre_tok.shape[0])
+                    rows = np.full((nba,), nb, np.int32)  # OOB pads drop
+                    for i, (_, slot, *_rest) in enumerate(admits):
+                        rows[i] = row_of.get(slot, nb)  # preempted → drop
+                    last_in, keys_in = _patch_rows(
+                        last_in, keys_in, jnp.asarray(rows), pre_tok,
+                        pre_keys)
+                sampling = bool(np.any(temps_c > 0.0))
+                fresh = (nb, k, sampling) not in self._decode_fns
+                decode = self._get_decode(nb, k, sampling)
+                # the whole chain is ONE compiled scan: one dispatch; the ONLY
+                # blocking fetch of the step happens below and covers the
+                # prefill results too
+                toks_d, pages, lengths_d, keys_d, bad_d, *ex = decode(
+                    self._params, self._pages_flat(), jnp.asarray(tables_c),
+                    jnp.asarray(lengths_c), last_in,
+                    jnp.asarray(temps_c), keys_in)
+                self._set_pages(pages)
+                self._note_moe_stats(ex)
+                chain = (slots, slot_reqs, nb, k, fresh, toks_d, lengths_d,
+                         keys_d, bad_d)
+                # queue heads whose slots this chain will free prefill NOW,
+                # in the chain's shadow
+                pending, pend_tok, pend_keys, pend_bad = \
+                    self._preadmit_dispatch(
+                        k, exclude=[r for r, *_ in admits])
+                # registered for step-fault recovery: pending requests live
+                # outside queue AND active until _activate_pending commits
+                self._pending_inflight = pending
         else:
             if self._queue and not admits:
                 # queued but nothing active and no admission possible:
@@ -3055,56 +3085,58 @@ class Engine:
                 self._note_stall()
             pending, pend_tok, pend_keys, pend_bad = [], None, None, None
         # ---- single harvest fence for prefill + chain + pre-admission ----
-        fetched = jax.device_get((
-            pre_tok, pre_keys, pre_bad, pend_tok, pend_keys, pend_bad,
-            *(chain[5:] if chain else ())))
-        if admits:
-            self._harvest_admits(admits, fetched[0], fetched[1], fetched[2])
-        if chain:
-            slots, slot_reqs, nb, k, fresh, *_ = chain
-            toks = np.asarray(fetched[6])  # [nb, k*chunk]
-            lengths_h = np.asarray(fetched[7])
-            keys_h = np.asarray(fetched[8])
-            bad_h = np.asarray(fetched[9])
-            for i, (slot, req) in enumerate(zip(slots, slot_reqs)):
-                if req.done and req.slot is None:
-                    continue  # finished at prefill harvest; slot freed
-                if req.slot != slot:
-                    continue  # preempted mid-step; chain row is garbage
-                try:
-                    if self._fi is not None:
-                        if self._fi.fire("step-exception", rid=req.rid):
-                            raise InjectedFault(
-                                f"injected step fault (rid {req.rid})")
-                        if self._fi.fire("nan-logits", rid=req.rid):
+        with _TRACER.nested("engine.harvest", "engine"):
+            fetched = jax.device_get((
+                pre_tok, pre_keys, pre_bad, pend_tok, pend_keys, pend_bad,
+                *(chain[5:] if chain else ())))
+            if admits:
+                self._harvest_admits(admits, fetched[0], fetched[1],
+                                     fetched[2])
+            if chain:
+                slots, slot_reqs, nb, k, fresh, *_ = chain
+                toks = np.asarray(fetched[6])  # [nb, k*chunk]
+                lengths_h = np.asarray(fetched[7])
+                keys_h = np.asarray(fetched[8])
+                bad_h = np.asarray(fetched[9])
+                for i, (slot, req) in enumerate(zip(slots, slot_reqs)):
+                    if req.done and req.slot is None:
+                        continue  # finished at prefill harvest; slot freed
+                    if req.slot != slot:
+                        continue  # preempted mid-step; chain row is garbage
+                    try:
+                        if self._fi is not None:
+                            if self._fi.fire("step-exception", rid=req.rid):
+                                raise InjectedFault(
+                                    f"injected step fault (rid {req.rid})")
+                            if self._fi.fire("nan-logits", rid=req.rid):
+                                raise NumericsError(
+                                    "injected non-finite logits", rid=req.rid)
+                        if bad_h[i]:
                             raise NumericsError(
-                                "injected non-finite logits", rid=req.rid)
-                    if bad_h[i]:
-                        raise NumericsError(
-                            "non-finite logits in decode chain",
-                            rid=req.rid)
-                    self._harvest(req, toks[i])
-                    self._last_tok[slot] = int(toks[i, -1])
-                    self.lengths[slot] = int(lengths_h[i])
-                    self._keys[slot] = keys_h[i]
-                    if req.done:
-                        del self._active[slot]
-                        self._free_slot(slot)
-                except RequestError as e:
-                    self._fail_request(req, e)
-                except Exception as e:
-                    # per-request isolation: ONE request's harvest going
-                    # wrong must never take down its batchmates
-                    self._fail_request(req, self._wrap_step_fault(e, req))
-            if pending:
-                self._activate_pending(pending, fetched[3], fetched[4],
-                                       fetched[5])
-            self._pending_inflight = []
-            if not admits and not pending and not fresh:
-                # pure-decode step on a warm program: a clean T(k) sample
-                # for the measured dispatch-cost ratio (a fresh compile's
-                # trace/cache-load seconds would poison the fit)
-                self._observe_chain_time(nb, k, time.perf_counter() - t0)
+                                "non-finite logits in decode chain",
+                                rid=req.rid)
+                        self._harvest(req, toks[i])
+                        self._last_tok[slot] = int(toks[i, -1])
+                        self.lengths[slot] = int(lengths_h[i])
+                        self._keys[slot] = keys_h[i]
+                        if req.done:
+                            del self._active[slot]
+                            self._free_slot(slot)
+                    except RequestError as e:
+                        self._fail_request(req, e)
+                    except Exception as e:
+                        # per-request isolation: ONE request's harvest going
+                        # wrong must never take down its batchmates
+                        self._fail_request(req, self._wrap_step_fault(e, req))
+                if pending:
+                    self._activate_pending(pending, fetched[3], fetched[4],
+                                           fetched[5])
+                self._pending_inflight = []
+                if not admits and not pending and not fresh:
+                    # pure-decode step on a warm program: a clean T(k) sample
+                    # for the measured dispatch-cost ratio (a fresh compile's
+                    # trace/cache-load seconds would poison the fit)
+                    self._observe_chain_time(nb, k, time.perf_counter() - t0)
 
     def _multi_chained_step(self, budget: int) -> int:
         """Multi-step scheduling fast path (ISSUE 12 tentpole): up to

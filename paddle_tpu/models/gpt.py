@@ -292,8 +292,11 @@ class GPTForCausalLM(GenerationMixin, nn.Layer):
         return self._logits(x), new_caches
 
     def _logits(self, x):
+        # the tied head sits in no sublayer: a scope of its own, so its
+        # operations (and their gradients) are found in a device trace
         w = self.gpt.wte.weight
-        return apply_op(lambda a, we: jnp.einsum("bsh,vh->bsv", a, we.astype(a.dtype)), x, w)
+        with jax.named_scope("lm_head"):
+            return apply_op(lambda a, we: jnp.einsum("bsh,vh->bsv", a, we.astype(a.dtype)), x, w)
 
     def init_caches(self, batch_size, max_seq, dtype=jnp.float32):
         return self.gpt.init_caches(batch_size, max_seq, dtype)

@@ -56,6 +56,7 @@ class Layer:
             if sublayers is None:
                 raise RuntimeError("call Layer.__init__ before assigning sublayers")
             sublayers[name] = value
+            value._held_as(self._child_scope(name))
         elif params is not None and name in params:
             if value is None:
                 params.pop(name)
@@ -129,7 +130,22 @@ class Layer:
 
     def add_sublayer(self, name: str, sublayer: "Layer"):
         self._sub_layers[name] = sublayer
+        sublayer._held_as(self._child_scope(name))
         return sublayer
+
+    # the key the parent holds this layer under ("attn", "qkv_proj"): the
+    # jax.named_scope its forward runs in, so that every operation of a
+    # compiled program carries the path of the layer that caused it
+    # (".../h/3/attn/qkv_proj/dot_general", the backward twin under
+    # "transpose(jvp(...))/h/3/attn/..."). Metadata only: the compiled
+    # program is the same. None for a root, which runs under its class name.
+    _scope_name: Optional[str] = None
+
+    def _held_as(self, name: str):
+        object.__setattr__(self, "_scope_name", name)
+
+    def _child_scope(self, key: str) -> str:
+        return key
 
     def register_buffer(self, name: str, tensor: Optional[Tensor], persistable=True):
         if tensor is not None and not isinstance(tensor, Tensor):
@@ -267,16 +283,17 @@ class Layer:
         raise NotImplementedError
 
     def __call__(self, *inputs, **kwargs):
-        for hook in self._forward_pre_hooks.values():
-            result = hook(self, inputs)
-            if result is not None:
-                inputs = result if isinstance(result, tuple) else (result,)
-        outputs = self.forward(*inputs, **kwargs)
-        for hook in self._forward_post_hooks.values():
-            result = hook(self, inputs, outputs)
-            if result is not None:
-                outputs = result
-        return outputs
+        with jax.named_scope(self._scope_name or type(self).__name__.lower()):
+            for hook in self._forward_pre_hooks.values():
+                result = hook(self, inputs)
+                if result is not None:
+                    inputs = result if isinstance(result, tuple) else (result,)
+            outputs = self.forward(*inputs, **kwargs)
+            for hook in self._forward_post_hooks.values():
+                result = hook(self, inputs, outputs)
+                if result is not None:
+                    outputs = result
+            return outputs
 
     def full_name(self):
         return self._full_name
@@ -310,6 +327,16 @@ class LayerList(Layer):
             for i, l in enumerate(sublayers):
                 self.add_sublayer(str(i), l)
 
+    def _held_as(self, name: str):
+        """A list is iterated, never called: it opens no scope of its own,
+        so its items run under "<the list's key>/<index>" ("h/3")."""
+        super()._held_as(name)
+        for key, sub in self._sub_layers.items():
+            sub._held_as(self._child_scope(key))
+
+    def _child_scope(self, key: str) -> str:
+        return f"{self._scope_name}/{key}" if self._scope_name else key
+
     def append(self, sublayer):
         self.add_sublayer(str(len(self._sub_layers)), sublayer)
         return self
@@ -319,7 +346,7 @@ class LayerList(Layer):
         layers.insert(index, sublayer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, sublayers):
         for l in sublayers:
@@ -332,7 +359,7 @@ class LayerList(Layer):
         return self._sub_layers[str(idx % len(self._sub_layers) if idx < 0 else idx)]
 
     def __setitem__(self, idx, layer):
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)

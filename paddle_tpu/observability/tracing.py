@@ -5,10 +5,19 @@ this module answers the two they cannot: "where did THIS request's time
 go" and "what was the engine doing in the seconds before the crash".
 It is a Dapper-style span recorder sized for the serving hot path:
 
-* **Near-zero when off.** Every record site in the stack guards on
-  ``TRACER.enabled`` (one attribute read); the ``span()`` helper
-  returns a shared no-op handle without allocating. ``bench_trace``
-  gates the *enabled* overhead < 2% on the SLO workload.
+* **Joined to the device trace.** A span opened through
+  ``Tracer.start`` / ``Tracer.nested`` / ``span()`` also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name (the call
+  :func:`annotation`, which ``paddle_tpu.profiler.RecordEvent`` ends in
+  too), whatever the tracer's mode: while a ``jax.profiler`` session
+  runs, the program's spans sit in the ``.xplane.pb`` as host events
+  on the same clock as the device's ``XLA Ops`` line, so an idle gap
+  of the chip reads as the span the host was in. No clock arithmetic,
+  no second recorder; an annotation outside a session is a flag test.
+* **Near-zero when off.** Instants and per-request records guard on
+  ``TRACER.enabled`` (one attribute read); a span site costs that read
+  plus one annotation enter/exit. Measured on the chip (PERF.md
+  section 6, PR 26), not on a CPU.
 * **Bounded when on.** Finished spans land in a ``deque(maxlen=...)``
   ring — one GIL-atomic append per record, a lock only for snapshots.
   Sustained load overwrites the oldest records; memory never grows.
@@ -41,9 +50,12 @@ decomposition's 1 ms budget is measured in).
 
 Hard rule (mirrors TPL601): tracing is HOST-side telemetry. A
 ``span()``/``instant()`` call inside jit/shard_map/pallas-traced code
-runs once at trace time and is flagged by tpulint rule TPL1401.
+runs once at trace time and is flagged by tpulint rule TPL1401. What
+belongs INSIDE traced code is a ``jax.named_scope``: ``nn.Layer``
+opens one per layer, so the device's operations carry their layer.
 
-Pure stdlib; safe to import from anywhere in the tree.
+Pure stdlib at import (jax is looked for on the first span, and its
+absence only means no annotation); safe to import from anywhere.
 """
 from __future__ import annotations
 
@@ -58,7 +70,8 @@ from typing import Dict, List, Optional
 __all__ = [
     "SpanContext", "Span", "Tracer", "TRACER",
     "configure_tracing", "get_tracer", "new_trace_id",
-    "span", "instant", "complete", "flight_record",
+    "span", "nested", "annotation", "instant", "complete",
+    "flight_record",
     "ttft_decomposition_summary",
 ]
 
@@ -83,6 +96,30 @@ def new_trace_id() -> str:
 
 def _new_span_id() -> str:
     return f"{_NONCE}-{next(_ids):x}"
+
+
+_ANNOTATION = None   # jax.profiler.TraceAnnotation; False without jax
+_local = threading.local()   # .stack: this thread's open nested() spans
+
+
+def annotation(name: str):
+    """An ENTERED ``jax.profiler.TraceAnnotation`` (the caller owes it
+    ``__exit__(None, None, None)``), or None where jax is not installed.
+    The one call through which this module's spans and
+    ``paddle_tpu.profiler.RecordEvent`` reach the profiler's trace; jax
+    is imported here, on first use, never at module import."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except ImportError:
+            _ANNOTATION = False
+    if _ANNOTATION is False:
+        return None
+    ann = _ANNOTATION(name)
+    ann.__enter__()
+    return ann
 
 
 class SpanContext:
@@ -117,28 +154,32 @@ class SpanContext:
         return f"SpanContext({self.encode()!r})"
 
 
-class _NullSpan:
-    """The disabled-path handle: every method is a no-op, shared as a
-    singleton so ``span()`` costs one attribute check and no
-    allocation when tracing is off."""
+class _OffSpan:
+    """The handle of a span that stays out of the ring (the tracer is
+    off, or the site asked for it): the profiler's annotation and
+    nothing else (no ids, no clock read, no record); without jax not
+    even that, and every method is a no-op."""
 
-    __slots__ = ()
+    __slots__ = ("_ann",)
     ctx = None
+
+    def __init__(self, name):
+        self._ann = annotation(name)
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
+        self.end()
         return False
 
     def end(self, **args):
-        pass
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
 
     def set(self, **args):
         pass
-
-
-_NULL_SPAN = _NullSpan()
 
 
 class Span:
@@ -146,20 +187,22 @@ class Span:
     duration and commits the record to the tracer's ring."""
 
     __slots__ = ("_tracer", "name", "cat", "ctx", "parent_id",
-                 "_t0_wall", "_t0", "args", "_done")
+                 "_t0_wall", "_t0", "args", "_done", "_ann", "_nested")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  ctx: SpanContext, parent_id: Optional[str],
-                 args: Optional[dict]):
+                 args: Optional[dict], nested: bool = False):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.ctx = ctx
         self.parent_id = parent_id
-        self._t0_wall = time.time()
-        self._t0 = time.perf_counter()
         self.args = args
         self._done = False
+        self._nested = nested
+        self._ann = annotation(name)
+        self._t0_wall = time.time()
+        self._t0 = time.perf_counter()
 
     def set(self, **args):
         """Attach/extend args on an open span."""
@@ -171,12 +214,19 @@ class Span:
         if self._done:
             return
         self._done = True
+        dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._nested:
+            stack = getattr(_local, "stack", ())
+            if self in stack:
+                del stack[stack.index(self):]
         if args:
             self.set(**args)
         self._tracer._commit(
             self.name, self.cat, self.ctx.trace_id, self.ctx.span_id,
-            self.parent_id, self._t0_wall,
-            time.perf_counter() - self._t0, self.args)
+            self.parent_id, self._t0_wall, dur, self.args)
         if self._tracer._open > 0:
             self._tracer._open -= 1
 
@@ -206,7 +256,6 @@ class Tracer:
         self._lock = threading.Lock()      # snapshots/dumps only
         self._open = 0                     # open spans (leak check)
         self._flight_seq = 0
-        self._m_spans = None               # lazy registry counter
 
     # -------------------------------------------------------- configure
     def configure(self, mode: str = "on", process: Optional[str] = None,
@@ -230,12 +279,6 @@ class Tracer:
             if flight_dir is not None:
                 self.flight_dir = flight_dir
             self._open = 0
-        if self.enabled and self._m_spans is None:
-            from .metrics import counter
-
-            self._m_spans = counter(
-                "paddle_tpu_trace_spans_total",
-                "span/event records committed to the trace ring")
         return self
 
     def clear(self):
@@ -252,9 +295,11 @@ class Tracer:
               parent=None, trace_id: Optional[str] = None, **args):
         """Open a span. ``parent`` is a SpanContext (or wire string)
         the new span nests under; with neither parent nor trace_id a
-        fresh trace is minted."""
+        fresh trace is minted. The span is also an annotation in the
+        profiler's trace (:func:`annotation`), which is all that
+        happens while the tracer is off."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _OffSpan(name)
         pctx = SpanContext.decode(parent) if parent is not None else None
         if pctx is not None:
             tid, pid = pctx.trace_id, pctx.span_id
@@ -263,6 +308,32 @@ class Tracer:
         self._open += 1
         return Span(self, name, cat, SpanContext(tid, _new_span_id()),
                     pid, args or None)
+
+    def nested(self, name: str, cat: str = "", ring: bool = True,
+               **args):
+        """Open a span under the one this thread opened last through
+        ``nested`` and has not ended: the phases of a loop on one thread
+        (``frontend.loop`` > ``engine.step`` > ``engine.harvest``), so a
+        phase's self time is its span less its children. For ``with``
+        blocks only: such spans end in the reverse of the order they
+        were opened in. A span with no open ancestor starts a trace.
+        ``ring=False`` keeps the span out of the ring (the annotation
+        alone): a loop's idle turns must not flush the record of work."""
+        if not (self.enabled and ring):
+            return _OffSpan(name)
+        stack = getattr(_local, "stack", None)
+        if stack is None:
+            stack = _local.stack = []
+        if stack:
+            top = stack[-1].ctx
+            tid, pid = top.trace_id, top.span_id
+        else:
+            tid, pid = new_trace_id(), None
+        self._open += 1
+        sp = Span(self, name, cat, SpanContext(tid, _new_span_id()), pid,
+                  args or None, nested=True)
+        stack.append(sp)
+        return sp
 
     def instant(self, name: str, cat: str = "", parent=None, **args):
         """Zero-duration event (harvests, migrations, fault points)."""
@@ -300,8 +371,6 @@ class Tracer:
         # deque.append with maxlen is a single GIL-atomic op — the
         # scheduler hot path never takes the lock
         self._ring.append(rec)
-        if self._m_spans is not None:
-            self._m_spans.inc()
 
     # ------------------------------------------------------------- export
     def snapshot(self) -> List[Dict]:
@@ -363,11 +432,16 @@ def configure_tracing(mode: str = "on", process: Optional[str] = None,
 def span(name: str, cat: str = "", parent=None,
          trace_id: Optional[str] = None, **args):
     """Module-level convenience: ``with span("router.place", parent=ctx)
-    as s: ...``. Returns the shared no-op handle when tracing is off."""
-    if not TRACER.enabled:
-        return _NULL_SPAN
+    as s: ...``. Ends in the same :func:`annotation` call as
+    ``paddle_tpu.profiler.RecordEvent``; with tracing off the handle is
+    that annotation alone."""
     return TRACER.start(name, cat, parent=parent, trace_id=trace_id,
                         **args)
+
+
+def nested(name: str, cat: str = "", ring: bool = True, **args):
+    """``TRACER.nested``: a span under this thread's innermost open one."""
+    return TRACER.nested(name, cat, ring=ring, **args)
 
 
 def instant(name: str, cat: str = "", parent=None, **args):

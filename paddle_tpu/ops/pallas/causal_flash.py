@@ -129,6 +129,7 @@ def _fwd(qkv, num_heads, head_dim, scale):
             jax.ShapeDtypeStruct((b, gh, seq, hpb), jnp.float32),
         ],
         interpret=_interpret(),
+        name="causal_flash_fwd",
     )(qkv, qkv, qkv)
     return out, lse
 
@@ -289,6 +290,7 @@ def _fwd_tiled(qkv, num_heads, head_dim, scale):
             jax.ShapeDtypeStruct((b, gh, seq, hpb), jnp.float32),
         ],
         interpret=_interpret(),
+        name="causal_flash_fwd_tiled",
     )(jnp.asarray(qi_tab), jnp.asarray(kc_tab), qkv, qkv, qkv)
     return out, lse
 
@@ -387,6 +389,7 @@ def _fwd_row(qkv, num_heads, head_dim, scale, blk):
             jax.ShapeDtypeStruct((b, gh, seq, hpb), jnp.float32),
         ],
         interpret=_interpret(),
+        name="causal_flash_fwd_row",
     )(qkv, qkv, qkv)
     return out, lse
 
@@ -563,6 +566,7 @@ def _bwd_tiled(num_heads, head_dim, scale, res, do):
             jax.ShapeDtypeStruct((b, 2, gh, seq, lanes), qkv.dtype),
         ],
         interpret=_interpret(),
+        name="causal_flash_bwd_tiled",
     )(jnp.asarray(a_tab), jnp.asarray(b_tab),
       qkv, do, out, lse, qkv, qkv)
     # [B, 3H/hpb, S, lanes]: dq rows then dk rows then dv rows — the same
@@ -623,6 +627,7 @@ def _bwd(num_heads, head_dim, scale, res, do):
                                lambda bi, hi: (bi, 0, hi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 3, gh, seq, lanes), qkv.dtype),
         interpret=_interpret(),
+        name="causal_flash_bwd",
     )(qkv, qkv, qkv, out, do, lse)
     return dqkv5.reshape(b, 3 * gh, seq, lanes)
 

@@ -99,6 +99,7 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths, scale=None):
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, _Q_ROWS, dp), q.dtype),
         interpret=_interpret(),
+        name="decode_attention",
     )(jnp.asarray(lengths, jnp.int32), qr, k_cache, v_cache)
     return out[:, 0, :d].reshape(b, h, d)
 
@@ -360,6 +361,7 @@ def _slab_pallas(q, kv_slab, lengths, scale):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="decode_attention_slab",
     )(jnp.asarray(lengths, jnp.int32), qr, kv_slab)
     return out[:, 0].reshape(b, h, d)
 

@@ -173,6 +173,7 @@ def _fwd(q, k, v, qp=None, kp=None, *, scale, causal, kv_valid, block_q, block_k
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(*inputs)
     return out, lse[:, :, :1]  # lse [bh, sq, 1]
 
@@ -256,6 +257,7 @@ def _bwd_fused(scale, causal, kv_valid, res, do):
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=_interpret(),
+        name="flash_attention_bwd",
     )(q, k, v, out, do, lse2d)
     return dq, dk, dv
 
@@ -411,6 +413,7 @@ def _bwd(scale, causal, kv_valid, block_q, block_k, res, do, dlse=None):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_bwd_dkv",
     )(*dkv_inputs)
     dk, dv = dkv
 
@@ -440,6 +443,7 @@ def _bwd(scale, causal, kv_valid, block_q, block_k, res, do, dlse=None):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_attention_bwd_dq",
     )(*dq_inputs)
     return dq, dk, dv
 

@@ -155,6 +155,7 @@ def grouped_matmul_pallas(lhs, rhs, group_sizes, valid_sizes=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="grouped_matmul",
     )(blk2grp, blk_rows, xa, wp)
 
     # ---- scatter back to the packed row order -------------------------

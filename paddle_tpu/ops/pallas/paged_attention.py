@@ -174,6 +174,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, _Q_ROWS, dp), jnp.float32),
         interpret=_interpret(),
+        name="paged_attention",
     )(jnp.asarray(lengths, jnp.int32), jnp.asarray(block_tables, jnp.int32),
       *inputs)
     return out[:, 0, :d].reshape(b, h, d).astype(q.dtype)
@@ -547,6 +548,8 @@ def _paged_window_call(lim, block_tables, qr, k_pages, v_pages, scale_pages,
             vmem_limit_bytes=(None if resident <= _DEFAULT_VMEM_BYTES
                               else resident + (resident >> 2))),
         interpret=interpret,
+        # m == 0: one query row a sequence (decode); m > 0: the verify slab
+        name="paged_attention_slab" if m == 0 else "paged_attention_verify",
     )(jnp.asarray(lim, jnp.int32), jnp.asarray(block_tables, jnp.int32),
       qr, k_pages, v_pages, scale_pages)
 

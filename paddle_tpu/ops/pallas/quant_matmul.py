@@ -255,6 +255,7 @@ def quant_matmul_pallas(x, wq, scales, bias=None, weight_dtype="int8",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="quant_matmul",
     )(*operands)
     return out[:rows, :n].reshape(*lead, n)
 

@@ -140,7 +140,8 @@ class Optimizer:
                 st = dict(st, master=p.astype(jnp.float32))
             return st
 
-        return jax.tree_util.tree_map(per_param, params_tree)
+        with jax.named_scope("optimizer"):
+            return jax.tree_util.tree_map(per_param, params_tree)
 
     def apply_gradients_tree(self, params_tree, grads_tree, state_tree, lr, step,
                              decay_mask_tree=None):
@@ -164,10 +165,13 @@ class Optimizer:
         else:
             flat_m = treedef.flatten_up_to(decay_mask_tree)
         new_p, new_s = [], []
-        for p, g, st, m in zip(flat_p, flat_g, flat_s, flat_m):
-            np_, ns_ = per_param(p, g, dict(st), m)
-            new_p.append(np_)
-            new_s.append(ns_)
+        # every operation of the update carries the scope "optimizer" in
+        # the compiled program (metadata only)
+        with jax.named_scope("optimizer"):
+            for p, g, st, m in zip(flat_p, flat_g, flat_s, flat_m):
+                np_, ns_ = per_param(p, g, dict(st), m)
+                new_p.append(np_)
+                new_s.append(ns_)
         return (jax.tree_util.tree_unflatten(treedef, new_p),
                 jax.tree_util.tree_unflatten(treedef, new_s))
 

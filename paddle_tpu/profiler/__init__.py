@@ -16,6 +16,8 @@ from typing import Callable, Optional
 
 import jax
 
+from ..observability.tracing import annotation as _annotation
+
 __all__ = [
     "Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
     "make_scheduler", "export_chrome_tracing", "mfu",
@@ -71,7 +73,10 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
 
 class RecordEvent:
     """Host-span annotation (reference: paddle.profiler.RecordEvent →
-    here jax.profiler.TraceAnnotation so spans appear in XProf)."""
+    here jax.profiler.TraceAnnotation so spans appear in XProf). Ends in
+    ``observability.tracing.annotation``, the same call the tracer's
+    spans (``tracing.span``) reach the profiler through: one mechanism,
+    and a RecordEvent is the span that keeps no ring record."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
@@ -79,8 +84,7 @@ class RecordEvent:
         self._t0 = None
 
     def begin(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
+        self._ann = _annotation(self.name)
         self._t0 = time.perf_counter()
 
     def end(self):

@@ -228,6 +228,16 @@ class ServingFrontend:
         self.ready_queue_depth = int(
             ready_queue_depth if ready_queue_depth is not None
             else max(8, 4 * engine.max_slots))
+        # submit -> pop wait in the fair queue, one observation a ticket
+        # handed to the engine; follows the engine's metrics switch
+        self._m_fair_wait = None
+        if getattr(engine, "_m", None) is not None:
+            from ..observability.metrics import histogram
+
+            self._m_fair_wait = histogram(
+                "paddle_serving_fair_queue_wait_seconds",
+                "submit to the pop from the frontend's fair queue "
+                "(the wait paddle_serving_queue_wait_seconds starts after)")
         self._live: Dict[int, StreamTicket] = {}  # rid -> ticket
         self._reqs: Dict[int, object] = {}        # rid -> engine Request
         self._cancels: deque = deque()
@@ -479,9 +489,13 @@ class ServingFrontend:
             if ticket.cancelled:
                 ticket._finish("cancelled")
                 continue
+            # the wait in the fair queue, submit -> this pop: counted
+            # where it ends (the engine's own queue_wait starts here)
+            now = time.perf_counter()
+            if self._m_fair_wait is not None:
+                self._m_fair_wait.observe(now - ticket.t_submit)
             if _TRACER.enabled:
-                # retroactive FairQueue-wait span: submit -> this pop
-                now = time.perf_counter()
+                # retroactive FairQueue-wait span of the same interval
                 _TRACER.complete(
                     "frontend.queue", "frontend",
                     time.time() - (now - ticket.t_submit),
@@ -575,6 +589,65 @@ class ServingFrontend:
             self._live.pop(rid, None)
             self._reqs.pop(rid, None)
 
+    def _idle_wait(self, ring):
+        """Sleep until a submit/cancel/drain wakes the engine thread."""
+        with _TRACER.nested("frontend.idle_wait", "frontend", ring=ring):
+            self._wake.wait(timeout=self._idle_wait_s)
+        self._wake.clear()
+
+    def _turn(self, eng, ring) -> bool:
+        """One turn of the engine thread, inside its ``frontend.loop``
+        span: errands, ``frontend.feed``, one ``engine.step`` (whose
+        phases nest under it), ``frontend.complete``, or
+        ``frontend.idle_wait`` when there is nothing to do. True when
+        the loop must end. ``ring``: whether the turn's spans are kept
+        in the tracer's ring as well as annotated for the profiler."""
+        self._apply_cancels()
+        self._apply_calls()
+        self._cancel_stalled()
+        if self._force_cancel:
+            for rid in list(self._live):
+                eng.cancel(rid)
+            while True:
+                popped = self.queue.pop()
+                if popped is None:
+                    break
+                popped[0]._finish("cancelled")
+        # draining still FEEDS: a ticket accepted into the fair
+        # queue is in-flight work the drain must finish (submit
+        # is what the drain gate refuses)
+        with _TRACER.nested("frontend.feed", "frontend", ring=ring):
+            self._feed()
+        if eng._queue or eng._active:
+            # arrivals waiting → single iterations for fast slot
+            # turnover; idle queue → the multi-step fast path
+            n = 1 if len(self.queue) else None
+            eng.step(n)
+            with _TRACER.nested("frontend.complete", "frontend",
+                                ring=ring):
+                self._complete()
+            if eng._watchdog.quarantined:
+                # integrity fail-stop (ISSUE 14): the engine
+                # refuses to mint tokens through corrupt
+                # weights, so step() is a no-op — idle-wait
+                # instead of hot-spinning until the router
+                # fences this replica and migrates its streams.
+                # The KV host tier drains with the replica
+                # (ISSUE 15): spill state captured on corrupt
+                # hardware is never carried into the restart.
+                eng._cache.shutdown_tier()
+                self._idle_wait(ring)
+            return False
+        with _TRACER.nested("frontend.complete", "frontend", ring=ring):
+            self._complete()
+        if self._draining and not self._live \
+                and not len(self.queue):
+            self._drained.set()
+            if self._stop.is_set():
+                return True
+        self._idle_wait(ring)
+        return False
+
     def _loop(self):
         eng = self.engine
         try:
@@ -584,49 +657,16 @@ class ServingFrontend:
                     # Live tickets stay unfinished on purpose — the
                     # router's failover machinery is what must react.
                     return
-                self._apply_cancels()
-                self._apply_calls()
-                self._cancel_stalled()
-                if self._force_cancel:
-                    for rid in list(self._live):
-                        eng.cancel(rid)
-                    while True:
-                        popped = self.queue.pop()
-                        if popped is None:
-                            break
-                        popped[0]._finish("cancelled")
-                # draining still FEEDS: a ticket accepted into the fair
-                # queue is in-flight work the drain must finish (submit
-                # is what the drain gate refuses)
-                self._feed()
-                if eng._queue or eng._active:
-                    # arrivals waiting → single iterations for fast slot
-                    # turnover; idle queue → the multi-step fast path
-                    n = 1 if len(self.queue) else None
-                    eng.step(n)
-                    self._complete()
-                    if eng._watchdog.quarantined:
-                        # integrity fail-stop (ISSUE 14): the engine
-                        # refuses to mint tokens through corrupt
-                        # weights, so step() is a no-op — idle-wait
-                        # instead of hot-spinning until the router
-                        # fences this replica and migrates its streams.
-                        # The KV host tier drains with the replica
-                        # (ISSUE 15): spill state captured on corrupt
-                        # hardware is never carried into the restart.
-                        eng._cache.shutdown_tier()
-                        self._wake.wait(timeout=self._idle_wait_s)
-                        self._wake.clear()
-                    continue
-                self._complete()
-                if self._draining and not self._live \
-                        and not len(self.queue):
-                    self._drained.set()
-                    if self._stop.is_set():
+                # a turn with nothing queued, live or asked of this
+                # thread stays out of the ring: idle turns come every
+                # idle_wait_s and would flush the record of the work
+                busy = bool(self._live or self._cancels or self._calls
+                            or self._force_cancel or len(self.queue)
+                            or eng._queue or eng._active)
+                with _TRACER.nested("frontend.loop", "frontend",
+                                    ring=busy):
+                    if self._turn(eng, busy):
                         break
-                # idle: sleep until a submit/cancel/drain wakes us
-                self._wake.wait(timeout=self._idle_wait_s)
-                self._wake.clear()
         finally:
             # every way out of the engine thread — drain, shutdown,
             # poison (the replica-crash chaos surface), an escape —

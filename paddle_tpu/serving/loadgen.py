@@ -353,7 +353,6 @@ def bench_trace_serving(cfg, on_tpu: bool) -> Dict:
     (median-on / median-off - 1) < 2% with > 0 spans recorded."""
     from ..inference.engine import Engine
     from ..models.gpt import GPTForCausalLM
-    from ..observability import metric_total
     from ..observability.tracing import TRACER, configure_tracing
 
     model = GPTForCausalLM(cfg)
@@ -371,7 +370,6 @@ def bench_trace_serving(cfg, on_tpu: bool) -> Dict:
         return [eng.add_request(_mk_prompt(rng, vocab, 12, 32), 8)
                 for _ in range(slots)]
 
-    spans0 = metric_total("paddle_tpu_trace_spans_total")
     # warmup under BOTH modes: compile every program, touch both record
     # paths once (the enabled-guard branch and the ring append)
     for mode in ("on", "off"):
@@ -395,13 +393,13 @@ def bench_trace_serving(cfg, on_tpu: bool) -> Dict:
                         break
     finally:
         configure_tracing("off")
+        spans = len(TRACER.snapshot())   # what the on reps left in the ring
         TRACER.clear()
     floor_s = 0.020 if on_tpu else 0.050
     med_off = float(np.median(steps["off"]))
     med_on = float(np.median(steps["on"]))
     ratio = max(med_on, floor_s) / max(med_off, floor_s)
     overhead = max(0.0, ratio - 1.0)
-    spans = int(metric_total("paddle_tpu_trace_spans_total") - spans0)
     ok = overhead < 0.02 and spans > 0
     if not ok:
         print(f"WARNING: bench_trace gate failed: overhead="

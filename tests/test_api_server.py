@@ -194,7 +194,13 @@ class TestFrontend:
             fe.shutdown()
 
     def test_cancel_mid_stream_frees_slots_and_pages(self, gpt):
-        eng = make_engine(gpt)
+        # one chunk a step and a slowed step: the 80 tokens take 20 round
+        # trips, so the cancel lands mid-stream however the host is
+        # loaded (with the default chain depth, which the engine fits to
+        # measured dispatch times, a busy host could see the whole
+        # stream delivered by the first harvest and nothing to cancel)
+        eng = make_engine(gpt, max_chain=1,
+                          fault_plan="slow-step:every=1,delay_ms=10")
         fe = ServingFrontend(eng).start()
         try:
             got = threading.Event()

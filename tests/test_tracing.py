@@ -139,12 +139,17 @@ class TestSpanContext:
 
 # ---------------------------------------------------------- disabled path
 class TestDisabledPath:
-    def test_off_records_nothing_and_shares_the_null_span(self):
+    def test_off_records_nothing_and_mints_no_ids(self):
         s1 = TRACER.start("a", "t")
-        s2 = TRACER.start("b", "t")
-        assert s1 is s2  # the shared no-op handle: no allocation
+        s2 = TRACER.nested("b", "t")
+        # off, a span is the profiler's annotation alone: no ids, no
+        # clock read, no ring record (tests/test_trace_join.py reads
+        # the annotations back from a profiler session)
+        assert s1.ctx is None and s2.ctx is None
         with s1:
             s1.set(x=1)
+        s2.end()
+        s2.end(x=2)  # idempotent
         TRACER.instant("ev", "t")
         TRACER.complete("c", "t", time.time(), 0.1)
         assert TRACER.snapshot() == []

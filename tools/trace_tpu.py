@@ -31,6 +31,19 @@ router's main-process file plus each replica's) merge on the shared
 wall clock — that merge is what renders a migrated stream as ONE
 contiguous cross-replica trace.
 
+This tool renders the tracer's own ring: requests across replicas on the
+wall clock. To see the same spans AGAINST THE DEVICE, take a
+``jax.profiler`` trace instead (``jax.profiler.start_trace(dir)`` around
+the serving process, or ``paddle_tpu.profiler.Profiler``): every span
+opened through ``Tracer.start`` / ``nested`` / ``tracing.span`` is also a
+``jax.profiler.TraceAnnotation`` of the same name, whatever the tracer's
+mode, so Perfetto / XProf show ``frontend.loop`` > ``engine.step`` >
+``engine.harvest`` on the host's line beside the chip's ``XLA Ops`` line
+on one clock, and each device operation carries its layer's
+``jax.named_scope`` path (``.../gpt/h/3/attn/...``) and each Pallas
+kernel its own name. ``benchmarks/readers/trace_scope.py`` and
+``benchmarks/harness/trace_host.py`` reduce such a trace to numbers.
+
 Pure stdlib; no paddle_tpu import (runs anywhere, even where jax is
 broken).
 """

@@ -21,21 +21,35 @@ two ways that dominate its speedup at train shapes:
    columns (the general kernel wrote a 128-lane broadcast, 64 MB of pure
    padding per layer).
 2. **One fused backward.** dQ, dK, dV come out of a single whole-sequence
-   program per (batch, head block) — math shared with the general kernel
-   via flash_attention.fused_bwd_math (logits re-formed once, delta
-   in-kernel, dots in the input dtype with fp32 accumulation) — written
-   into one ``[B, 3, H/hpb, S, hpb*D]`` array that bitcasts to the packed
-   layout the QKV projection's backward consumes.
+   program per (batch, head block) — logits re-formed once per tile pair
+   and shared by the three accumulations (``_pair_grads``, one body for
+   this program and the S > 1024 per-pair grid), delta in-kernel, dots in
+   the input dtype with fp32 accumulation — written into one
+   ``[B, 3, H/hpb, S, hpb*D]`` array that bitcasts to the packed layout
+   the QKV projection's backward consumes.
 
 Three regimes by sequence length (VERDICT r3 #2 lifted the old S<=1024
 cap; r5 added the whole-row middle regime):
 
 * **S <= 1024 — whole-sequence programs.** One program per (batch, head
-  block) pays the full S×S square (no causal skip): measured on v5e,
-  Mosaic's cross-grid-step pipelining beats both in-kernel fori chunk
-  loops (~1.3x slower despite computing the triangle only) and finer grid
-  blocks (~2x slower from per-step overhead) at these sizes. The [S, S]
-  fp32 logits chunk is the VMEM budget that ends this regime.
+  block), no grid over the sequence. The forward at S = 1024 is the
+  whole-row kernel below (3 of 4 512-tiles); other S pay the full square.
+  The backward walks the causal square by q-tiles of 256 rows, unrolled
+  at trace time: each tile's masked diagonal square and ONE unmasked
+  rectangle over every k row to its left — (n+1)/2n of the square,
+  62.5% at S = 1024. The kernel is bound by the matrix unit (D = 64
+  fills half of the v5e's 128 x 128 array: the whole square ran 95
+  TFLOP/s of a ~98.5 ceiling, D = 128 ran 189 of 197), so time follows
+  the executed area. Measured on one v5e chip, B=12 x 16 heads x 1024 x
+  64 bf16, ``causal_flash_bwd`` ms a call from the profiler (PR 27):
+  whole square 1.352; this walk 0.955; square 512-tiles 1.020 (a-outer)
+  / 1.024 (b-outer); square 256-tiles 1.043 / 1.060; square 128-tiles
+  1.349; rows of 128 with their rectangle 0.965; per-k-tile columns of
+  256 with a rectangle below 0.970; 512-tiles with the diagonal refined
+  once 1.001. Small square tiles lose per executed FLOP what they save
+  in area; the rectangle keeps the products long. (An in-kernel DYNAMIC
+  fori chunk loop and a finer GRID were both slower than the square at
+  these sizes, r3-r5, not re-measured.)
 * **1024 < S <= 4096 — whole-ROW forward + per-pair backward.** The
   forward runs one program per (batch, head block, q-row of 512): the
   row's k-chunk walk is fully unrolled per static row length
@@ -65,7 +79,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1.0e30
 
-# [S, S] fp32 logits + exp + bf16 copy resident per program: 1024 -> ~12 MB
+# whole-sequence programs: q/k/v/o/do/dqkv blocks resident and double-
+# buffered (~5 MB at S=1024 bf16) beside the backward's [256, S-256] f32
+# tile temps; past it K/V residency and the unroll's code size take over
 _MAX_SEQ = 1024
 # tiled regime: q/k/v/o/do whole-seq resident -> ~5*S*256B, plus [blk, blk]
 # fp32 logits temps; 8192 -> ~12 MB
@@ -408,6 +424,63 @@ def _row_blk(seq, dtype):
     return _BLK if seq <= 4096 else None  # S=8192: per-pair grid
 
 
+# ------------------------------------------------------- bwd: one tile pair
+
+
+def _scaled_delta(do, o, scale):
+    """delta = rowsum(dO * O) as an f32 [blk, 1] column, in the units
+    ``_pair_grads`` forms dp in: times ``scale`` where the scale folds
+    into the narrow operands. Once per q-tile, not per pair (a [blk, 1]
+    column costs a quarter of a [blk, blk] tile's vector work)."""
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    return delta * scale if _exact_in_bf16(scale) else delta
+
+
+def _pair_grads(q, do, k, v, lse, delta, *, scale, masked):
+    """One live pair of the causal backward: the rows of q-tile a ([bq, D]
+    q/do, [bq, 1] lse/delta) against a span of k rows at or left of the
+    diagonal ([bk, D] k/v). p and dp = do_a . v^T are formed ONCE and feed
+    all three products (a two-pass scheme re-forms them per side). Returns
+    the pair's f32 contributions to (dQ_a [bq, D], dK [bk, D], dV [bk, D]).
+    Only the diagonal square (``masked``, bq == bk at the same offset)
+    straddles the causal boundary and pays the iota mask. Dots run in the
+    input dtype with f32 accumulation."""
+    fold = _exact_in_bf16(scale)
+    if fold:
+        # exact power-of-two scale: fold into the narrow operands feeding
+        # the s and dp dots ([blk, D] multiplies) instead of two
+        # [blk, blk] f32 multiplies per pair; the dq/dk/dv dots keep the
+        # unscaled q/do
+        q_in = q * jnp.asarray(scale, q.dtype)
+        do_in = do * jnp.asarray(scale, do.dtype)
+    else:
+        q_in, do_in = q, do
+    s = jax.lax.dot_general(q_in, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if not fold:
+        s = s * scale
+    p = jnp.exp(s - lse)
+    if masked:
+        q_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        p = jnp.where(q_ids >= k_ids, p, jnp.zeros((), p.dtype))
+    dp = jax.lax.dot_general(do_in, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - delta)
+    if not fold:
+        ds = ds * scale
+    ds = ds.astype(k.dtype)
+    dq = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    dv = jax.lax.dot_general(p.astype(do.dtype), do,
+                             (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    dk = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return dq, dk, dv
+
+
 # -------------------------------------------------------------- tiled bwd
 
 
@@ -415,34 +488,26 @@ def _bwd_tiled_kernel(a_tab, b_tab, qa_ref, doa_ref, oa_ref, lsea_ref,
                       kb_ref, vb_ref, dq_ref, dkv_ref, dq_s, dk_s, dv_s,
                       delta_s, *, scale, seq, d, hpb, blk):
     # TRIANGLE-PACKED shared-p backward: one step per live (a, b) pair
-    # (q-block a, k-chunk b, b <= a; b fastest within a row). The step
-    # forms p(a, b) and dp = do_a . v_b^T ONCE and feeds BOTH
-    # accumulations — dQ_a += ds . k_b and (dK_b += ds^T . q_a,
-    # dV_b += p^T . do_a). A two-pass scheme recomputes p and dp on each
-    # side: sharing halves the backward's exp and dp-dot work.
-    # dQ_a lives in row scratch (zeroed at b == 0, flushed at b == a);
-    # dK_b/dV_b accumulate ACROSS rows in per-b scratch (zeroed on first
-    # touch a == b, written out during the last row a == nblk-1, whose
-    # flushes land last and overwrite any earlier unwritten-buffer
-    # flushes of the dkv output blocks). delta_a is cached per row.
+    # (q-block a, k-chunk b, b <= a; b fastest within a row), its math in
+    # _pair_grads. dQ_a lives in row scratch (zeroed at b == 0, flushed
+    # at b == a); dK_b/dV_b accumulate ACROSS rows in per-b scratch
+    # (zeroed on first touch a == b, written out during the last row
+    # a == nblk-1, whose flushes land last and overwrite any earlier
+    # unwritten-buffer flushes of the dkv output blocks). delta_a is
+    # cached per row (narrow [blk, 1] store).
     t = pl.program_id(2)
     a = a_tab[t]
     b = b_tab[t]
     nblk = seq // blk
-    fold = _exact_in_bf16(scale)
 
     @pl.when(b == 0)
     def _row_start():
         dq_s[:] = jnp.zeros_like(dq_s)
         for sub in range(hpb):
             lo = sub * d
-            dob = doa_ref[0, 0, :, lo:lo + d].astype(jnp.float32)
-            ob = oa_ref[0, 0, :, lo:lo + d].astype(jnp.float32)
-            # pre-scaled (when folding) narrow [blk, 1] store: pairs read
-            # delta already multiplied by scale, so ds needs no [blk, blk]
-            # scale multiply
-            delta = jnp.sum(dob * ob, axis=-1, keepdims=True)
-            delta_s[sub, :, :1] = delta * scale if fold else delta
+            delta_s[sub, :, :1] = _scaled_delta(
+                doa_ref[0, 0, :, lo:lo + d], oa_ref[0, 0, :, lo:lo + d],
+                scale)
 
     @pl.when(a == b)
     def _first_touch_b():
@@ -452,47 +517,14 @@ def _bwd_tiled_kernel(a_tab, b_tab, qa_ref, doa_ref, oa_ref, lsea_ref,
     def _pair(masked):
         for sub in range(hpb):
             lo = sub * d
-            qb = qa_ref[0, 0, :, lo:lo + d]
-            dob = doa_ref[0, 0, :, lo:lo + d]
-            kb = kb_ref[0, 0, :, lo:lo + d]
-            vb = vb_ref[0, 0, :, lo:lo + d]
-            if fold:
-                # exact power-of-two scale: fold into the narrow operands
-                # feeding the s and dp dots ([blk, D] multiplies) instead
-                # of two [blk, blk] f32 multiplies per pair; dq/dk/dv dots
-                # keep the unscaled qb/dob
-                q_in = qb * jnp.asarray(scale, qb.dtype)
-                do_in = dob * jnp.asarray(scale, dob.dtype)
-            else:
-                q_in, do_in = qb, dob
-            s = jax.lax.dot_general(
-                q_in, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if not fold:
-                s = s * scale
-            p = jnp.exp(s - lsea_ref[0, 0, :, sub:sub + 1])
-            if masked:  # only the diagonal pair straddles the boundary
-                q_ids = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
-                k_ids = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
-                p = jnp.where(q_ids >= k_ids, p, jnp.zeros((), p.dtype))
-            dp = jax.lax.dot_general(
-                do_in, vb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            ds_ = p * (dp - delta_s[sub, :, :1])
-            if not fold:
-                ds_ = ds_ * scale
-            ds_ = ds_.astype(kb.dtype)
-            dq_s[:, lo:lo + d] = dq_s[:, lo:lo + d] + jax.lax.dot_general(
-                ds_, kb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dv_s[b, :, lo:lo + d] = (
-                dv_s[b, :, lo:lo + d] + jax.lax.dot_general(
-                    p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32))
-            dk_s[b, :, lo:lo + d] = (
-                dk_s[b, :, lo:lo + d] + jax.lax.dot_general(
-                    ds_, qb, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32))
+            dq, dk, dv = _pair_grads(
+                qa_ref[0, 0, :, lo:lo + d], doa_ref[0, 0, :, lo:lo + d],
+                kb_ref[0, 0, :, lo:lo + d], vb_ref[0, 0, :, lo:lo + d],
+                lsea_ref[0, 0, :, sub:sub + 1], delta_s[sub, :, :1],
+                scale=scale, masked=masked)
+            dq_s[:, lo:lo + d] = dq_s[:, lo:lo + d] + dq
+            dv_s[b, :, lo:lo + d] = dv_s[b, :, lo:lo + d] + dv
+            dk_s[b, :, lo:lo + d] = dk_s[b, :, lo:lo + d] + dk
 
     @pl.when(a == b)
     def _diag_pair():
@@ -579,32 +611,77 @@ def _bwd_tiled(num_heads, head_dim, scale, res, do):
 # ---------------------------------------------------------------------- bwd
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dqkv_ref, *,
-                scale, seq, d, hpb):
-    from .flash_attention import fused_bwd_math
+def _bwd_sq_blk(seq):
+    """q-tile rows of the whole-sequence backward's in-program triangle,
+    from what is static: ``seq``. 256 measured best on the v5e (the table
+    in the module docstring). Where ``seq`` has no second tile the one
+    tile is the whole square (n = 1, the masked diagonal pair alone)."""
+    blk = 256
+    # tpulint: disable=TPL301 -- `seq` is a static python int (unroll
+    # sizing at pallas_call build time), not a traced value
+    return blk if seq > blk and seq % blk == 0 else seq
 
-    for sub in range(hpb):
-        lo = sub * d
-        dq, dk, dv = fused_bwd_math(
-            q_ref[0, 0, :, lo:lo + d], k_ref[0, 0, :, lo:lo + d],
-            v_ref[0, 0, :, lo:lo + d], o_ref[0, 0, :, lo:lo + d],
-            do_ref[0, 0, :, lo:lo + d], lse_ref[0, 0, :, sub:sub + 1],
-            scale=scale, causal=True, kv_valid=None)
-        dqkv_ref[0, 0, 0, :, lo:lo + d] = dq.astype(dqkv_ref.dtype)
-        dqkv_ref[0, 1, 0, :, lo:lo + d] = dk.astype(dqkv_ref.dtype)
-        dqkv_ref[0, 2, 0, :, lo:lo + d] = dv.astype(dqkv_ref.dtype)
+
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dqkv_ref, *,
+                scale, seq, d, hpb, blk):
+    """One program per (batch, head block) walks the live part of the
+    causal square by q-tiles of ``blk`` rows, unrolled at trace time (no
+    dynamic trip count, no extra grid step, no cross-step scratch): tile
+    a's masked diagonal square, then ONE unmasked rectangle over all the
+    k rows to its left, [blk, a*blk]. The tiles above the diagonal are
+    exactly those the mask zeroes and are never formed: (n+1)/2n of the
+    square is executed, in 2n-1 pair bodies. dQ_a is complete at the end
+    of its row and stored there; dK_b/dV_b accumulate in f32 values."""
+    n = seq // blk
+    for sub in range(hpb):  # static unroll over the heads sharing the lanes
+        cols = slice(sub * d, (sub + 1) * d)
+        dk, dv = [None] * n, [None] * n
+        for a in range(n):
+            rows = slice(a * blk, (a + 1) * blk)
+            q = q_ref[0, 0, rows, cols]
+            do = do_ref[0, 0, rows, cols]
+            lse = lse_ref[0, 0, rows, sub:sub + 1]
+            delta = _scaled_delta(do, o_ref[0, 0, rows, cols], scale)
+            dq, dk[a], dv[a] = _pair_grads(
+                q, do, k_ref[0, 0, rows, cols], v_ref[0, 0, rows, cols],
+                lse, delta, scale=scale, masked=True)
+            if a:
+                left = slice(0, a * blk)
+                dq_l, dk_l, dv_l = _pair_grads(
+                    q, do, k_ref[0, 0, left, cols], v_ref[0, 0, left, cols],
+                    lse, delta, scale=scale, masked=False)
+                dq = dq + dq_l
+                for b in range(a):
+                    at_b = slice(b * blk, (b + 1) * blk)
+                    dk[b] = dk[b] + dk_l[at_b]
+                    dv[b] = dv[b] + dv_l[at_b]
+            dqkv_ref[0, 0, 0, rows, cols] = dq.astype(dqkv_ref.dtype)
+        for b in range(n):
+            at_b = slice(b * blk, (b + 1) * blk)
+            dqkv_ref[0, 1, 0, at_b, cols] = dk[b].astype(dqkv_ref.dtype)
+            dqkv_ref[0, 2, 0, at_b, cols] = dv[b].astype(dqkv_ref.dtype)
 
 
 def _bwd(num_heads, head_dim, scale, res, do):
+    return _bwd_traced(num_heads, head_dim, scale, _interpret(), res, do)
+
+
+# traced ONCE for all the layers of a model that share a shape, then inlined
+# at each call under the caller's scope: the unrolled body costs 0.1 s of
+# host time a trace (24 layers: 2.5 s of every run's set-up, PR 27)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3), inline=True)
+def _bwd_traced(num_heads, head_dim, scale, interpret, res, do):
     qkv, out, lse = res
     b, groups, seq, lanes = qkv.shape
     hpb = lanes // head_dim
     gh = num_heads // hpb
     dqkv5 = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, seq=seq, d=head_dim,
-                          hpb=hpb),
-        # f32 operands at S=1024 sit ~1 MB over the default 16 MB scoped
-        # VMEM (the [S,S] f32 temps double); raise the cap like _fwd_row
+                          hpb=hpb, blk=_bwd_sq_blk(seq)),
+        # f32 at S=1024: with 256-row tiles D=64 and D=128 compile inside
+        # the default 16 MB scoped VMEM, D=256 (1 MB per resident block,
+        # double-buffered) still does not (AOT for v5e, PR 27): raise the
+        # cap like _fwd_row
         compiler_params=(pltpu.CompilerParams(
             vmem_limit_bytes=32 * 1024 * 1024)
             if seq >= 1024 and jnp.dtype(qkv.dtype).itemsize > 2
@@ -626,7 +703,7 @@ def _bwd(num_heads, head_dim, scale, res, do):
         out_specs=pl.BlockSpec((1, 3, 1, seq, lanes),
                                lambda bi, hi: (bi, 0, hi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 3, gh, seq, lanes), qkv.dtype),
-        interpret=_interpret(),
+        interpret=interpret,
         name="causal_flash_bwd",
     )(qkv, qkv, qkv, out, do, lse)
     return dqkv5.reshape(b, 3 * gh, seq, lanes)
@@ -668,10 +745,10 @@ def _packed_bwd_rule(num_heads, head_dim, scale, res, do):
     do = do.astype(res[0].dtype)
     if res[0].shape[2] <= _MAX_SEQ:
         return (_bwd(num_heads, head_dim, scale, res, do),)
-    # (a whole-column unrolled backward mirroring _fwd_row_kernel was
-    # measured equal to this per-pair grid at S=2048 — the backward is
-    # not grid-overhead-bound the way the forward was — so the simpler
-    # battle-tested per-pair kernel stays)
+    # S > 1024: the triangle-packed per-pair grid. (A whole-column
+    # unrolled backward mirroring _fwd_row_kernel measured equal to it at
+    # S=2048, r5; the in-program walk of _bwd_kernel has not been tried
+    # past 1024, where K/V residency and 2n-1 unrolled bodies grow.)
     return (_bwd_tiled(num_heads, head_dim, scale, res, do),)
 
 

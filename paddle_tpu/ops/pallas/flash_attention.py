@@ -182,10 +182,10 @@ def _fwd(q, k, v, qp=None, kp=None, *, scale, causal, kv_valid, block_q, block_k
 
 
 def fused_bwd_math(q, k, v, out, do, lse_col, *, scale, causal, kv_valid):
-    """Whole-sequence fused backward math on 2-D [S, D] operands — shared by
-    this module's _bwd_fused_kernel and causal_flash._bwd_kernel (one body,
-    two layouts). The logits are re-formed ONCE (the split dkv/dq kernel
-    pair re-forms them twice), delta = rowsum(dO*O) is computed in-kernel
+    """Whole-sequence fused backward math on 2-D [S, D] operands, the body
+    of this module's _bwd_fused_kernel. The logits are re-formed ONCE (the
+    split dkv/dq kernel pair re-forms them twice), delta = rowsum(dO*O) is
+    computed in-kernel
     (no [bh,sq,128] broadcast operands), and the five dots run in the input
     dtype (bf16 on the train path) with fp32 accumulation — fp32 MXU dots
     run at a fraction of bf16 rate, which made the old bwd the dominant

@@ -157,12 +157,17 @@ def test_grouped_matmul(chip, k, n):
          ((rows, k), BF16), ((e, k, n), BF16), ((e,), jnp.int32))
 
 
-@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
-def test_causal_flash_qkv_gpt_medium(chip, grad):
+@pytest.mark.parametrize("heads,d,grad", [
+    (16, 64, False), (16, 64, True), (8, 128, True)],
+    ids=["fwd", "grad", "head128-grad"])
+def test_causal_flash_qkv_gpt_medium(chip, heads, d, grad):
     """The packed-attention path of the train phase: gpt2_medium(), batch
-    12, S=1024 — 16 heads x 64 pair-packed into 128-lane blocks."""
+    12, S=1024 — 16 heads x 64 pair-packed into 128-lane blocks. And a
+    width no benchmark cell trains: head_dim 128 (one head a lane block;
+    the scale is no power of two, so it does not fold), through the
+    whole-row forward and the whole-sequence program's tiled backward."""
     cf = _mod("causal_flash")
-    heads, d, seq = 16, 64, 1024
+    seq = 1024
     hpb = cf.heads_per_block(heads, d)
 
     def fwd(qkv):

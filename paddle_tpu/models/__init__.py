@@ -17,3 +17,4 @@ from .llama import (  # noqa: F401
     llama2_7b,
     tiny_llama_config,
 )
+from .nemotron_h import NemotronHConfig, NemotronHForCausalLM  # noqa: F401

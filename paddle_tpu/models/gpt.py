@@ -110,15 +110,10 @@ class GPTAttention(nn.Layer):
 
     def _packed_ok(self, s):
         """Train-path packed kernel eligibility (see causal_flash.py)."""
-        from ..framework.flags import get_flags
         from ..ops.pallas import causal_flash
 
-        flag = get_flags("FLAGS_use_packed_attention")[
-            "FLAGS_use_packed_attention"]
-        if flag is None:
-            flag = jax.default_backend() == "tpu"
-        return (bool(flag) and self.use_flash and self.attn_dropout == 0.0
-                and causal_flash.supported(s, self.head_dim))
+        return (self.use_flash and self.attn_dropout == 0.0
+                and causal_flash.enabled(s, self.head_dim))
 
     def _forward_packed(self, x):
         """Zero-glue train path: qkv projection emitted as

@@ -18,7 +18,6 @@ rms_norm_kernel.cu — SURVEY.md A3.x). TPU-native design mirrors models/gpt:
 """
 from __future__ import annotations
 
-import contextlib
 import math
 from dataclasses import dataclass
 
@@ -28,7 +27,9 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn import functional as F
 from ..framework.tensor import Tensor, apply_op
+from . import moe_stats
 from .generation import GenerationMixin
+from .moe_stats import moe_stats_tap
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "llama2_7b",
            "tiny_llama_config", "tiny_moe_llama_config", "LlamaMoEMLP",
@@ -245,21 +246,7 @@ class LlamaMLP(nn.Layer):
 # pairs, router-entropy sum, routed tokens — which the builder threads
 # out of the trace as ONE extra program output. Unarmed (training,
 # generation, the spec verify program) the layers skip stats entirely,
-# so those traces are unchanged.
-_MOE_STATS_TAP = None
-
-
-@contextlib.contextmanager
-def moe_stats_tap():
-    """Collect per-MoE-layer routing stats emitted during a forward
-    traced under this context. Yields the list the layers append to."""
-    global _MOE_STATS_TAP
-    prev = _MOE_STATS_TAP
-    _MOE_STATS_TAP = tap = []
-    try:
-        yield tap
-    finally:
-        _MOE_STATS_TAP = prev
+# so those traces are unchanged. The tap itself is ``models/moe_stats.py``.
 
 
 def moe_stats_size(config) -> int:
@@ -406,9 +393,10 @@ def _moe_forward(m: LlamaMoEMLP, x):
     for j in range(k):
         out = out + wc[:, j:j + 1] * y_all[gslot[:, j]].astype(jnp.float32)
 
-    if _MOE_STATS_TAP is not None:
+    tap = moe_stats.armed()
+    if tap is not None:
         ent = -jnp.sum(probs * jnp.log(probs + 1e-20), axis=-1)
-        _MOE_STATS_TAP.append(jnp.concatenate([
+        tap.append(jnp.concatenate([
             kc.astype(jnp.float32),
             jnp.sum(tot - kc).astype(jnp.float32)[None],
             jnp.sum(ent)[None],

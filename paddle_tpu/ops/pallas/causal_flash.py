@@ -774,6 +774,19 @@ def supported(seq: int, head_dim: int) -> bool:
     return seq % _BLK == 0 and seq <= limit
 
 
+def enabled(seq: int, head_dim: int) -> bool:
+    """Whether a train path should take this kernel: where
+    ``FLAGS_use_packed_attention`` says so (unset: on the TPU only) and the
+    shape is supported."""
+    from ...framework.flags import get_flags
+
+    flag = get_flags("FLAGS_use_packed_attention")[
+        "FLAGS_use_packed_attention"]
+    if flag is None:
+        flag = jax.default_backend() == "tpu"
+    return bool(flag) and supported(seq, head_dim)
+
+
 def causal_flash_qkv(qkv, num_heads, head_dim=None):
     """Causal self-attention on a packed QKV tensor.
 

@@ -178,6 +178,21 @@ def test_causal_flash_qkv_gpt_medium(chip, heads, d, grad):
     chip(fn, ((12, 3 * heads // hpb, seq, hpb * d), BF16))
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_causal_flash_qkv_nemotron_share(chip, grad):
+    """The attention of ``nemotron3s-pretrain-s4096``: 4 query heads of 128
+    (one key/value head expanded to them) at batch 4, S=4096: the whole-row
+    forward and the per-pair tiled backward."""
+    cf = _mod("causal_flash")
+
+    def fwd(qkv):
+        return cf.causal_flash_qkv(qkv, 4, 128)
+
+    fn = fwd if not grad else jax.grad(
+        lambda qkv: fwd(qkv).astype(jnp.float32).sum())
+    chip(fn, ((4, 12, 4096, 128), BF16))
+
+
 @pytest.mark.parametrize("batch,seq", [(1, 2048), (8, 1024), (8, 128)])
 def test_flash_attention_llama_prefill(chip, batch, seq):
     """The serve phase's prefill: ``F.flash_attention`` at head_dim 128,

@@ -13,10 +13,14 @@ code is the whole model. A share adds its partial result to the residual
 and exchanges nothing: nothing here stands in for absent chips.
 
 * **Mamba-2** runs the chunked (SSD) form: products inside chunks of
-  ``chunk_size`` positions on the matrix unit, and a ``lax.scan`` that
-  carries the float32 state ``[heads, head_dim, state]`` from chunk to
-  chunk. Any sequence length: the tail is padded with steps that neither
-  decay nor add.
+  ``chunk_size`` positions on the matrix unit, and the float32 state
+  ``[heads, head_dim, state]`` carried from chunk to chunk. On the chip,
+  at shapes its tiles take, everything after the cumulative decay exponent
+  is one Pallas kernel pair that walks the chunks with the state in VMEM
+  (``ops/pallas/ssd_scan.py``); elsewhere the same mathematics in
+  ``jax.numpy`` with a ``lax.scan`` over the chunk states, the kernel's twin
+  in the tests. Any sequence length: the tail is padded with steps that
+  neither decay nor add.
 * **Attention** has no rotary embedding (the Mamba layers carry position);
   each key/value head is expanded to the query heads that read it and the
   packed ``causal_flash`` kernel runs on the chip (plain softmax elsewhere).
@@ -127,17 +131,24 @@ def _rms(x, w, eps, groups=1):
 # ------------------------------------------------------------------ Mamba-2
 
 
-def ssd_chunked(x, dt, a, bm, cm, chunk):
+def ssd_chunked(x, dt, a, bm, cm, chunk, d=None):
     """The state-space recurrence in its chunked matrix form.
 
     ``h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x) B_t``, ``y_t = h_t C_t`` with
     x ``[b, s, h, p]``, dt ``[b, s, h]`` (positive, float32), a ``[h]``
     (negative, float32), B and C ``[b, s, g, n]``; head ``i`` reads group
-    ``i // (h / g)``. Returns y ``[b, s, h, p]`` float32. Products take
-    their operands in x's type and accumulate in float32; decays, the
-    carried state ``[b, h, p, n]`` and the sums are float32. Every exponent
-    is a sum of ``dt a`` over a span that runs forward in time, so none is
-    positive."""
+    ``i // (h / g)``. Returns y ``[b, s, h, p]`` float32, plus the skip
+    ``d x`` where d ``[h]`` is given. Products take their operands in x's
+    type and accumulate in float32; decays, the carried state ``[b, h, p,
+    n]`` and the sums are float32. Every exponent is a sum of ``dt a`` over
+    a span that runs forward in time, so none is positive.
+
+    The padding, the chunked views and the cumulative sum ``acs`` are
+    ``jax.numpy`` on every path. Where ``ssd_scan.enabled`` says so (the TPU
+    backend; chunk and state multiples of 128, a group's heads filling whole
+    lane tiles and fitting its VMEM budget) the rest, ``dt x`` and ``d x``
+    included, is the kernel pair ``ssd_scan_fwd`` / ``ssd_scan_bwd``; the
+    lines below it run otherwise, with the precisions the kernels keep."""
     b, s, h, p = x.shape
     g, n = bm.shape[2:]
     pad = -s % chunk
@@ -150,6 +161,13 @@ def ssd_chunked(x, dt, a, bm, cm, chunk):
     bc, cc = bm.reshape(b, nc, q, g, n), cm.reshape(b, nc, q, g, n)
     dtc = dt.astype(f32).reshape(b, nc, q, g, h // g)
     acs = jnp.cumsum(dtc * a.astype(f32).reshape(g, h // g), axis=2)
+    from ..ops.pallas import ssd_scan
+
+    if ssd_scan.enabled(q, n, h // g, p, jnp.dtype(lo).itemsize):
+        # everything below, chunk by chunk with the state held in VMEM
+        skip = jnp.zeros((h,), f32) if d is None else d.astype(f32)
+        y = ssd_scan.ssd_scan(cc, bc, acs, dtc, xc, skip.reshape(g, h // g))
+        return y.reshape(b, nc * q, h, p)[:, :s]
     acs_t = jnp.moveaxis(acs, 2, -1)                         # [b,c,g,e,q]
     xdt = (xc.astype(f32) * dtc[..., None]).astype(lo)       # dt_j x_j
 
@@ -182,7 +200,10 @@ def ssd_chunked(x, dt, a, bm, cm, chunk):
     y = y + jnp.exp(acs)[..., None] * jnp.einsum(
         "bcign,bcgepn->bcigep", cc, entering.astype(lo),
         preferred_element_type=f32)
-    return y.reshape(b, nc * q, h, p)[:, :s]
+    y = y.reshape(b, nc * q, h, p)
+    if d is not None:
+        y = y + d.astype(f32)[:, None] * x.astype(f32)
+    return y[:, :s]
 
 
 class Mamba2Mixer(nn.Layer):
@@ -239,8 +260,7 @@ class Mamba2Mixer(nn.Layer):
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
         y = ssd_chunked(x, dt, -jnp.exp(a_log.astype(f32)),
                         bm.reshape(b, s, g, n), cm.reshape(b, s, g, n),
-                        self.chunk)
-        y = y + d.astype(f32)[:, None] * x.astype(f32)
+                        self.chunk, d)
         y = y.reshape(b, s, self.inner) * jax.nn.silu(z.astype(f32))
         return _mm(_rms(y, w_norm, self.eps, groups=g).astype(u.dtype), w_out)
 

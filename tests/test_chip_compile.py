@@ -78,7 +78,7 @@ def chip(one_chip, no_persistent_cache, monkeypatch):
     ``jax.default_backend()`` (the CPU here) and would take their jnp
     twins, so the test steers their ``_interpret`` to the chip branch."""
     for name in ("paged_attention", "decode_attention", "causal_flash",
-                 "flash_attention"):
+                 "flash_attention", "ssd_scan"):
         monkeypatch.setattr(_mod(name), "_interpret", lambda: False)
 
     def compiles(fn, *shapes):
@@ -191,6 +191,39 @@ def test_causal_flash_qkv_nemotron_share(chip, grad):
     fn = fwd if not grad else jax.grad(
         lambda qkv: fwd(qkv).astype(jnp.float32).sum())
     chip(fn, ((4, 12, 4096, 128), BF16))
+
+
+@pytest.mark.parametrize("batch,seq,heads,groups,chunk,dtype", [
+    (4, 4096, 16, 1, 128, BF16), (1, 4096, 128, 8, 128, BF16),
+    (2, 1024, 32, 1, 128, BF16), (2, 1024, 16, 1, 256, BF16),
+    (2, 1024, 16, 1, 128, jnp.float32)],
+    ids=["nemotron-share", "uncut-8-groups", "32-heads-a-group", "chunk-256",
+         "float32"])
+def test_ssd_scan_pair(chip, monkeypatch, batch, seq, heads, groups, chunk,
+                       dtype):
+    """The Mamba-2 chunk walk of ``nemotron3s-pretrain-s4096`` (16 heads of
+    64 on one group, state 128, batch 4, S=4096), forward and backward
+    through ``ssd_chunked``; the uncut layer's 8 groups of 16 heads (the
+    grid's group axis); and the widest shapes ``ssd_scan.supported`` lets
+    through, whose blocks must still fit the scoped fast memory."""
+    from paddle_tpu.models.nemotron_h import ssd_chunked
+
+    ssd = _mod("ssd_scan")
+    monkeypatch.setattr(ssd, "enabled", ssd.supported)
+    p, n = 64, 128
+    assert ssd.supported(chunk, n, heads // groups, p,
+                         jnp.dtype(dtype).itemsize)
+
+    def both(x, dt, a, bm, cm, d, dy):
+        y, vjp = jax.vjp(lambda *t: ssd_chunked(*t[:5], chunk, t[5]),
+                         x, dt, a, bm, cm, d)
+        return (y,) + vjp(dy)
+
+    f32 = jnp.float32
+    chip(both, ((batch, seq, heads, p), dtype), ((batch, seq, heads), f32),
+         ((heads,), f32), ((batch, seq, groups, n), dtype),
+         ((batch, seq, groups, n), dtype), ((heads,), f32),
+         ((batch, seq, heads, p), f32))
 
 
 @pytest.mark.parametrize("batch,seq", [(1, 2048), (8, 1024), (8, 128)])

@@ -244,7 +244,19 @@ def _quant():
              _sds((256,), jnp.float32)))
 
 
+def _ssd_scan():
+    from paddle_tpu.ops.pallas import ssd_scan
+
+    f32 = jnp.float32
+    f = lambda c, b, a, dt, x, d: ssd_scan.ssd_scan(c, b, a, dt, x, d).sum()
+    return (jax.grad(f, argnums=4),
+            (_sds((1, 2, 128, 1, 128)), _sds((1, 2, 128, 1, 128)),
+             _sds((1, 2, 128, 1, 2), f32), _sds((1, 2, 128, 1, 2), f32),
+             _sds((1, 2, 128, 1, 2, 64)), _sds((1, 2), f32)))
+
+
 @pytest.mark.parametrize("recipe,expected", [
+    (_ssd_scan, ["ssd_scan_bwd", "ssd_scan_fwd"]),
     (lambda: _causal(256), ["causal_flash_bwd", "causal_flash_fwd"]),
     (lambda: _causal(1024), ["causal_flash_bwd", "causal_flash_fwd_row"]),
     (lambda: _causal(2048),
@@ -261,7 +273,7 @@ def _quant():
     (lambda: _paged_slab(3), ["paged_attention_verify"]),
     (_grouped, ["grouped_matmul"]),
     (_quant, ["quant_matmul"]),
-], ids=["causal_flash-s256", "causal_flash-s1024", "causal_flash-s2048",
+], ids=["ssd_scan", "causal_flash-s256", "causal_flash-s1024", "causal_flash-s2048",
         "causal_flash-fwd_tiled", "flash_attention-s256",
         "flash_attention-s2048", "decode_attention",
         "decode_attention_slab", "paged_attention", "paged_attention_slab",
@@ -280,7 +292,7 @@ def test_no_pallas_call_site_is_without_a_name():
         src = open(path).read()
         sites += len(re.findall(r"pl\.pallas_call\(", src))
         named += len(re.findall(r"^\s+name=", src, re.M))
-    assert sites == named == 15
+    assert sites == named == 17
 
 
 # -------------------------------------------------------------- host side

@@ -22,7 +22,6 @@
 # onchip  — the real-TPU lane (VERDICT r3 #4): Pallas kernels through
 #           Mosaic (non-interpret) + PJRT memory tests. Needs the chip, and
 #           the chip belongs to ONE process: run one lane at a time.
-# bench   — the driver-visible headline benchmark (real TPU).
 
 lint:
 	python tools/lint_tpu.py paddle_tpu examples tools --fail-on-violation
@@ -115,8 +114,5 @@ chip-smoke:
 onchip:
 	PADDLE_TPU_ONCHIP=1 python -m pytest tests/onchip -q -rs
 
-bench:
-	python bench.py
-
 .PHONY: lint races analyze plan chaos chaos-serve chaos-integrity \
-	chaos-tier serve-smoke trace-smoke test chip-smoke onchip bench
+	chaos-tier serve-smoke trace-smoke test chip-smoke onchip

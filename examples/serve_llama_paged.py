@@ -45,7 +45,7 @@ def run_cluster_smoke(model, cfg, args):
     prefill pool, their KV ships to a decode replica (digest-verified,
     recompute on any failure), shared-prefix streams converge onto warm
     decode replicas. Prints the handoff/fallback counters the chaos
-    suite and bench_cluster gate on."""
+    suite gates on."""
     import time
 
     import jax.numpy as jnp

@@ -14,8 +14,7 @@ axes and donation decisions —
   ppermutes;
 * **donation** — donated-but-unusable buffers (silent copy) and missed
   copy-free donation opportunities;
-* **cost** — roofline FLOPs/HBM-bytes rollup with a predicted step time
-  (``bench.py`` reports it next to each measured roofline);
+* **cost** — roofline FLOPs/HBM-bytes rollup with a predicted step time;
 * **sharding** (tpushard) — implicit full replication of parameter-
   sized shard_map operands, resharding copies at region boundaries,
   degenerate/materializing collectives, and the host-divergence

@@ -8,7 +8,7 @@ does the talking hide under the compute". Three outputs, all static:
 * **predicted comm time** — every collective costed with the standard
   ring/torus formulas below, using per-device ICI peak tables (same
   single-source-of-truth convention as the HBM/FLOPs tables in
-  ``cost.py``; bench.py and tools/multichip.py import THESE numbers);
+  ``cost.py``; tools/multichip.py imports THESE numbers);
 * **comm/compute overlap fraction** — a dependency-window model: the
   compute issued between a collective and its first consumer can hide
   under the transfer (Megatron-style overlap). Windows are counted per
@@ -69,7 +69,7 @@ ICI_LATENCY_S = 1e-6
 # at ~0.5ms on the virtual-CPU mesh; on real ICI the launch+rendezvous
 # cost is a few microseconds. The planner prices device-retargeted
 # plans with this constant so small latency-bound collectives (the
-# decode regime that MULTICHIP_r11 mispredicted 15x) are never free.
+# decode regime) are never free.
 ICI_COLLECTIVE_OVERHEAD_S = 2e-6
 
 

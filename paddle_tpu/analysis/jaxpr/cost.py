@@ -4,14 +4,11 @@ Per-op FLOPs and HBM bytes rolled up into a predicted step time:
 ``sum over ops of max(flops / peak_flops, bytes / hbm_bw)`` — the
 op-serial roofline. Byte accounting reuses the liveness pass's
 materialization model (a fused elementwise producer streams through
-registers; only HBM-resident buffers count), which is the same
-convention ``bench.py``'s measured rooflines use via
-``weight_stream_bytes``: actual storage bytes, so int8/int4 weight
-streams count their packed sizes and predicted-vs-measured divide by
-the same byte model.
+registers; only HBM-resident buffers count): actual storage bytes, so
+int8/int4 weight streams count their packed sizes.
 
 The device peak table lives HERE (``DEVICE_PEAKS``) and everything else —
-comm model, planner, profiler.mfu, bench.py — reads it: one source of
+comm model, planner, profiler.mfu — reads it: one source of
 truth for "what the hardware allows" (ROADMAP north star).
 """
 from __future__ import annotations

@@ -101,8 +101,7 @@ define_flag("FLAGS_check_ownership",
             "— the dynamic twin of the tpurace TPL1501-TPL1504 static "
             "pass. Also settable via env PADDLE_TPU_CHECK_OWNERSHIP=1. "
             "Off by default: adds a dict lookup to every guarded "
-            "attribute write (<2%% end-to-end, gated by "
-            "bench_ownership)")
+            "attribute write")
 define_flag("FLAGS_check_tracers",
             os.environ.get("PADDLE_TPU_CHECK_TRACERS", "").lower()
             in ("1", "true", "yes"),

@@ -66,7 +66,7 @@ import numpy as np
 from .errors import IntegrityError
 
 __all__ = ["IntegrityConfig", "IntegritySentinel",
-           "count_integrity_check", "bench_integrity_overhead"]
+           "count_integrity_check"]
 
 
 def _counter(name: str, help_: str):
@@ -423,92 +423,3 @@ class IntegritySentinel:
         err.__cause__ = exc
         self.last_error = err
         _count_check("sentinel", False)
-
-
-# --------------------------------------------------------------- benchmark
-def bench_integrity_overhead(cfg, on_tpu: bool):
-    """bench.py ``bench_integrity`` block (ISSUE 14 satellite): the
-    audit layer's steady-state cost as an interleaved-rep ratio of
-    median scheduling-step times, sentinel ``strict`` vs off, over the
-    same prefix-heavy workload (so the KV checksum path actually
-    exercises). Per-engine medians are floored at the host jitter floor
-    (50 ms on the single-core CPU smoke host, 20 ms on TPU — memory:
-    one cold compile lands in p99 otherwise) before the ratio, and the
-    gate is ``integrity_overhead_frac`` (median-on / median-off - 1)
-    < 2%."""
-    import time
-
-    from ..models.gpt import GPTConfig, GPTForCausalLM
-    from ..observability import metric_total
-    from .engine import Engine
-
-    del cfg  # the block sizes its own tiny config (CPU smoke parity)
-    from .. import seed as _seed
-
-    _seed(0)
-    mcfg = GPTConfig(hidden_size=128, num_layers=2, num_heads=4,
-                     max_position=256, vocab_size=1024)
-    model = GPTForCausalLM(mcfg)
-    model.eval()
-
-    rng = np.random.default_rng(7)
-    shared = rng.integers(0, 1024, (32,))
-
-    def workload(eng):
-        # prefix-heavy (shared 32-token template + per-request tail):
-        # splice/register probes fire on the hit path, not just misses
-        reqs = []
-        for i in range(4):
-            tail = rng.integers(0, 1024, (4 + i,))
-            reqs.append(eng.add_request(
-                np.concatenate([shared, tail]), 8))
-        return reqs
-
-    engines = {
-        "off": Engine(model, max_slots=4, num_pages=128, page_size=8,
-                      chunk_size=4, dtype=jnp.float32, prefix_cache=True,
-                      integrity=None),
-        "on": Engine(model, max_slots=4, num_pages=128, page_size=8,
-                     chunk_size=4, dtype=jnp.float32, prefix_cache=True,
-                     integrity={"mode": "strict", "weight_audit_every": 4,
-                                "shadow_every": 8}),
-    }
-    checks0 = metric_total("paddle_tpu_integrity_checks_total")
-    fails0 = metric_total("paddle_tpu_integrity_failures_total")
-    # warmup: compile every program both engines will touch
-    for eng in engines.values():
-        workload(eng)
-        eng.run()
-    reps, steps = 4, {"off": [], "on": []}
-    for _ in range(reps):
-        for key, eng in engines.items():
-            workload(eng)
-            while True:
-                t0 = time.perf_counter()
-                live = eng.step()
-                steps[key].append(time.perf_counter() - t0)
-                if not live:
-                    break
-    floor_s = (0.020 if on_tpu else 0.050)
-    med_off = float(np.median(steps["off"]))
-    med_on = float(np.median(steps["on"]))
-    ratio = max(med_on, floor_s) / max(med_off, floor_s)
-    overhead = max(0.0, ratio - 1.0)
-    checks = int(metric_total("paddle_tpu_integrity_checks_total")
-                 - checks0)
-    fails = int(metric_total("paddle_tpu_integrity_failures_total")
-                - fails0)
-    ok = overhead < 0.02 and fails == 0 and checks > 0
-    if not ok:
-        print(f"WARNING: bench_integrity gate failed: overhead="
-              f"{overhead:.4f} (<0.02 required), checks={checks} (>0), "
-              f"failures={fails} (==0)")
-    return {
-        "integrity_overhead_frac": round(overhead, 4),
-        "integrity_step_ms_off": round(1e3 * med_off, 3),
-        "integrity_step_ms_on": round(1e3 * med_on, 3),
-        "integrity_jitter_floor_ms": 1e3 * floor_s,
-        "integrity_bench_checks": checks,
-        "integrity_bench_failures": fails,
-        "integrity_ok": bool(ok),
-    }

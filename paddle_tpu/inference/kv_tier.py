@@ -65,7 +65,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-__all__ = ["HostTier", "bench_kv_tier", "capture_handoff_spill"]
+__all__ = ["HostTier", "capture_handoff_spill"]
 
 # capture/restore waves use one fixed index width (mirrors
 # HostTier.COPY_WIDTH): a per-wave width would mint a fresh XLA program
@@ -572,123 +572,3 @@ class HostTier:
                      time.perf_counter() - t0))
             else:
                 self._done.append(("promote-bad", gen, ent, token))
-
-
-# --------------------------------------------------------------- benchmark
-def bench_kv_tier(cfg, on_tpu: bool):
-    """bench.py ``bench_kv_tier`` block (ISSUE 15 satellite): a
-    templated-overlap workload whose CACHED working set is ~10x the
-    paged pool — the regime where the un-tiered prefix cache collapses
-    (every template is reclaimed before its next visit) and the host
-    tier keeps paying. Round-robin template visits with distinct tails,
-    closed-loop (submit + step), so promote prefetch overlaps queue
-    wait exactly as in serving.
-
-    The model is sized so a template's prefill is genuinely expensive
-    relative to a page copy (hidden 384: the compute a hit skips grows
-    ~quadratically with width, the bytes the tier moves only linearly —
-    at toy widths the single-core host spends as long hashing/copying
-    as it would recomputing and the comparison measures nothing).
-
-    Gates (CPU smoke green; the host is single-core, so the throughput
-    comparison is an interleaved-rep ratio of medians floored at the
-    50 ms jitter floor — no absolute-latency gates):
-
-    * sustained prefix hit-rate >= 0.8 tier-on where tier-off stays
-      < 0.2 — the headline: reuse survives a working set the HBM pool
-      cannot hold;
-    * effective prefill throughput (prompt tokens ingested/s over the
-      measured passes) tier-on >= tier-off (ratio >= 1.0): splices +
-      page copies must beat recompute even on a host where the copy,
-      the hash, and the compute all share one core;
-    * > 0 promotions and 0 drops (every round trip verified clean)."""
-    from ..models.gpt import GPTConfig, GPTForCausalLM
-    from .engine import Engine
-
-    del cfg  # the block sizes its own config (CPU smoke parity)
-    import jax.numpy as jnp
-
-    from .. import seed as _seed
-
-    _seed(0)
-    mcfg = GPTConfig(hidden_size=384, num_layers=2, num_heads=4,
-                     max_position=256, vocab_size=512)
-    model = GPTForCausalLM(mcfg)
-    model.eval()
-
-    ps, slots, num_pages = 16, 2, 24
-    n_templates, template_len, tail_len, budget = 21, 144, 16, 2
-    host_pages = 512
-    rng = np.random.default_rng(7)
-    templates = [rng.integers(0, 512, (template_len,))
-                 for _ in range(n_templates)]
-    work_pages = n_templates * (template_len // ps)
-    ws_ratio = work_pages / (num_pages - 1)
-
-    def make(hp):
-        return Engine(model, max_slots=slots, num_pages=num_pages,
-                      page_size=ps, chunk_size=4, dtype=jnp.float32,
-                      prefix_cache=True, kv_host_pages=hp)
-
-    seed = [0]
-
-    def round_once(eng):
-        reqs = []
-        for t in range(n_templates):
-            seed[0] += 1
-            r = np.random.default_rng(10_000 + seed[0])
-            prompt = np.concatenate(
-                [templates[t], r.integers(0, 512, (tail_len,))])
-            reqs.append(eng.add_request(prompt, budget))
-            eng.step()
-            eng.step()
-        eng.run()
-        return sum(int(q.prompt.size) for q in reqs)
-
-    engines = {"on": make(host_pages), "off": make(0)}
-    for eng in engines.values():
-        round_once(eng)  # warmup: compiles + first cache fill
-    marks = {k: (e._pcache.hits, e._pcache.misses)
-             for k, e in engines.items()}
-    reps, times, ptoks = 3, {"on": [], "off": []}, {"on": 0, "off": 0}
-    for _ in range(reps):
-        for key, eng in engines.items():
-            t0 = time.perf_counter()
-            ptoks[key] += round_once(eng)
-            times[key].append(time.perf_counter() - t0)
-
-    floor_s = 0.020 if on_tpu else 0.050
-    med = {k: max(float(np.median(v)), floor_s)
-           for k, v in times.items()}
-    thr = {k: ptoks[k] / (med[k] * reps) for k in engines}
-    ratio = thr["on"] / thr["off"] if thr["off"] else 0.0
-    rates = {}
-    for key, eng in engines.items():
-        h0, m0 = marks[key]
-        pc = eng._pcache
-        dh, dm = pc.hits - h0, pc.misses - m0
-        rates[key] = dh / max(1, dh + dm)
-    tier = engines["on"].kv_tier
-    ok = (rates["on"] >= 0.8 and rates["off"] < 0.2 and ratio >= 1.0
-          and tier.promotions > 0 and tier.drops == 0)
-    if not ok:
-        print(f"WARNING: bench_kv_tier gate failed: hit_rate_on="
-              f"{rates['on']:.3f} (>=0.8), hit_rate_off="
-              f"{rates['off']:.3f} (<0.2), throughput_ratio="
-              f"{ratio:.3f} (>=1.0), promotions={tier.promotions} "
-              f"(>0), drops={tier.drops} (==0)")
-    out = {
-        "kv_tier_working_set_x_pool": round(ws_ratio, 2),
-        "kv_tier_hit_rate_on": round(rates["on"], 3),
-        "kv_tier_hit_rate_off": round(rates["off"], 3),
-        "kv_tier_prefill_ratio": round(ratio, 3),
-        "kv_tier_prefill_tokens_per_sec": round(thr["on"], 1),
-        "kv_tier_prefill_tokens_per_sec_off": round(thr["off"], 1),
-        "kv_tier_demotions": int(tier.demotions),
-        "kv_tier_promotions": int(tier.promotions),
-        "kv_tier_drops": int(tier.drops),
-        "kv_tier_jitter_floor_ms": 1e3 * floor_s,
-        "kv_tier_ok": bool(ok),
-    }
-    engines["on"]._cache.shutdown_tier()
-    return out

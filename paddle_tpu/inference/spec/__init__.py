@@ -64,7 +64,7 @@ class _SpecMetrics:
 class SpecDecoder:
     """Engine-side spec-decode state: the drafter, the per-request
     adaptive controller, the compiled verify programs, and the rolling
-    stats bench.py / the Prometheus scrape report."""
+    stats the Prometheus scrape reports."""
 
     def __init__(self, engine, mode: str, k: int = 4, draft_model=None,
                  max_ngram: int = 3, min_ngram: int = 1):
@@ -93,7 +93,7 @@ class SpecDecoder:
         self._m: Optional[_SpecMetrics] = (
             _SpecMetrics(self.drafter.name)
             if engine._m is not None else None)
-        # rolling totals for bench.py and the adaptive-depth export
+        # rolling totals for the adaptive-depth export
         self.verify_steps = 0      # verify dispatches
         self.request_steps = 0     # per-request verify rows harvested
         self.tokens_landed = 0     # tokens delivered via spec steps

@@ -12,8 +12,7 @@ program takes either) aligned with the sorted-slot batch order and
   extra dispatches, works on any model including the tiny test configs —
   and is remarkably effective on repetitive continuations (exactly what
   memory-bound decode serves a lot of: code, templated text, and — on
-  the untrained tiny models — the greedy repetition loops the bench
-  workload exploits).
+  the untrained tiny models — greedy repetition loops).
 * ``DraftModelDrafter`` — a small causal LM drafts k tokens by greedy
   chained decode over ITS OWN paged KV pool (same page/table machinery
   as the engine, one jitted k-step scan per proposal). The draft cache

@@ -100,7 +100,7 @@ def tiny_llama_config(**kw):
 def tiny_moe_llama_config(**kw):
     """Tiny MoE twin of ``tiny_llama_config``: 8 experts, top-2, 64-wide
     expert FFs — active params per token (2 * 64) equal the tiny dense
-    MLP's 128-wide FF, so the bench/identity suites compare like for
+    MLP's 128-wide FF, so the identity suites compare like for
     like. 8 experts divide every ep in {1, 2, 4, 8}."""
     base = dict(vocab_size=128, hidden_size=64, num_layers=2, num_heads=4,
                 num_kv_heads=2, intermediate_size=128, max_position=128,

@@ -61,7 +61,7 @@ __all__ = [
 
 def metric_total(name: str, registry: Registry = REGISTRY) -> float:
     """Sum of a counter/gauge across all label series; 0.0 if absent.
-    Convenience for embedding single numbers (bench.py)."""
+    Convenience for embedding single numbers."""
     m = registry.get(name)
     if m is None:
         return 0.0

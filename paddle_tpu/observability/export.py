@@ -9,8 +9,7 @@ Three consumers, one registry:
   / ``_sum`` / ``_count`` triple, so stock Prometheus/Grafana histogram
   functions (``histogram_quantile``) work unmodified.
 * **JSONL** — one self-contained snapshot line per call, append-only:
-  the plain-tooling sink (jq, pandas) and what ``bench.py`` embeds so
-  the perf trajectory carries observability data.
+  the plain-tooling sink (jq, pandas).
 * **TensorBoard** — training runs already write scalars through
   ``utils/tbevents.EventFileWriter``; the bridge publishes the same
   registry there, mapping metric ``name{label="v"}`` to tag
@@ -156,7 +155,7 @@ def start_metrics_server(port: int = 0,
 def write_jsonl_snapshot(path: str, registry: Optional[Registry] = None,
                          extra: Optional[Dict] = None) -> Dict:
     """Append one self-contained snapshot line to ``path``. Returns the
-    record written (callers embed it — e.g. bench.py)."""
+    record written (callers may embed it)."""
     registry = registry or REGISTRY
     record = {"ts": time.time(), "metrics": registry.snapshot()}
     if extra:

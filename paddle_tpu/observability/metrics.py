@@ -14,9 +14,7 @@ Design constraints (the hot path is the serving scheduler's host loop):
 * **Host-side only.** Recording is plain Python on plain floats — never
   called inside traced code (tpulint TPL601 enforces this). A metric
   update is a handful of bytecode ops; one scheduling step records ~10
-  samples while covering ``chunk_size * chain`` decoded tokens, so the
-  measured overhead budget (<1% on the decode microbench,
-  ``tools/mb_metrics.py``) holds with room to spare.
+  samples while covering ``chunk_size * chain`` decoded tokens.
 * **No locks on the update path.** Under the GIL a ``+=`` on an instance
   attribute can at worst lose a racing increment — acceptable for
   monitoring counters; registration (get-or-create) IS locked because it

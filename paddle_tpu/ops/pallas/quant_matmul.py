@@ -12,9 +12,8 @@ byte streams from HBM exactly once:
   slab — low-nibble rows stacked over high-nibble rows, paired with the
   activation's pre-split even/odd K columns so no in-kernel sublane
   interleave is needed — and a SINGLE full-depth MXU dot contracts the
-  slab (ISSUE 9 tentpole c: the previous two half-depth dots per block
-  doubled the accumulator traffic and left int4 decode SLOWER than int8
-  in BENCH_r05, 0.71 vs 0.533 ms/token, despite half the weight bytes).
+  slab (two half-depth dots per block would double the accumulator
+  traffic, which costs more than halving the weight bytes saves).
 
 f32 accumulation lives in VMEM scratch across the k grid dimension; the
 per-output-channel scale (and optional bias) apply in the epilogue at the
@@ -23,9 +22,9 @@ DIVISOR-AWARE (``select_block_shapes``): a block that does not divide the
 problem forces ``jnp.pad`` to materialize a padded copy of the whole
 weight OUTSIDE the kernel — an extra full read+write of the weight
 stream per GEMM, which is exactly the traffic the kernel exists to
-avoid (768-dim layers padding to 1024 on both axes was the other half of
-the BENCH_r05 int4 regression). Non-conforming shapes still pad and stay
-correct. Shapes are picked per (rows, in, out, dtype) and memoized
+avoid (768-dim layers would pad to 1024 on both axes). Non-conforming
+shapes still pad and stay correct. Shapes are picked per
+(rows, in, out, dtype) and memoized
 through ``framework.compile_cache.memoize_kernel_choice`` so a warm
 server never retunes mid-flight. On non-TPU backends the kernel runs in
 Pallas interpret mode (exact, slow) — CI covers it; dispatch policy

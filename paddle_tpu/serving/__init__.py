@@ -16,9 +16,6 @@ The layer that turns the paged ``inference.Engine`` into a *service*:
   ``/v1/chat/completions``) decoupled from the engine by the fair
   queue. tpulint rule TPL901 enforces that nothing inside this
   package's ``async def`` bodies blocks the event loop.
-* :mod:`loadgen` — closed- and open-loop SLO load generation driving
-  the frontend; ``bench_slo`` gates p99 TTFT/TPOT at a target QPS and
-  the multi-step speedup (bench.py's ``slo_*``/``multistep_*`` keys).
 * :mod:`replica` / :mod:`router` — the replica-resilience layer
   (ISSUE 13): supervised engine replicas (in-process or subprocess
   workers behind the ApiServer protocol) with split liveness/readiness,

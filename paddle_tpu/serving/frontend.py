@@ -82,7 +82,7 @@ class StreamTicket:
         # and the ORIGINAL submit time — a migrated stream carries its
         # first submission's clock so TTFT attribution spans replicas
         self.trace = trace
-        # host-side latency marks (the SLO loadgen's measurement side)
+        # host-side latency marks (what a load generator reads)
         self.t_submit = time.perf_counter()
         self.t_origin = (float(t_origin) if t_origin is not None
                          else self.t_submit)

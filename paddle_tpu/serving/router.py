@@ -46,12 +46,11 @@ the replica-resilience layer above the PR 12 front-end:
 
 Metrics: ``paddle_tpu_router_migrations_total``,
 ``paddle_tpu_replica_restarts_total``, ``paddle_tpu_router_hedges_total``,
-``paddle_tpu_router_replicas_ready`` — the bench_failover block and the
-chaos suite assert on these.
+``paddle_tpu_router_replicas_ready`` — the chaos suite asserts on these.
 
 Client callbacks fire from replica-owned threads; RouterTicket does the
-locking. Stdlib-only (tickets mirror StreamTicket's surface, so the
-SLO load generator drives a Router exactly like a ServingFrontend).
+locking. Stdlib-only (tickets mirror StreamTicket's surface, so a
+load generator drives a Router exactly like a ServingFrontend).
 
 ISSUE 20 layers :mod:`~paddle_tpu.serving.cluster` above this router:
 ``Router(pools={"prefill": k, "decode": m})`` activates role pools,

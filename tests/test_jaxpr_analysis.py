@@ -295,7 +295,7 @@ class TestAnalyzeOnCompileHook:
 
 class TestCommModel:
     """tpushard comm roofline: cost-formula ground truths + the ICI
-    tables bench.py/tools/multichip.py reprice against."""
+    tables tools/multichip.py reprices against."""
 
     def test_collective_cost_formulas_exact(self):
         from paddle_tpu.analysis.jaxpr.comm import collective_cost

@@ -40,8 +40,7 @@ def _artifact():
 class TestCommittedCalibration:
     def test_decode_band_and_train_gate(self):
         """The tentpole's acceptance bands, asserted on the committed
-        artifact: decode pred_vs_measured in [0.8, 1.25], train <= 1.15
-        (MULTICHIP_r11's decode was mispredicted ~15x)."""
+        artifact: decode pred_vs_measured in [0.8, 1.25], train <= 1.15."""
         d = _artifact()
         assert d["ok"] is True
         serving = d["tp_serving"]

@@ -756,7 +756,7 @@ ENTRIES: List[Entry] = [
     Entry("hapi_train_step", _hapi_train_step,
           "hapi Model-style MLP train step (fwd+bwd+SGD)"),
     Entry("gpt_train_step", _gpt_train_step,
-          "bench.py train step: bf16 compute, fp32 master, momentum"),
+          "train step: bf16 compute, fp32 master, momentum"),
     Entry("quant_matmul_int8", lambda: _quant_matmul("int8"),
           "weight-only int8 GEMM (nn.quant XLA path)"),
     Entry("quant_matmul_int4", lambda: _quant_matmul("int4"),

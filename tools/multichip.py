@@ -15,8 +15,7 @@ no measured counterpart to track drift against. This harness emits:
   under a host-calibrated device profile (matmul flops, memcpy
   bandwidth, and per-collective-step latency are measured on THIS
   host, then fed through the same cost formulas the TPC601 advisory
-  uses) — with the predicted/measured ratio bench.py's metrics block
-  records.
+  uses) — with the predicted/measured ratio.
 
 Runs on the virtual-8-CPU-device mesh (no TPU slice needed); on a real
 slice the same code measures real ICI. ``--json`` prints one
@@ -58,8 +57,8 @@ _force_virtual_devices()
 
 # payload sweep shape: collectives per swept program and the payload
 # grid (f32 element counts, all divisible by the 8-device mesh). The
-# grid brackets the regimes MULTICHIP_r11 got wrong: decode-sized
-# psums (~2KiB) up through train-step activations (~1MiB).
+# grid brackets both regimes: decode-sized psums (~2KiB) up through
+# train-step activations (~1MiB).
 _SWEEP_COLLECTIVES = 4
 _SWEEP_ELEMS = (512, 4096, 32768, 262144)
 
@@ -436,8 +435,7 @@ def tp_serving_metrics(n_devices: int, steps: int = 16
     chunk+decode step — the two programs a disaggregated serving step
     dispatches — each timed warm against a collective-stripped twin
     (same sharded weights and per-shard compute, psums skipped), with
-    the tpushard comm rollup priced under the host calibration. The
-    combined ``pred_vs_measured`` rides bench.py's existing 2x gate."""
+    the tpushard comm rollup priced under the host calibration."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -582,8 +580,7 @@ def main(argv: Optional[list] = None) -> int:
                     "measured-vs-predicted TP comm roofline")
     ap.add_argument("--n-devices", type=int, default=8)
     ap.add_argument("--tp-only", action="store_true",
-                    help="skip the strategy-surface suites (bench.py's "
-                         "fast path)")
+                    help="skip the strategy-surface suites")
     ap.add_argument("--json", action="store_true",
                     help="print one machine-readable JSON object")
     ap.add_argument("--out", default=None,

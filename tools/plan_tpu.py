@@ -26,8 +26,8 @@ Modes:
   inverted from);
 * ``--calibrated FILE`` — price comm with the host-calibrated
   per-collective curves from a MULTICHIP_r16-style artifact instead of
-  the pure device tables (bench.py's ``bench_plan`` uses this; goldens
-  always use device tables so they stay host-independent).
+  the pure device tables (goldens always use device tables so they
+  stay host-independent).
 
 Exit codes: 0 clean, 1 regression/audit failure, 2 usage error.
 """

@@ -26,10 +26,14 @@ and exchanges nothing: nothing here stands in for absent chips.
   packed ``causal_flash`` kernel runs on the chip (plain softmax elsewhere).
 * **LatentMoE** routes over ALL published experts (sigmoid scores, a
   selection bias without gradient, top-k normalised over the chosen and
-  scaled), computes the experts held here in the latent width through
-  ``jax.lax.ragged_dot`` over a static buffer of (token, expert) pairs
-  sorted by expert, and drops nothing: the buffer is sized from a stated
-  bound and the pairs over it are counted (``moe_stats_tap``), never hidden.
+  scaled; the chosen set is ``jax.lax.top_k``'s, equal scores to the lowest
+  index, found as a dense mask from each token's k-th largest score with
+  no index sorted: ``ops/pallas/topk_mask.py``, a kernel on the chip and
+  ``jax.numpy`` elsewhere), computes the experts held here in the latent
+  width through ``jax.lax.ragged_dot`` over a static buffer of (token,
+  expert) pairs sorted by expert, and drops nothing: the buffer is sized
+  from a stated bound and the pairs over it are counted
+  (``moe_stats_tap``), never hidden.
 """
 from __future__ import annotations
 
@@ -325,7 +329,11 @@ class NemotronHAttention(nn.Layer):
 class LatentMoE(nn.Layer):
     """``s = sigmoid(u W_r)``; the k largest of ``s + bias`` are chosen
     (the bias is a buffer: it selects, carries no gradient and does not
-    weigh); ``w_e = scale * s_e / sum over the chosen of s``; the experts
+    weigh). Equal scores go to the lowest index, ``jax.lax.top_k``'s order,
+    and the set is found by threshold: the entries above a token's k-th
+    largest and as many of those equal to it as fill the k places, a
+    boolean ``[tokens, experts]`` mask and never an index (``topk_mask``);
+    ``w_e = scale * s_e / sum over the chosen of s``; the experts
     work in the latent width between two shared projections: ``out = (sum
     over chosen e of w_e relu(l W1_e)^2 W2_e) W_up + relu(u Ws1)^2 Ws2``
     with ``l = u W_dn``. Holds experts ``first_expert ... + experts_held``
@@ -374,6 +382,7 @@ class LatentMoE(nn.Layer):
                         self.shared_down.weight)
 
     def _route_and_mix(self, u, w_r, bias, w_dn, w_up, w1, w2, ws1, ws2):
+        from ..ops.pallas.topk_mask import topk_mask
         b, s, hidden = u.shape
         t, held, f32 = b * s, self.held, jnp.float32
         ut = u.reshape(t, hidden)
@@ -381,11 +390,9 @@ class LatentMoE(nn.Layer):
 
         scores = jax.nn.sigmoid(jnp.dot(ut, w_r.astype(ut.dtype),
                                         preferred_element_type=f32))
-        _, chosen = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(bias.astype(f32)), self.top_k)
-        # [t, experts]: which experts each token chose. Compared, not
-        # gathered: everything the weights need is then dense over experts
-        picked = jnp.any(chosen[..., None] == jnp.arange(self.experts), axis=1)
+        # [t, experts]: which experts each token chose, found by threshold.
+        # A mask, not indices: everything the weights need is dense
+        picked = topk_mask(scores + bias.astype(f32), self.top_k)
         total = jnp.sum(jnp.where(picked, scores, 0.0), -1, keepdims=True)
         # [t, held]: the weight of each held expert for each token, nought
         # where the token did not choose it

@@ -78,7 +78,7 @@ def chip(one_chip, no_persistent_cache, monkeypatch):
     ``jax.default_backend()`` (the CPU here) and would take their jnp
     twins, so the test steers their ``_interpret`` to the chip branch."""
     for name in ("paged_attention", "decode_attention", "causal_flash",
-                 "flash_attention", "ssd_scan"):
+                 "flash_attention", "ssd_scan", "topk_mask"):
         monkeypatch.setattr(_mod(name), "_interpret", lambda: False)
 
     def compiles(fn, *shapes):
@@ -224,6 +224,19 @@ def test_ssd_scan_pair(chip, monkeypatch, batch, seq, heads, groups, chunk,
          ((heads,), f32), ((batch, seq, groups, n), dtype),
          ((batch, seq, groups, n), dtype), ((heads,), f32),
          ((batch, seq, heads, p), f32))
+
+
+@pytest.mark.parametrize("tokens,experts,k", [
+    (16384, 512, 22), (1024, 1024, 32), (2048, 64, 1)],
+    ids=["nemotron-share", "widest", "k-1"])
+def test_topk_mask(chip, monkeypatch, tokens, experts, k):
+    """LatentMoE's selection in ``nemotron3s-pretrain-s4096`` (16 384 tokens
+    a step over a 512-wide router, 22 chosen); the widest block and longest
+    sorted lists ``topk_mask.supported`` lets through; a list of one."""
+    tm = _mod("topk_mask")
+    monkeypatch.setattr(tm, "enabled", tm.supported)
+    assert tm.supported(tokens, experts, k)
+    chip(lambda v: tm.topk_mask(v, k), ((tokens, experts), jnp.float32))
 
 
 @pytest.mark.parametrize("batch,seq", [(1, 2048), (8, 1024), (8, 128)])

@@ -255,7 +255,15 @@ def _ssd_scan():
              _sds((1, 2, 128, 1, 2, 64)), _sds((1, 2), f32)))
 
 
+def _topk_mask():
+    from paddle_tpu.ops.pallas import topk_mask
+
+    return (lambda v: topk_mask._threshold_traced(True, 22, v),
+            (_sds((1024, 64), jnp.float32),))
+
+
 @pytest.mark.parametrize("recipe,expected", [
+    (_topk_mask, ["topk_mask"]),
     (_ssd_scan, ["ssd_scan_bwd", "ssd_scan_fwd"]),
     (lambda: _causal(256), ["causal_flash_bwd", "causal_flash_fwd"]),
     (lambda: _causal(1024), ["causal_flash_bwd", "causal_flash_fwd_row"]),
@@ -273,7 +281,7 @@ def _ssd_scan():
     (lambda: _paged_slab(3), ["paged_attention_verify"]),
     (_grouped, ["grouped_matmul"]),
     (_quant, ["quant_matmul"]),
-], ids=["ssd_scan", "causal_flash-s256", "causal_flash-s1024", "causal_flash-s2048",
+], ids=["topk_mask", "ssd_scan", "causal_flash-s256", "causal_flash-s1024", "causal_flash-s2048",
         "causal_flash-fwd_tiled", "flash_attention-s256",
         "flash_attention-s2048", "decode_attention",
         "decode_attention_slab", "paged_attention", "paged_attention_slab",
@@ -292,7 +300,7 @@ def test_no_pallas_call_site_is_without_a_name():
         src = open(path).read()
         sites += len(re.findall(r"pl\.pallas_call\(", src))
         named += len(re.findall(r"^\s+name=", src, re.M))
-    assert sites == named == 17
+    assert sites == named == 18
 
 
 # -------------------------------------------------------------- host side

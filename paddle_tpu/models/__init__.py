@@ -18,3 +18,4 @@ from .llama import (  # noqa: F401
     tiny_llama_config,
 )
 from .nemotron_h import NemotronHConfig, NemotronHForCausalLM  # noqa: F401
+from .laguna import LagunaConfig, LagunaForCausalLM  # noqa: F401

@@ -31,9 +31,10 @@ and exchanges nothing: nothing here stands in for absent chips.
   no index sorted: ``ops/pallas/topk_mask.py``, a kernel on the chip and
   ``jax.numpy`` elsewhere), computes the experts held here in the latent
   width through ``jax.lax.ragged_dot`` over a static buffer of (token,
-  expert) pairs sorted by expert, and drops nothing: the buffer is sized
-  from a stated bound and the pairs over it are counted
-  (``moe_stats_tap``), never hidden.
+  expert) pairs sorted by expert (``models/routed_experts.py``, shared with
+  ``models/laguna.py``), and drops nothing: the buffer is sized from a
+  stated bound and the pairs over it are counted (``moe_stats_tap``), never
+  hidden.
 """
 from __future__ import annotations
 
@@ -46,7 +47,7 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..framework.tensor import Tensor, apply_op
-from . import moe_stats
+from . import routed_experts
 from .moe_stats import moe_stats_tap  # noqa: F401  the repo's one routing tap
 
 __all__ = ["NemotronHConfig", "NemotronHForCausalLM", "NemotronHModel",
@@ -370,9 +371,8 @@ class LatentMoE(nn.Layer):
     def buffer_rows(self, tokens: int) -> int:
         """Rows of the local pairs' buffer: ``local_pairs_bound`` times the
         pairs uniform routing sends here, at most one a token and expert."""
-        fair = tokens * self.top_k * self.held / self.experts
-        return min(tokens * min(self.held, self.top_k),
-                   -(-int(math.ceil(self.bound * fair)) // 8) * 8)
+        return routed_experts.buffer_rows(tokens, self.top_k, self.held,
+                                          self.experts, self.bound)
 
     def forward(self, u):
         return apply_op(self._route_and_mix, u, self.router.weight,
@@ -384,7 +384,7 @@ class LatentMoE(nn.Layer):
     def _route_and_mix(self, u, w_r, bias, w_dn, w_up, w1, w2, ws1, ws2):
         from ..ops.pallas.topk_mask import topk_mask
         b, s, hidden = u.shape
-        t, held, f32 = b * s, self.held, jnp.float32
+        t, f32 = b * s, jnp.float32
         ut = u.reshape(t, hidden)
         relu2 = lambda a: jnp.square(jax.nn.relu(a))
 
@@ -393,41 +393,16 @@ class LatentMoE(nn.Layer):
         # [t, experts]: which experts each token chose, found by threshold.
         # A mask, not indices: everything the weights need is dense
         picked = topk_mask(scores + bias.astype(f32), self.top_k)
-        total = jnp.sum(jnp.where(picked, scores, 0.0), -1, keepdims=True)
-        # [t, held]: the weight of each held expert for each token, nought
-        # where the token did not choose it
-        here = slice(self.first, self.first + held)
-        routed = picked[:, here]
-        w_local = jnp.where(routed, self.scale * scores[:, here] / total, 0.0)
-
-        # the pairs held here, sorted by expert then token, in a buffer of
-        # a static size; ``sizes`` are the rows of each expert that fit
-        rows = self.buffer_rows(t)
-        counts = jnp.sum(routed, axis=0, dtype=jnp.int32)
-        ends = jnp.minimum(jnp.cumsum(counts), rows)
-        sizes = jnp.diff(ends, prepend=0)
-        flat, = jnp.nonzero(routed.T.reshape(-1), size=rows, fill_value=0)
-        expert, token = flat // t, flat % t
-        live = jnp.arange(rows) < ends[-1]
+        routed, w_local = routed_experts.held_weights(
+            scores, picked, self.scale, self.first, self.held)
+        # the held experts over the sorted buffer of local pairs, in the
+        # latent width (``models/routed_experts.py``)
+        pairs = routed_experts.sort_pairs(routed, self.buffer_rows(t))
         latent = _mm(ut, w_dn)
-        # what ragged_dot leaves in the rows past its groups is not ours
-        only_live = lambda a: jnp.where(live[:, None], a, 0)
-        x = only_live(latent[token])
-        hid = only_live(relu2(jax.lax.ragged_dot(x, w1.astype(x.dtype), sizes,
-                                            preferred_element_type=f32)))
-        y = jax.lax.ragged_dot(hid.astype(x.dtype), w2.astype(x.dtype), sizes,
-                               preferred_element_type=f32)
-        y = only_live(y * w_local[token, expert][:, None])
-        mixed = jnp.zeros((t, latent.shape[1]), f32).at[token].add(y)
-
+        mixed = routed_experts.mix(pairs, latent, routed, w_local, (w1,),
+                                   relu2, w2)
         out = _mm(mixed.astype(ut.dtype), w_up) + _mm(
             relu2(_mm(ut, ws1)), ws2)
-        tap = moe_stats.armed()
-        if tap is not None:
-            total = jnp.sum(counts)
-            tap.append(jnp.stack([
-                total, jnp.sum(~jnp.any(routed, axis=1)),
-                total - ends[-1]]).astype(f32))
         return out.reshape(b, s, hidden)
 
 

@@ -29,7 +29,9 @@ two ways that dominate its speedup at train shapes:
    the QKV projection's backward consumes.
 
 Three regimes by sequence length (VERDICT r3 #2 lifted the old S<=1024
-cap; r5 added the whole-row middle regime):
+cap; r5 added the whole-row middle regime; PR 32's cell is the first to
+run the third, at S = 8192 with 12 heads of 128: ``_fwd_tiled`` 3.28 and
+``_bwd_tiled`` 6.99 ms a call, 61.2% of the causal roofline):
 
 * **S <= 1024 — whole-sequence programs.** One program per (batch, head
   block), no grid over the sequence. The forward at S = 1024 is the
@@ -64,9 +66,36 @@ cap; r5 added the whole-row middle regime):
   both passes: the row unroll's O(nq^2/2) code size is a compile-time
   hazard past nq=8, and K/V whole-seq residency outgrows VMEM.
 
+A fourth regime beside them, by MASK and not by length:
+
+* **Band (``window=512``) — the pairs the window touches, and no others.**
+  Query i sees keys j with ``0 <= i - j < window``. The scalar-prefetched
+  pair grid again, forward (``window_flash_fwd``) and backward
+  (``window_flash_bwd``, its math ``_pair_grads``), over the tables of
+  ``_band_tables``: with tiles of 512 rows q tile a meets k tiles a - 1 and
+  a, ``2 S/512 - 1`` pairs (31 at S = 8192 where the causal triangle has
+  136), the diagonal one masked causally and the trailing one at the
+  window's edge (key c of it is seen by query r where c > r), so about half
+  of what executes is masked. dK/dV of tile b are met by rows b and b + 1
+  only and roll through two scratch slots instead of the tiled regime's
+  whole-sequence scratch. Measured on one v5e chip, 2 x 18 heads x 8192 x
+  128 bf16, ms a call (PR 32; alone, host-timed over 30 calls): tiles of
+  512: forward 2.474, backward 3.448; tiles of 256 (three tiles a q tile,
+  0.75 of the area, twice the grid steps): 4.132 / 4.396; the causal
+  kernels on the same heads 4.938 / 11.475. In the training step (device
+  trace): ``window_flash_fwd`` 2.45 and ``window_flash_bwd`` 2.58 ms a
+  call, 22.7% of the band's own roofline (the cost function counts the
+  band, not the tiles): the forward spends as long on its 31 half-masked
+  tiles as the backward, since every q tile pays the accumulators' set-up
+  and write-out for two tiles' work; one program a q tile holding both k
+  tiles (the whole-row kernel's form) has not been tried (ROADMAP A12).
+
 Constraints: D in {64, 128, 256}, causal only, no dropout inside the
 kernel (the model applies dropout outside); S % 8 == 0 up to 1024,
-S % 512 == 0 for the tiled regime.
+S % 512 == 0 for the tiled regime. The band regime: window 512, D = 128, S
+a multiple of 512 from 1024 to 8192, bf16 or float32; elsewhere (and off
+the chip) callers keep their ``jax.numpy`` masked softmax. With
+``window=None`` every path above traces as it did before the band existed.
 """
 from __future__ import annotations
 
@@ -437,14 +466,16 @@ def _scaled_delta(do, o, scale):
     return delta * scale if _exact_in_bf16(scale) else delta
 
 
-def _pair_grads(q, do, k, v, lse, delta, *, scale, masked):
+def _pair_grads(q, do, k, v, lse, delta, *, scale, masked, edge=False):
     """One live pair of the causal backward: the rows of q-tile a ([bq, D]
     q/do, [bq, 1] lse/delta) against a span of k rows at or left of the
     diagonal ([bk, D] k/v). p and dp = do_a . v^T are formed ONCE and feed
     all three products (a two-pass scheme re-forms them per side). Returns
     the pair's f32 contributions to (dQ_a [bq, D], dK [bk, D], dV [bk, D]).
     Only the diagonal square (``masked``, bq == bk at the same offset)
-    straddles the causal boundary and pays the iota mask. Dots run in the
+    straddles the causal boundary and pays the iota mask; the band regime's
+    trailing square (``edge``, the k tile a window behind the q tile) keeps
+    what lies INSIDE the window, the strict upper triangle. Dots run in the
     input dtype with f32 accumulation."""
     fold = _exact_in_bf16(scale)
     if fold:
@@ -465,6 +496,10 @@ def _pair_grads(q, do, k, v, lse, delta, *, scale, masked):
         q_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         k_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         p = jnp.where(q_ids >= k_ids, p, jnp.zeros((), p.dtype))
+    if edge:
+        q_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        p = jnp.where(k_ids > q_ids, p, jnp.zeros((), p.dtype))
     dp = jax.lax.dot_general(do_in, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     ds = p * (dp - delta)
@@ -606,6 +641,267 @@ def _bwd_tiled(num_heads, head_dim, scale, res, do):
     # this concat into the consuming GEMMs (dot-of-concat => sum of dots).
     return jnp.concatenate(
         [dq4, dkv5[:, 0], dkv5[:, 1]], axis=1)
+
+
+# ------------------------------------------------------------- band regime
+#
+# Sliding-window attention: query i sees keys j with 0 <= i - j < window.
+# With tiles of ``blk`` rows (window a multiple of blk, nb = window / blk)
+# q tile a meets k tiles a - nb ... a: the diagonal one masked causally, the
+# trailing one (a - nb, where it exists) masked at the window's edge, those
+# between whole. The grid's last axis enumerates only these pairs through
+# scalar-prefetched tables, as the triangle-packed grids above do.
+
+# tile rows of the band regime: 512 beat 256 on the chip (the numbers in the
+# module docstring)
+_WIN_BLK = 512
+
+
+def _band_tables(nq, nb):
+    """qi/kc lookup tables of the band's pairs, kc fastest and rising, so
+    that a q tile's accumulators stay resident within its row."""
+    import numpy as np
+
+    rows = [(q, np.arange(max(q - nb, 0), q + 1, dtype=np.int32))
+            for q in range(nq)]
+    return (np.concatenate([np.full(len(k), q, np.int32) for q, k in rows]),
+            np.concatenate([k for _, k in rows]))
+
+
+def _window_fwd_kernel(qi_tab, kc_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                       m_s, l_s, acc_s, *, scale, d, hpb, blk, nb):
+    t = pl.program_id(2)
+    qi = qi_tab[t]
+    kc = kc_tab[t]
+    fold = _exact_in_bf16(scale)
+
+    @pl.when(kc == jnp.maximum(qi - nb, 0))
+    def _init():
+        m_s[:] = jnp.full_like(m_s, NEG_INF)
+        l_s[:] = jnp.zeros_like(l_s)
+        acc_s[:] = jnp.zeros_like(acc_s)
+
+    def _tile(mask):
+        for sub in range(hpb):
+            lo = sub * d
+            q = q_ref[0, 0, :, lo:lo + d]  # [blk, D]
+            if fold:
+                q = q * jnp.asarray(scale, q.dtype)
+            s = jax.lax.dot_general(
+                q, k_ref[0, 0, :, lo:lo + d], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [blk, blk]
+            if not fold:
+                s = s * scale
+            if mask is not None:
+                q_ids = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+                k_ids = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+                s = jnp.where(q_ids >= k_ids if mask == "causal"
+                              else k_ids > q_ids, s, NEG_INF)
+            # a row the edge masks whole reads exp(0) = 1 here (NEG_INF is
+            # finite); the diagonal tile, which always follows and holds a
+            # live entry for every row, scales that away by alpha = 0
+            m_prev = m_s[sub, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_s[sub, :, :1] = (alpha * l_s[sub, :, :1]
+                               + jnp.sum(p, axis=-1, keepdims=True))
+            m_s[sub, :, :1] = m_new
+            acc_s[:, lo:lo + d] = acc_s[:, lo:lo + d] * alpha + (
+                jax.lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[0, 0, :, lo:lo + d],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+
+    @pl.when(kc == qi - nb)
+    def _edge():
+        _tile("edge")
+
+    @pl.when((kc > qi - nb) & (kc < qi))
+    def _interior():
+        _tile(None)
+
+    @pl.when(kc == qi)  # the row's last pair: the diagonal, then finalize
+    def _diag():
+        _tile("causal")
+        for sub in range(hpb):
+            lo = sub * d
+            l = l_s[sub, :, :1]
+            o_ref[0, 0, :, lo:lo + d] = (acc_s[:, lo:lo + d] / l).astype(
+                o_ref.dtype)
+            lse_ref[0, 0, :, sub:sub + 1] = m_s[sub, :, :1] + jnp.log(l)
+
+
+def _window_fwd(qkv, num_heads, head_dim, scale, window):
+    b, groups, seq, lanes = qkv.shape
+    hpb = lanes // head_dim
+    gh = num_heads // hpb
+    blk = _WIN_BLK
+    qi_tab, kc_tab = _band_tables(seq // blk, window // blk)
+    at_q = lambda bi, hi, t, qt, kt: (bi, hi, qt[t], 0)
+    out, lse = pl.pallas_call(
+        functools.partial(_window_fwd_kernel, scale=scale, d=head_dim,
+                          hpb=hpb, blk=blk, nb=window // blk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, gh, len(qi_tab)),
+            in_specs=[
+                pl.BlockSpec((1, 1, blk, lanes), at_q),
+                pl.BlockSpec((1, 1, blk, lanes),
+                             lambda bi, hi, t, qt, kt, gh=gh:
+                             (bi, hi + gh, kt[t], 0)),
+                pl.BlockSpec((1, 1, blk, lanes),
+                             lambda bi, hi, t, qt, kt, gh=gh:
+                             (bi, hi + 2 * gh, kt[t], 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, blk, lanes), at_q),
+                pl.BlockSpec((1, 1, blk, hpb), at_q),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((hpb, blk, 128), jnp.float32),
+                pltpu.VMEM((hpb, blk, 128), jnp.float32),
+                pltpu.VMEM((blk, lanes), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, gh, seq, lanes), qkv.dtype),
+            jax.ShapeDtypeStruct((b, gh, seq, hpb), jnp.float32),
+        ],
+        interpret=_interpret(),
+        name="window_flash_fwd",
+    )(jnp.asarray(qi_tab), jnp.asarray(kc_tab), qkv, qkv, qkv)
+    return out, lse
+
+
+def _window_bwd_kernel(a_tab, b_tab, qa_ref, doa_ref, oa_ref, lsea_ref,
+                       kb_ref, vb_ref, dq_ref, dkv_ref, dq_s, dk_s, dv_s,
+                       delta_s, *, scale, seq, d, hpb, blk, nb):
+    # one step per pair of the band (q tile a, k tile b, a - nb <= b <= a;
+    # b fastest and rising), its math in _pair_grads. dQ_a lives in row
+    # scratch (zeroed at the row's first pair, flushed at b == a). k tile b
+    # is met by rows b ... b + nb, so dK_b/dV_b accumulate in slot b mod
+    # (nb + 1) of a rolling scratch (zeroed on first touch a == b) and are
+    # written out on the last touch, a == min(b + nb, last row): that write
+    # lands after any flush of the block's unwritten buffer by the rows
+    # between. delta_a is cached per row.
+    t = pl.program_id(2)
+    a = a_tab[t]
+    b = b_tab[t]
+    slot = b % (nb + 1)
+    last = seq // blk - 1
+
+    @pl.when(b == jnp.maximum(a - nb, 0))
+    def _row_start():
+        dq_s[:] = jnp.zeros_like(dq_s)
+        for sub in range(hpb):
+            lo = sub * d
+            delta_s[sub, :, :1] = _scaled_delta(
+                doa_ref[0, 0, :, lo:lo + d], oa_ref[0, 0, :, lo:lo + d],
+                scale)
+
+    @pl.when(a == b)
+    def _first_touch_b():
+        dk_s[pl.ds(slot, 1)] = jnp.zeros((1,) + dk_s.shape[1:], dk_s.dtype)
+        dv_s[pl.ds(slot, 1)] = jnp.zeros((1,) + dv_s.shape[1:], dv_s.dtype)
+
+    def _pair(masked, edge):
+        for sub in range(hpb):
+            lo = sub * d
+            dq, dk, dv = _pair_grads(
+                qa_ref[0, 0, :, lo:lo + d], doa_ref[0, 0, :, lo:lo + d],
+                kb_ref[0, 0, :, lo:lo + d], vb_ref[0, 0, :, lo:lo + d],
+                lsea_ref[0, 0, :, sub:sub + 1], delta_s[sub, :, :1],
+                scale=scale, masked=masked, edge=edge)
+            dq_s[:, lo:lo + d] = dq_s[:, lo:lo + d] + dq
+            dv_s[slot, :, lo:lo + d] = dv_s[slot, :, lo:lo + d] + dv
+            dk_s[slot, :, lo:lo + d] = dk_s[slot, :, lo:lo + d] + dk
+
+    @pl.when(b == a - nb)
+    def _edge_pair():
+        _pair(masked=False, edge=True)
+
+    @pl.when((b > a - nb) & (b < a))
+    def _interior_pair():
+        _pair(masked=False, edge=False)
+
+    @pl.when(a == b)  # diag = end of row a: dQ_a complete
+    def _diag_pair():
+        _pair(masked=True, edge=False)
+        dq_ref[0, 0] = dq_s[:].astype(dq_ref.dtype)
+
+    @pl.when(a == jnp.minimum(b + nb, last))  # the last row that meets b
+    def _write_dkv():
+        dkv_ref[0, 0, 0] = dk_s[slot].astype(dkv_ref.dtype)
+        dkv_ref[0, 1, 0] = dv_s[slot].astype(dkv_ref.dtype)
+
+
+def _window_bwd(num_heads, head_dim, scale, window, res, do):
+    qkv, out, lse = res
+    b, groups, seq, lanes = qkv.shape
+    hpb = lanes // head_dim
+    gh = num_heads // hpb
+    blk = _WIN_BLK
+    nb = window // blk
+    a_tab, b_tab = _band_tables(seq // blk, nb)
+
+    def at_a(width=lanes):
+        return pl.BlockSpec((1, 1, blk, width),
+                            lambda bi, hi, t, at, bt: (bi, hi, at[t], 0))
+
+    def at_b(group):
+        return pl.BlockSpec(
+            (1, 1, blk, lanes),
+            lambda bi, hi, t, at, bt, g=group, gh=gh:
+            (bi, hi + g * gh, bt[t], 0))
+
+    dq4, dkv5 = pl.pallas_call(
+        functools.partial(_window_bwd_kernel, scale=scale, seq=seq,
+                          d=head_dim, hpb=hpb, blk=blk, nb=nb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, gh, len(a_tab)),
+            # q, do, o, lse at a; k, v at b
+            in_specs=[at_a(), at_a(), at_a(), at_a(hpb), at_b(1), at_b(2)],
+            out_specs=[
+                at_a(),
+                pl.BlockSpec((1, 2, 1, blk, lanes),
+                             lambda bi, hi, t, at, bt: (bi, 0, hi, bt[t], 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((blk, lanes), jnp.float32),
+                pltpu.VMEM((nb + 1, blk, lanes), jnp.float32),
+                pltpu.VMEM((nb + 1, blk, lanes), jnp.float32),
+                pltpu.VMEM((hpb, blk, 128), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, gh, seq, lanes), qkv.dtype),
+            jax.ShapeDtypeStruct((b, 2, gh, seq, lanes), qkv.dtype),
+        ],
+        interpret=_interpret(),
+        name="window_flash_bwd",
+    )(jnp.asarray(a_tab), jnp.asarray(b_tab), qkv, do, out, lse, qkv, qkv)
+    return jnp.concatenate([dq4, dkv5[:, 0], dkv5[:, 1]], axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _banded(qkv, num_heads, head_dim, scale, window):
+    out, _ = _window_fwd(qkv, num_heads, head_dim, scale, window)
+    return out
+
+
+def _banded_fwd_rule(qkv, num_heads, head_dim, scale, window):
+    out, lse = _window_fwd(qkv, num_heads, head_dim, scale, window)
+    return out, (qkv, out, lse)
+
+
+def _banded_bwd_rule(num_heads, head_dim, scale, window, res, do):
+    return (_window_bwd(num_heads, head_dim, scale, window, res,
+                        do.astype(res[0].dtype)),)
+
+
+_banded.defvjp(_banded_fwd_rule, _banded_bwd_rule)
 
 
 # ---------------------------------------------------------------------- bwd
@@ -761,7 +1057,11 @@ def heads_per_block(num_heads: int, head_dim: int) -> int:
     return 2 if (head_dim == 64 and num_heads % 2 == 0) else 1
 
 
-def supported(seq: int, head_dim: int) -> bool:
+def supported(seq: int, head_dim: int, window=None) -> bool:
+    if window is not None:
+        # the band regime: one window, one head width, whole tiles
+        return (window == 512 and head_dim == 128 and seq % _WIN_BLK == 0
+                and 2 * window <= seq <= _MAX_SEQ_TILED)
     if head_dim not in (64, 128, 256):
         return False
     if seq <= _MAX_SEQ:
@@ -774,25 +1074,27 @@ def supported(seq: int, head_dim: int) -> bool:
     return seq % _BLK == 0 and seq <= limit
 
 
-def enabled(seq: int, head_dim: int) -> bool:
+def enabled(seq: int, head_dim: int, window=None) -> bool:
     """Whether a train path should take this kernel: where
     ``FLAGS_use_packed_attention`` says so (unset: on the TPU only) and the
-    shape is supported."""
+    shape (with the window, on a sliding layer) is supported."""
     from ...framework.flags import get_flags
 
     flag = get_flags("FLAGS_use_packed_attention")[
         "FLAGS_use_packed_attention"]
     if flag is None:
         flag = jax.default_backend() == "tpu"
-    return bool(flag) and supported(seq, head_dim)
+    return bool(flag) and supported(seq, head_dim, window)
 
 
-def causal_flash_qkv(qkv, num_heads, head_dim=None):
+def causal_flash_qkv(qkv, num_heads, head_dim=None, window=None):
     """Causal self-attention on a packed QKV tensor.
 
     qkv: ``[B, 3H/hpb, S, hpb*D]`` — q head blocks, then k, then v, where
     ``hpb = heads_per_block(H, D)`` (exactly the reshaped-weight einsum of
-    the fused projection). Returns ``[B, H/hpb, S, hpb*D]``.
+    the fused projection). Returns ``[B, H/hpb, S, hpb*D]``. With
+    ``window`` query i sees keys j with ``0 <= i - j < window`` only (the
+    band regime; ``supported`` says at which shapes).
     """
     b, groups, seq, lanes = qkv.shape
     if head_dim is None:
@@ -803,10 +1105,17 @@ def causal_flash_qkv(qkv, num_heads, head_dim=None):
         raise ValueError(
             f"causal_flash_qkv: qkv shape {qkv.shape} inconsistent with "
             f"num_heads={num_heads}, head_dim={head_dim}")
+    scale = 1.0 / (head_dim ** 0.5)
+    if window is not None:
+        if not supported(seq, head_dim, window):
+            raise ValueError(
+                f"causal_flash_qkv: window {window} at shape {qkv.shape}: "
+                f"the band regime takes window 512, D = 128 and S a "
+                f"multiple of {_WIN_BLK} from 1024 to {_MAX_SEQ_TILED}")
+        return _banded(qkv, num_heads, head_dim, float(scale), window)
     if not supported(seq, head_dim):
         raise ValueError(
             f"causal_flash_qkv: unsupported shape {qkv.shape}; need "
             f"D in (64,128,256) and S % 8 == 0 (S <= {_MAX_SEQ}) or "
             f"S % {_BLK} == 0 (S <= {_MAX_SEQ_TILED})")
-    scale = 1.0 / (head_dim ** 0.5)
     return _packed(qkv, num_heads, head_dim, float(scale))

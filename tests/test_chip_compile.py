@@ -193,6 +193,26 @@ def test_causal_flash_qkv_nemotron_share(chip, grad):
     chip(fn, ((4, 12, 4096, 128), BF16))
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("heads,window,dtype", [
+    (12, None, BF16), (18, 512, BF16), (2, 512, jnp.float32)],
+    ids=["full-12-heads", "window-18-heads", "window-float32"])
+def test_causal_flash_qkv_laguna_share(chip, heads, window, dtype, grad):
+    """The attention of ``laguna-s-pretrain-s8192`` at batch 2, S=8192, D=128:
+    12 query heads through the tiled per-pair grids (``_fwd_tiled`` /
+    ``_bwd_tiled``, the regime's first cell), 18 through the band regime at
+    window 512 (``window_flash_fwd`` / ``window_flash_bwd``), and the band
+    regime in float32."""
+    cf = _mod("causal_flash")
+
+    def fwd(qkv):
+        return cf.causal_flash_qkv(qkv, heads, 128, window=window)
+
+    fn = fwd if not grad else jax.grad(
+        lambda qkv: fwd(qkv).astype(jnp.float32).sum())
+    chip(fn, ((2, 3 * heads, 8192, 128), dtype))
+
+
 @pytest.mark.parametrize("batch,seq,heads,groups,chunk,dtype", [
     (4, 4096, 16, 1, 128, BF16), (1, 4096, 128, 8, 128, BF16),
     (2, 1024, 32, 1, 128, BF16), (2, 1024, 16, 1, 256, BF16),
@@ -237,6 +257,38 @@ def test_topk_mask(chip, monkeypatch, tokens, experts, k):
     monkeypatch.setattr(tm, "enabled", tm.supported)
     assert tm.supported(tokens, experts, k)
     chip(lambda v: tm.topk_mask(v, k), ((tokens, experts), jnp.float32))
+
+
+def test_every_pair_of_a_laguna_layer_in_one_buffers_memory(
+        one_chip, no_persistent_cache):
+    """``routed_experts.mix_every_pair`` at one MoE layer of
+    ``laguna-s-pretrain-s8192`` (16 384 tokens of width 3072, 8 held experts
+    of width 1024, a first buffer of 10 240 rows and twelve more for the
+    131 072 pairs no routing can pass), value and gradients: the later
+    buffers keep nothing for the backward, so the layer takes memory for the
+    first buffer and one buffer's work (the thirteen buffers' rows kept
+    would be 4.4 GiB)."""
+    from paddle_tpu.models import routed_experts as rx
+
+    t, held, width, ff, rows = 16384, 8, 3072, 1024, 10240
+    act = lambda a, g: jax.nn.silu(a) * g
+
+    def loss(x, scores, w1, w3, w2):
+        routed = scores > 0
+        w_local = jnp.where(routed, scores, 0.0)
+        out = rx.mix_every_pair(routed, rows, t * held, x, w_local, (w1, w3),
+                                act, w2)
+        return jnp.sum(out * out)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((t, width), BF16), ((t, held), jnp.float32),
+        ((held, width, ff), BF16), ((held, width, ff), BF16),
+        ((held, ff, width), BF16))]
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+                       ).lower(*args).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and " conditional(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
 
 
 @pytest.mark.parametrize("batch,seq", [(1, 2048), (8, 1024), (8, 128)])

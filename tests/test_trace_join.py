@@ -175,6 +175,14 @@ def _causal(seq):
     return jax.grad(f), (_sds((1, 6, seq, 128)),)
 
 
+def _window(seq):
+    from paddle_tpu.ops.pallas import causal_flash
+
+    f = lambda x: causal_flash.causal_flash_qkv(
+        x, 2, 128, window=512).astype(jnp.float32).sum()
+    return jax.grad(f), (_sds((1, 6, seq, 128)),)
+
+
 def _causal_tiled():
     from paddle_tpu.ops.pallas import causal_flash
 
@@ -270,6 +278,7 @@ def _topk_mask():
     (lambda: _causal(2048),
      ["causal_flash_bwd_tiled", "causal_flash_fwd_row"]),
     (_causal_tiled, ["causal_flash_fwd_tiled"]),
+    (lambda: _window(1024), ["window_flash_bwd", "window_flash_fwd"]),
     (lambda: _flash(256), ["flash_attention_bwd", "flash_attention_fwd"]),
     (lambda: _flash(2048), ["flash_attention_bwd_dkv",
                             "flash_attention_bwd_dq",
@@ -282,7 +291,7 @@ def _topk_mask():
     (_grouped, ["grouped_matmul"]),
     (_quant, ["quant_matmul"]),
 ], ids=["topk_mask", "ssd_scan", "causal_flash-s256", "causal_flash-s1024", "causal_flash-s2048",
-        "causal_flash-fwd_tiled", "flash_attention-s256",
+        "causal_flash-fwd_tiled", "window_flash-s1024", "flash_attention-s256",
         "flash_attention-s2048", "decode_attention",
         "decode_attention_slab", "paged_attention", "paged_attention_slab",
         "paged_attention_verify", "grouped_matmul", "quant_matmul"])
@@ -300,7 +309,7 @@ def test_no_pallas_call_site_is_without_a_name():
         src = open(path).read()
         sites += len(re.findall(r"pl\.pallas_call\(", src))
         named += len(re.findall(r"^\s+name=", src, re.M))
-    assert sites == named == 18
+    assert sites == named == 20
 
 
 # -------------------------------------------------------------- host side

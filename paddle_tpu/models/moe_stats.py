@@ -4,8 +4,8 @@ traced forward threads out as a program output.
 A builder arms the tap around ``model.forward``; each MoE layer traced
 under it appends one float32 vector (what it holds is the layer's own:
 ``LlamaMoEMLP``'s per-expert kept tokens, dropped pairs, router entropy and
-routed tokens; ``LatentMoE``'s pairs routed here, tokens with none, pairs
-over the buffer), and the builder returns the list from the traced
+routed tokens; ``LatentMoE``'s and ``LagunaMoE``'s pairs routed here, tokens
+with none, pairs left out, rows walked), and the builder returns the list from the traced
 function. Unarmed, the layers skip the counters entirely and their traces
 are unchanged.
 """

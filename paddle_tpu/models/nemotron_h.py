@@ -342,7 +342,7 @@ class LatentMoE(nn.Layer):
     over the chosen experts held here, the k and their normalisation stay
     as published. Traced under ``moe_stats_tap`` (``models/moe_stats.py``), each
     layer appends ``[pairs routed to held experts, tokens with none of
-    them, pairs over the buffer]`` (float32) to the tap's list, for the
+    them, pairs over the buffer, rows walked]`` (float32) to the tap's list, for the
     caller to thread out of the traced function as an output. The bias's
     load-balancing update belongs to the training loop (a buffer update
     between steps); nothing here runs it."""

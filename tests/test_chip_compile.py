@@ -259,36 +259,83 @@ def test_topk_mask(chip, monkeypatch, tokens, experts, k):
     chip(lambda v: tm.topk_mask(v, k), ((tokens, experts), jnp.float32))
 
 
-def test_every_pair_of_a_laguna_layer_in_one_buffers_memory(
-        one_chip, no_persistent_cache):
-    """``routed_experts.mix_every_pair`` at one MoE layer of
+def _loop_bodies(text):
+    """{name: lines} of the computations the compiled text's ``while``
+    instructions name as their bodies."""
+    import re
+
+    bodies = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
+    found, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1) if head.group(1) in bodies else None
+        elif name:
+            found.setdefault(name, []).append(line)
+    assert set(found) == bodies
+    return found
+
+
+@pytest.mark.parametrize("layer", ["laguna", "latent"])
+def test_an_expert_layer_walks_its_rows_in_place_in_one_buffers_memory(
+        one_chip, no_persistent_cache, layer):
+    """One expert layer of each share cell, value and gradients.
+
+    ``laguna``: ``routed_experts.mix_every_pair`` at one MoE layer of
     ``laguna-s-pretrain-s8192`` (16 384 tokens of width 3072, 8 held experts
     of width 1024, a first buffer of 10 240 rows and twelve more for the
-    131 072 pairs no routing can pass), value and gradients: the later
-    buffers keep nothing for the backward, so the layer takes memory for the
-    first buffer and one buffer's work (the thirteen buffers' rows kept
-    would be 4.4 GiB)."""
+    131 072 pairs no routing can pass): the later buffers keep nothing for
+    the backward, so the layer takes memory for the first buffer and one
+    buffer's work (the thirteen buffers' rows kept would be 4.4 GiB).
+    ``latent``: ``mix`` at one E layer of ``nemotron3s-pretrain-s4096``
+    (16 384 tokens of latent width 1024, 8 experts of width 2688, one
+    buffer of 16 896 rows: 33 whole chunks; a last chunk that starts early
+    is ``tests/test_routed_experts.py``'s, at two and a half chunks).
+
+    Both move their rows through ``take_rows`` / ``add_rows``: ``while``
+    loops over the chunks that hold a pair, whose bodies update the carried
+    array in place (a ``copy`` of it inside a body would cost the whole
+    array once a chunk)."""
     from paddle_tpu.models import routed_experts as rx
 
-    t, held, width, ff, rows = 16384, 8, 3072, 1024, 10240
-    act = lambda a, g: jax.nn.silu(a) * g
+    t, held = 16384, 8
+    if layer == "laguna":
+        width, ff, rows, limit, loops = 3072, 1024, 10240, 2.5, 4 + 4 + 1
+        act = lambda a, g: jax.nn.silu(a) * g
+        w_in = [((held, width, ff), BF16)] * 2
+        mix = lambda routed, x, w_local, w_in, w_out: rx.mix_every_pair(
+            routed, rows, t * held, x, w_local, w_in, act, w_out)
+    else:
+        width, ff, rows, limit, loops = 1024, 2688, 16896, 0.75, 4
+        act = lambda a: jnp.square(jax.nn.relu(a))
+        w_in = [((held, width, ff), BF16)]
+        mix = lambda routed, x, w_local, w_in, w_out: rx.mix(
+            rx.sort_pairs(routed, rows), x, routed, w_local, w_in, act,
+            w_out)
 
-    def loss(x, scores, w1, w3, w2):
+    def loss(x, scores, w_out, *w_in):
         routed = scores > 0
-        w_local = jnp.where(routed, scores, 0.0)
-        out = rx.mix_every_pair(routed, rows, t * held, x, w_local, (w1, w3),
-                                act, w2)
+        out = mix(routed, x, jnp.where(routed, scores, 0.0), w_in, w_out)
         return jnp.sum(out * out)
 
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
         ((t, width), BF16), ((t, held), jnp.float32),
-        ((held, width, ff), BF16), ((held, width, ff), BF16),
-        ((held, ff, width), BF16))]
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+        ((held, ff, width), BF16), *w_in)]
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=range(len(args)))
                        ).lower(*args).compile()
     text = compiled.as_text()
-    assert "ragged-dot" in text and " conditional(" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
+    assert "ragged-dot" in text
+    assert (" conditional(" in text) == (layer == "laguna")
+    # the compiler's figure read when this was written: 2.08 and 0.49 GiB
+    assert compiled.memory_analysis().temp_size_in_bytes < limit * 2**30
+    bodies = _loop_bodies(text)
+    assert len(bodies) >= loops
+    walks = {name: lines for name, lines in bodies.items()
+             if any(f"[{n},{width}]" in line for line in lines
+                    for n in (t, rows))}
+    assert len(walks) >= 4
+    for name, lines in walks.items():
+        assert not any(" copy(" in line for line in lines), name
 
 
 @pytest.mark.parametrize("batch,seq", [(1, 2048), (8, 1024), (8, 128)])

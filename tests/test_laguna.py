@@ -310,8 +310,9 @@ def test_moe_shares_add_up_to_the_uncut_layer():
 @pytest.mark.parametrize("bound", [4.0, 0.5], ids=["fits", "overflows"])
 def test_routing_counters_and_a_buffer_too_small(bound):
     """Under ``moe_stats_tap`` a layer reports pairs routed here, tokens with
-    none, pairs left out; a buffer under the load leaves none out either
-    (further buffers take them) and the layer's output is the reference's."""
+    none, pairs left out, rows walked; a buffer under the load leaves none
+    out either (further buffers take them, and their rows are walked too)
+    and the layer's output is the reference's."""
     from paddle_tpu.models.moe_stats import moe_stats_tap
 
     cfg = share([(FULL, "sparse")])
@@ -325,10 +326,13 @@ def test_routing_counters_and_a_buffer_too_small(bound):
     layer = lg.LagunaMoE(builder.model_config(cfg))
     with moe_stats_tap() as tap:
         out = program_mixer_out(layer, params, "moe", u)
-    (pairs, none, left_out), = np.asarray(tap)
+    (pairs, none, left_out, walked), = np.asarray(tap)
     assert pairs == int(jnp.sum(here))
     assert none == int(jnp.sum(~jnp.any(here, -1)))
-    assert (pairs > layer.buffer_rows(48)) == (bound < 1) and left_out == 0
+    rows = layer.buffer_rows(48)
+    assert (pairs > rows) == (bound < 1) and left_out == 0
+    # these buffers are under a chunk: each one run is walked whole
+    assert pairs <= walked == rows * -(-pairs // rows)
     close(out, mixer_out(ref.moe_ffn, cfg, params, "moe", u), F32, "moe")
 
 

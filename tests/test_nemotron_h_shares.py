@@ -212,9 +212,11 @@ def routing_counts(cfg, seq=40):
 def test_moe_counters_no_pair_over_the_buffer():
     cfg = share("EME")
     stats, logits, want = routing_counts(cfg)
-    assert stats.shape == (2, 3)                 # one row an E layer
+    assert stats.shape == (2, 4)                 # one row an E layer
     tokens, k = 80, cfg["num_experts_per_tok"]
     assert np.all(stats[:, 2] == 0)              # nothing over the buffer
+    rows = nh.LatentMoE(builder.model_config(cfg)).buffer_rows(tokens)
+    assert np.all(stats[:, 0] <= stats[:, 3]) and np.all(stats[:, 3] <= rows)
     assert np.all(stats[:, 0] + stats[:, 1] >= tokens)
     assert np.all(stats[:, 0] <= tokens * min(k, 4))
     assert 0.5 < stats[:, 0].mean() / (tokens * k * 4 / 16) < 2.0
@@ -228,6 +230,8 @@ def test_a_pair_over_the_buffer_is_counted_and_caught():
     cfg["held"]["local_pairs_bound"] = 0.25
     stats, logits, want = routing_counts(cfg)
     assert np.all(stats[:, 2] > 0)
+    # the one buffer is full, and walked whole
+    assert np.all(stats[:, 3] == stats[:, 0] - stats[:, 2])
     with pytest.raises(AssertionError, match="gap"):
         close(logits, want, F32, "logits")
 

@@ -19,3 +19,4 @@ from .llama import (  # noqa: F401
 )
 from .nemotron_h import NemotronHConfig, NemotronHForCausalLM  # noqa: F401
 from .laguna import LagunaConfig, LagunaForCausalLM  # noqa: F401
+from .phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM  # noqa: F401

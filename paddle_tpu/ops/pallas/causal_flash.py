@@ -96,6 +96,19 @@ S % 512 == 0 for the tiled regime. The band regime: window 512, D = 128, S
 a multiple of 512 from 1024 to 8192, bf16 or float32; elsewhere (and off
 the chip) callers keep their ``jax.numpy`` masked softmax. With
 ``window=None`` every path above traces as it did before the band existed.
+
+**Narrower q and k under wider v: the padded call.** ``head_dim`` is the
+width of q, k AND v, and the softmax scale is ``1 / sqrt(head_dim)``. A
+caller whose scores are d wide and whose values are wider (differential
+attention: heads of 64 under value pairs of 128, ``models/phi4flash.py::
+softmax_heads``) calls at ``head_dim`` = the values' width with q and k
+zero-padded to it and q multiplied by ``sqrt(head_dim / d)``, so that the
+kernel's scale comes out as the caller's ``1 / sqrt(d)``: the padded lanes
+add nought to every score and take nought of the gradient. That is how D =
+64 runs under a window today (the band regime takes D = 128 only, and not by
+accident: its tiles are sized for 128 lanes); on the v5e a 64-deep
+contraction half-fills the 128 x 128 array either way, so the padding costs
+loads, not passes. A regime with q, k narrower than v is not written.
 """
 from __future__ import annotations
 
@@ -1058,6 +1071,10 @@ def heads_per_block(num_heads: int, head_dim: int) -> int:
 
 
 def supported(seq: int, head_dim: int, window=None) -> bool:
+    """Whether the kernels take heads of ``head_dim`` (the width of q, k and
+    v alike) at length ``seq``; with ``window``, whether the band regime
+    does: window 512 at D = 128 only. Narrower heads under a window, or q
+    and k narrower than v, go through the padded call (module docstring)."""
     if window is not None:
         # the band regime: one window, one head width, whole tiles
         return (window == 512 and head_dim == 128 and seq % _WIN_BLK == 0
@@ -1094,7 +1111,11 @@ def causal_flash_qkv(qkv, num_heads, head_dim=None, window=None):
     ``hpb = heads_per_block(H, D)`` (exactly the reshaped-weight einsum of
     the fused projection). Returns ``[B, H/hpb, S, hpb*D]``. With
     ``window`` query i sees keys j with ``0 <= i - j < window`` only (the
-    band regime; ``supported`` says at which shapes).
+    band regime; ``supported`` says at which shapes). q, k and v are all
+    ``head_dim`` wide and the scores are scaled by ``1 / sqrt(head_dim)``;
+    scores narrower than the values: zero-pad q and k to the values' width
+    and fold the caller's scale into q (module docstring, "the padded
+    call"; tested in ``tests/test_phi4flash.py``).
     """
     b, groups, seq, lanes = qkv.shape
     if head_dim is None:

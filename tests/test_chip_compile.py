@@ -78,7 +78,8 @@ def chip(one_chip, no_persistent_cache, monkeypatch):
     ``jax.default_backend()`` (the CPU here) and would take their jnp
     twins, so the test steers their ``_interpret`` to the chip branch."""
     for name in ("paged_attention", "decode_attention", "causal_flash",
-                 "flash_attention", "ssd_scan", "topk_mask"):
+                 "flash_attention", "ssd_scan", "topk_mask",
+                 "selective_scan"):
         monkeypatch.setattr(_mod(name), "_interpret", lambda: False)
 
     def compiles(fn, *shapes):
@@ -244,6 +245,53 @@ def test_ssd_scan_pair(chip, monkeypatch, batch, seq, heads, groups, chunk,
          ((heads,), f32), ((batch, seq, groups, n), dtype),
          ((batch, seq, groups, n), dtype), ((heads,), f32),
          ((batch, seq, heads, p), f32))
+
+
+@pytest.mark.parametrize("batch,seq,channels,states,dtype", [
+    (1, 8192, 2560, 16, BF16), (1, 4096, 5120, 16, BF16),
+    (2, 1000, 1280, 8, BF16), (2, 1024, 2560, 16, jnp.float32)],
+    ids=["phi4flash-share", "uncut-5120-channels", "ragged-10-rows-8-states",
+         "float32"])
+def test_selective_scan_pair(chip, monkeypatch, batch, seq, channels, states,
+                             dtype):
+    """The Mamba-1 scan of ``phi4flash-pretrain-s8192`` (2560 channels of 16
+    states, one row of 8192: groups of 8 + 8 + 4 rows of 128 channels),
+    forward and backward; the uncut layer's 5120 channels, whose blocks and
+    kept states must still fit the raised scoped limit; a length that is no
+    whole number of chunks over a half-filled last group; float32."""
+    ss = _mod("selective_scan")
+    monkeypatch.setattr(ss, "enabled", ss.supported)
+    assert ss.supported(seq, channels, states)
+
+    def both(x, delta, a, bm, cm, d, dy):
+        y, vjp = jax.vjp(ss.selective_scan, x, delta, a, bm, cm, d)
+        return (y,) + vjp(dy)
+
+    f32 = jnp.float32
+    chip(both, ((batch, seq, channels), dtype), ((batch, seq, channels), f32),
+         ((channels, states), f32), ((batch, seq, states), dtype),
+         ((batch, seq, states), dtype), ((channels,), f32),
+         ((batch, seq, channels), f32))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "window"])
+def test_padded_differential_attention_phi4flash_share(chip, monkeypatch,
+                                                       window, grad):
+    """The attention of ``phi4flash-pretrain-s8192``: 20 query heads of 64
+    over 5 key/value pairs whose values are 128 wide, one row of 8192,
+    through ``causal_flash_qkv`` at head_dim 128 with q and k zero-padded
+    (``models/phi4flash.py::softmax_heads``): the tiled causal kernels on
+    layers F and C, the band regime on the S layers."""
+    from paddle_tpu.framework import flags
+    from paddle_tpu.models.phi4flash import softmax_heads
+
+    monkeypatch.setitem(flags._REGISTRY, "FLAGS_use_packed_attention", True)
+    fwd = lambda q, k, v: softmax_heads(q, k, v, window)
+    fn = fwd if not grad else jax.grad(
+        lambda *t: fwd(*t).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    chip(fn, ((1, 20, 8192, 64), BF16), ((1, 8192, 640), BF16),
+         ((1, 8192, 640), BF16))
 
 
 @pytest.mark.parametrize("tokens,experts,k", [

@@ -263,6 +263,17 @@ def _ssd_scan():
              _sds((1, 2, 128, 1, 2, 64)), _sds((1, 2), f32)))
 
 
+def _selective_scan():
+    from paddle_tpu.ops.pallas import selective_scan as ss
+
+    f32 = jnp.float32
+    f = lambda x, dl, a, b, c, d: ss._scan_kernels(x, dl, a, b, c, d, 64).sum()
+    return (jax.grad(f),
+            (_sds((1, 128, 256)), _sds((1, 128, 256), f32),
+             _sds((256, 16), f32), _sds((1, 128, 16)), _sds((1, 128, 16)),
+             _sds((256,), f32)))
+
+
 def _topk_mask():
     from paddle_tpu.ops.pallas import topk_mask
 
@@ -273,6 +284,7 @@ def _topk_mask():
 @pytest.mark.parametrize("recipe,expected", [
     (_topk_mask, ["topk_mask"]),
     (_ssd_scan, ["ssd_scan_bwd", "ssd_scan_fwd"]),
+    (_selective_scan, ["selective_scan_bwd", "selective_scan_fwd"]),
     (lambda: _causal(256), ["causal_flash_bwd", "causal_flash_fwd"]),
     (lambda: _causal(1024), ["causal_flash_bwd", "causal_flash_fwd_row"]),
     (lambda: _causal(2048),
@@ -290,7 +302,7 @@ def _topk_mask():
     (lambda: _paged_slab(3), ["paged_attention_verify"]),
     (_grouped, ["grouped_matmul"]),
     (_quant, ["quant_matmul"]),
-], ids=["topk_mask", "ssd_scan", "causal_flash-s256", "causal_flash-s1024", "causal_flash-s2048",
+], ids=["topk_mask", "ssd_scan", "selective_scan", "causal_flash-s256", "causal_flash-s1024", "causal_flash-s2048",
         "causal_flash-fwd_tiled", "window_flash-s1024", "flash_attention-s256",
         "flash_attention-s2048", "decode_attention",
         "decode_attention_slab", "paged_attention", "paged_attention_slab",
@@ -309,7 +321,7 @@ def test_no_pallas_call_site_is_without_a_name():
         src = open(path).read()
         sites += len(re.findall(r"pl\.pallas_call\(", src))
         named += len(re.findall(r"^\s+name=", src, re.M))
-    assert sites == named == 20
+    assert sites == named == 22
 
 
 # -------------------------------------------------------------- host side

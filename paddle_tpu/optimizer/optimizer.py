@@ -29,6 +29,17 @@ from .lr import LRScheduler
 __all__ = ["Optimizer", "SGD", "Momentum", "Adam", "AdamW", "Adagrad", "RMSProp", "Lamb"]
 
 
+# Elements of a leaf from which its update stands apart from the product
+# that makes its gradient (``apply_gradients_tree``). Measured on a TPU v5e
+# (PERF.md section 6, PR 36), product + update, fused -> apart: 4.2 M
+# elements ([1024, 4096]) 0.73 -> 0.64 ms but 0.2 ms of exposed copies of
+# the float32 state beside it, a loss of 1.1% of GPT-2 medium's step;
+# 6.6 M ([2560, 2560]) 1.17 -> 0.89; 26 M ([2560, 10240]) 4.49 -> 3.72;
+# 67 M ([4096, 16384]) 21.75 -> 15.42. The break-even lies between the
+# first two.
+_UPDATE_APART_FROM = 5 * 2**20
+
+
 def _is_low_precision(dt):
     return np.dtype(dt) in (np.dtype(dtypes.float16), np.dtype(dtypes.bfloat16))
 
@@ -169,6 +180,15 @@ class Optimizer:
         # the compiled program (metadata only)
         with jax.named_scope("optimizer"):
             for p, g, st, m in zip(flat_p, flat_g, flat_s, flat_m):
+                if g.ndim >= 2 and g.size > _UPDATE_APART_FROM:
+                    # a large matrix's gradient is a product's output: it
+                    # reaches the update as a finished array, or the
+                    # compiler folds the update (three float32 operands,
+                    # four outputs a tile) into the product's epilogue,
+                    # which halves the product's tile. One call a leaf: a
+                    # barrier over the tree keeps every gradient alive
+                    # until the last is made.
+                    g = jax.lax.optimization_barrier(g)
                 np_, ns_ = per_param(p, g, dict(st), m)
                 new_p.append(np_)
                 new_s.append(ns_)

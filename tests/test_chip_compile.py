@@ -307,21 +307,31 @@ def test_topk_mask(chip, monkeypatch, tokens, experts, k):
     chip(lambda v: tm.topk_mask(v, k), ((tokens, experts), jnp.float32))
 
 
+def _computations(text):
+    """({name: lines}, the entry's name) of a compiled program's text."""
+    import re
+
+    comps, name, entry = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+        elif name:
+            comps[name].append(line)
+    return comps, entry
+
+
 def _loop_bodies(text):
     """{name: lines} of the computations the compiled text's ``while``
     instructions name as their bodies."""
     import re
 
     bodies = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
-    found, name = {}, None
-    for line in text.splitlines():
-        head = re.match(r"%?([\w.\-]+) \(.*\{\s*$", line)
-        if head:
-            name = head.group(1) if head.group(1) in bodies else None
-        elif name:
-            found.setdefault(name, []).append(line)
-    assert set(found) == bodies
-    return found
+    comps, _ = _computations(text)
+    assert bodies <= set(comps)
+    return {name: comps[name] for name in bodies}
 
 
 @pytest.mark.parametrize("layer", ["laguna", "latent"])
@@ -384,6 +394,64 @@ def test_an_expert_layer_walks_its_rows_in_place_in_one_buffers_memory(
     assert len(walks) >= 4
     for name, lines in walks.items():
         assert not any(" copy(" in line for line in lines), name
+
+
+def _entry_fusions(text):
+    """[(output shape without layouts, called computation's lines)] of the
+    fusions of the compiled text's entry computation."""
+    import re
+
+    comps, entry = _computations(text)
+    found = []
+    for line in comps[entry]:
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) fusion\(.*"
+                     r"calls=%?([\w.\-]+)", line)
+        if m:
+            found.append((re.sub(r"\{[^}]*\}", "", m.group(1)),
+                          comps[m.group(2)]))
+    return found
+
+
+def test_a_weight_gradient_and_its_adamw_update_stand_apart(
+        one_chip, no_persistent_cache):
+    """The gate-and-up weight gradient of ``phi4flash-pretrain-s8192``
+    (``x^T dy`` over 8192 rows into a bfloat16 ``[2560, 10240]`` leaf) with
+    ``AdamW.apply_gradients_tree`` on a float32 master behind it. The
+    gradient reaches the update as a finished array: no fusion holds both
+    the product and the update's outputs (leaf, master, two moments). Folded
+    into the product's epilogue, three float32 operands and four outputs a
+    tile share fast memory with it, the tile halves, and on the chip the
+    product ran at half its rate (PERF.md section 6, PR 36). The state is
+    updated in place and nothing is kept but the one gradient."""
+    from paddle_tpu import optimizer
+
+    rows, k, n = 8192, 2560, 10240
+    opt = optimizer.AdamW(learning_rate=3e-4, weight_decay=0.01,
+                          multi_precision=True)
+
+    def step(params, state, x, dy, step_no):
+        grads = jax.vjp(lambda p: {"y": jnp.dot(x, p["w"])}, params)[1](
+            {"y": dy})[0]
+        return opt.apply_gradients_tree(params, grads, state, 3e-4, step_no)
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    leaf = sds((k, n), jnp.float32)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        {"w": sds((k, n), BF16)},
+        {"w": {"master": leaf, "moment1": leaf, "moment2": leaf}},
+        sds((rows, k), BF16), sds((rows, n), BF16),
+        sds((), jnp.int32)).compile()
+    update = f"(bf16[{k},{n}], " + ", ".join([f"f32[{k},{n}]"] * 3) + ")"
+    is_product = lambda lines: any(
+        " convolution(" in l or " dot(" in l for l in lines)
+    fusions = _entry_fusions(compiled.as_text())
+    assert [shape for shape, lines in fusions if is_product(lines)] \
+        == [f"bf16[{k},{n}]"]
+    assert [is_product(lines) for shape, lines in fusions
+            if shape == update] == [False]
+    # the compiler's figure read when this was written: one gradient
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.01 * k * n * 2
 
 
 @pytest.mark.parametrize("batch,seq", [(1, 2048), (8, 1024), (8, 128)])

@@ -1,10 +1,15 @@
 """Optimizer + LR scheduler tests (reference: test/legacy_test/test_adamw_op.py,
 test_lr_scheduler.py patterns — convergence + analytic single-step checks)."""
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import nn, optimizer
+from paddle_tpu.optimizer import optimizer as optimizer_module
 
 
 def _converges(opt_cls, lr=0.1, steps=120, **kw):
@@ -144,3 +149,168 @@ class TestLRSchedulers:
         assert abs(opt.get_lr() - 0.1) < 1e-8
         sched.step()
         assert abs(opt.get_lr() - 0.01) < 1e-8
+
+
+# ------------------------------------------ the functional update, compiled
+def _tree():
+    """(x, dy, params): bfloat16 matrices whose gradients are products made
+    in the step, a stack of matrices, vectors of both types and a scalar."""
+    rng = np.random.default_rng(7)
+    bf = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
+    params = {"w": bf(16, 24), "stack": bf(3, 8, 8), "bias": bf(24),
+              "scale": jnp.asarray(rng.standard_normal(16), jnp.float32),
+              "temperature": jnp.float32(0.7)}
+    return bf(32, 16), bf(32, 24), params
+
+
+def _grads(x, dy, params):
+    """The weight gradient as the backward pass makes it (``x^T dy`` rounded
+    to the leaf's type); the others from the leaves themselves."""
+    g = jax.tree_util.tree_map(lambda p: (p * p).astype(p.dtype), params)
+    return dict(g, w=jnp.dot(x.T, dy))
+
+
+def _plain_apply(opt, params, grads, state, lr, step, mask=None):
+    """The update as ``apply_gradients_tree`` stood before PR 36, frozen:
+    every leaf's rule read straight off the gradient, nothing between."""
+    new_p, new_s = {}, {}
+    for k, p in params.items():
+        st = dict(state[k])
+        master = st.pop("master", None)
+        pf = master if master is not None else p.astype(jnp.float32)
+        wd = opt._weight_decay if mask is None or mask[k] else 0.0
+        pf, st = opt._update_rule(pf, grads[k].astype(jnp.float32), st, lr,
+                                  step, wd)
+        new_s[k] = dict(st, master=pf) if master is not None else st
+        new_p[k] = pf.astype(p.dtype)
+    return new_p, new_s
+
+
+OPTIMIZERS = pytest.mark.parametrize("make", [
+    lambda: optimizer.AdamW(learning_rate=1e-2, weight_decay=0.01),
+    lambda: optimizer.Adam(learning_rate=1e-2, weight_decay=0.01),
+    lambda: optimizer.Momentum(learning_rate=1e-2, weight_decay=0.01),
+    lambda: optimizer.SGD(learning_rate=1e-2)],
+    ids=["adamw", "adam", "momentum", "sgd"])
+
+
+@pytest.fixture
+def small_leaves_apart(monkeypatch):
+    """The rule's size brought down to the tests' leaves."""
+    monkeypatch.setattr(optimizer_module, "_UPDATE_APART_FROM", 100)
+
+
+class TestApplyGradientsTree:
+    @OPTIMIZERS
+    @pytest.mark.parametrize("masked", [False, True], ids=["decay", "mask"])
+    def test_numbers_are_the_plain_forms_bit_for_bit(self, make, masked,
+                                                     small_leaves_apart):
+        """Jitted with donated arguments and a traced ``step``, three steps
+        running: leaves, masters and moments equal the frozen form's."""
+        opt = make()
+        x, dy, params = _tree()
+        mask = {k: v.ndim >= 2 for k, v in params.items()} if masked else None
+
+        # the gradients come in finished, as the backward pass of another
+        # call would leave them: inside ONE compiled function the plain
+        # form's round trip through the leaf's type is the compiler's to
+        # drop (excess precision), and then it is the plain form that moves
+        grads = jax.jit(_grads)(x, dy, params)
+
+        def three_steps(apply):
+            fn = jax.jit(lambda params, state, grads, n: apply(
+                params, grads, state, 1e-2, n, mask), donate_argnums=(0, 1))
+            p = jax.tree_util.tree_map(jnp.copy, params)
+            s = opt.init_state_tree(p)
+            for n in (1, 2, 3):
+                p, s = fn(p, s, grads, jnp.int32(n))
+            return p, s
+
+        got = three_steps(opt.apply_gradients_tree)
+        want = three_steps(functools.partial(_plain_apply, opt))
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+        assert got[1]["w"]["master"].dtype == jnp.float32
+        assert not np.array_equal(np.asarray(got[0]["w"], np.float32),
+                                  np.asarray(params["w"], np.float32))
+
+    @OPTIMIZERS
+    def test_each_large_matrix_gradient_reaches_its_update_alone(
+            self, make, small_leaves_apart):
+        """One ``optimization_barrier`` a leaf of two and more dimensions
+        over the size the rule names, each over that one gradient (a
+        barrier over several keeps them all alive until the last is made),
+        under the scope ``optimizer``; a vector's and a scalar's gradient
+        pass none, however large."""
+        opt = make()
+        x, dy, params = _tree()
+        params["long"] = jnp.ones(4096, jnp.bfloat16)
+        state = opt.init_state_tree(params)
+        jaxpr = jax.make_jaxpr(lambda p, s, n: opt.apply_gradients_tree(
+            p, _grads(x, dy, p), s, 1e-2, n))(params, state, jnp.int32(1))
+        barriers = [e for e in jaxpr.jaxpr.eqns
+                    if e.primitive.name == "optimization_barrier"]
+        assert sorted(e.invars[0].aval.shape for e in barriers) == sorted(
+            v.shape for v in params.values() if v.ndim >= 2)
+        for e in barriers:
+            assert len(e.invars) == 1 and len(e.outvars) == 1
+            assert e.outvars[0].aval.dtype == jnp.bfloat16  # the leaf's type
+            assert str(e.source_info.name_stack) == "optimizer"
+
+    def test_the_rule_reads_the_leafs_size(self):
+        """At the sizes the break-even was measured between (PERF.md section
+        6, PR 36): GPT-2 medium's ``[1024, 4096]`` keeps its update in the
+        product's fusion, ``[2560, 2560]`` and everything larger stand
+        apart. Shapes only: nothing runs."""
+        opt = optimizer.AdamW(learning_rate=1e-2)
+        shapes = {"fc": (1024, 4096), "out": (2560, 2560),
+                  "gate_up": (2560, 10240), "experts": (8, 2688, 1024),
+                  "norm": (2560,)}
+        params = {k: jax.ShapeDtypeStruct(v, jnp.bfloat16)
+                  for k, v in shapes.items()}
+        state = jax.eval_shape(opt.init_state_tree, params)
+        jaxpr = jax.make_jaxpr(lambda p, g, s: opt.apply_gradients_tree(
+            p, g, s, 1e-2, 1))(params, params, state)
+        assert sorted(e.invars[0].aval.shape for e in jaxpr.jaxpr.eqns
+                      if e.primitive.name == "optimization_barrier") \
+            == sorted([shapes["out"], shapes["gate_up"], shapes["experts"]])
+
+    def test_the_barrier_reaches_the_lowered_step(self, small_leaves_apart):
+        opt = optimizer.AdamW(learning_rate=1e-2)
+        x, dy, params = _tree()
+        state = opt.init_state_tree(params)
+        txt = jax.jit(lambda p, s: opt.apply_gradients_tree(
+            p, _grads(x, dy, p), s, 1e-2, 1)).lower(params, state).as_text()
+        lines = [l for l in txt.splitlines() if "optimization_barrier" in l]
+        assert len(lines) == 2
+        assert all(l.count("tensor<") == 1 and l.count("%") == 2
+                   for l in lines)  # one operand, one result
+
+    @pytest.mark.parametrize("wrt", ["params", "inputs", "lr"])
+    def test_grad_passes_through_an_update(self, wrt, small_leaves_apart):
+        """``jax.grad`` of a function of the UPDATED leaves (a meta-step, or
+        a pipeline stage differentiated after its update) sees through the
+        barrier: it equals the plain form's gradient."""
+        opt = optimizer.AdamW(learning_rate=1e-2)
+        x, dy, params = _tree()
+        params = jax.tree_util.tree_map(
+            lambda p: p.astype(jnp.float32), params)
+        x, dy = x.astype(jnp.float32), dy.astype(jnp.float32)
+        state = opt.init_state_tree(params)
+
+        def after(apply, params, x, lr):
+            new_p, _ = apply(params, _grads(x, dy, params), state, lr, 1)
+            return sum(jnp.sum(jnp.square(v)) for v in new_p.values())
+
+        argnum = {"params": 0, "inputs": 1, "lr": 2}[wrt]
+        got, want = (jax.grad(functools.partial(after, apply), argnum)(
+            params, x, jnp.float32(1e-2))
+            for apply in (opt.apply_gradients_tree,
+                          functools.partial(_plain_apply, opt)))
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert np.all(np.isfinite(a)) and np.any(np.asarray(a) != 0)
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -40,6 +40,10 @@ from .framework import (  # noqa: F401
     set_flags,
 )
 from .framework.param_attr import ParamAttr  # noqa: F401
+from .framework.compile_cache import register_compile_listeners
+
+register_compile_listeners()  # every trace / lowering / compile jax does
+del register_compile_listeners
 from .ops import *  # noqa: F401,F403
 from .ops import creation, linalg, manipulation, math  # noqa: F401
 from .serialization import load, save  # noqa: F401

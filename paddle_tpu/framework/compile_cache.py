@@ -39,11 +39,23 @@ _KERNEL_CHOICES_LOCK = threading.Lock()
 
 # ------------------------------------------------- compile-path telemetry
 # "Why is my server recompiling" must be answerable from metrics alone
-# (ISSUE 3): the jit entry (StaticFunction) reports every program-cache
-# hit, every compile's wall time, and attributes each RETRACE to the
-# shape/dtype signature that triggered it. The metric objects are built
-# lazily so importing compile_cache never pulls in the observability
-# package (and the first record costs one dict build, the rest a lookup).
+# (ISSUE 3). Two sources feed one block of metrics:
+#
+# * jax's own monitoring events (``register_compile_listeners``, called
+#   when ``paddle_tpu`` is imported): every program jax builds, whatever
+#   its entry (``jax.jit`` over ``functional_call``, an eager op, the
+#   optimizer's state init, a serving program, ``StaticFunction``), reports
+#   its jaxpr trace, its lowering to MLIR and its backend compile or
+#   persistent-cache load: ``paddle_jit_trace_seconds``,
+#   ``paddle_jit_lower_seconds``, ``paddle_jit_backend_seconds``,
+#   ``paddle_jit_backend_compiles_total``, ``paddle_jit_cache_loads_total``.
+# * the jit entry (StaticFunction) reports every program-cache hit, every
+#   first call's wall time, and attributes each RETRACE to the shape/dtype
+#   signature that triggered it.
+#
+# The metric objects are built lazily so importing compile_cache never
+# pulls in the observability package (and the first record costs one dict
+# build, the rest a lookup).
 
 _JIT_METRICS: Optional[Dict[str, Any]] = None
 
@@ -77,8 +89,98 @@ def _jit_metrics() -> Dict[str, Any]:
                 "paddle_kernel_choice_misses_total",
                 "kernel-geometry choices computed+pinned, by namespace",
                 labelnames=("kind",)),
+            "trace_seconds": histogram(
+                "paddle_jit_trace_seconds",
+                "jaxpr trace of any program jax builds, less the phases "
+                "nested in it"),
+            "lower_seconds": histogram(
+                "paddle_jit_lower_seconds",
+                "jaxpr -> MLIR lowering of any program, less the phases "
+                "nested in it"),
+            "backend_seconds": histogram(
+                "paddle_jit_backend_seconds",
+                "backend compile or persistent-cache load of any program"),
+            "backend_compiles": counter(
+                "paddle_jit_backend_compiles_total",
+                "programs the backend compiled (no persistent-cache hit)"),
+            "cache_loads": counter(
+                "paddle_jit_cache_loads_total",
+                "programs loaded from the persistent compilation cache"),
         }
     return _JIT_METRICS
+
+
+# jax's names (jax/_src/dispatch.py, compiler.py). A phase opens with a
+# scalar event (its start time) and closes with its duration; a cache hit
+# is reported inside the backend phase that it ends.
+_PHASE_METRIC = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_seconds",
+    "/jax/core/compile/backend_compile_duration": "backend_seconds",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class _OpenPhases(threading.local):
+    """[seconds of the phases nested in it, loaded from the cache] of each
+    phase open on this thread, innermost last. A phase nests in another
+    where a trace runs an eager op (which traces, lowers and compiles a
+    program of its own) or a lowering traces a nested jit; each histogram
+    takes a phase's own time only, so the three never count a second
+    twice and their sums add up to the wall time the phases cover."""
+
+    def __init__(self):
+        self.stack = []
+
+
+_OPEN = _OpenPhases()
+
+
+def _phase_opens(event, start_time, **kwargs):
+    if event in _PHASE_METRIC:
+        _OPEN.stack.append([0.0, False])
+
+
+def _cache_event(event, **kwargs):
+    if event == _CACHE_HIT_EVENT and _OPEN.stack:
+        _OPEN.stack[-1][1] = True
+
+
+def _phase_closes(event, seconds, **kwargs):
+    name = _PHASE_METRIC.get(event)
+    if name is None:
+        return
+    stack = _OPEN.stack
+    # a phase that opened before the listeners were registered has no entry
+    nested, loaded = stack.pop() if stack else (0.0, False)
+    if stack:
+        stack[-1][0] += seconds
+    _JIT_METRICS[name].observe(seconds - nested)
+    if name == "backend_seconds":
+        _JIT_METRICS["cache_loads" if loaded else "backend_compiles"].inc()
+
+
+for _fn in (_phase_opens, _cache_event, _phase_closes):
+    _fn._paddle_compile_listener = True
+del _fn
+
+
+def register_compile_listeners() -> None:
+    """Hear every trace, lowering and backend compile or cache load jax
+    does in this process (module comment above). Called when
+    ``paddle_tpu`` is imported; idempotent across calls and re-imports of
+    this module. A warm program fires no event, so a steady loop pays
+    nothing; an event that is not a compile phase costs one dict lookup."""
+    import jax.monitoring as monitoring
+    from jax._src import monitoring as registered  # the lists are private
+
+    if any(getattr(f, "_paddle_compile_listener", False)
+           for f in registered.get_event_duration_listeners()):
+        return
+    _jit_metrics()
+    monitoring.register_scalar_listener(_phase_opens)
+    monitoring.register_event_listener(_cache_event)
+    monitoring.register_event_duration_secs_listener(_phase_closes)
 
 
 def ensure_compile_metrics() -> None:

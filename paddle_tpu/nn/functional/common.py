@@ -48,6 +48,69 @@ def dropout(x, p=0.5, axis=None, training=True, mode="upscale_in_train", name=No
     return apply_op(fn, x)
 
 
+# The chip's scatter-add walks a row slowly where the row is wider than this
+# and its width is 5, 7, ... times a power of two. 8192 rows of bfloat16 onto
+# 25 088, ms: 2560 wide 10.19, 3584 5.58, 5120 44.46; against 1280 0.75, 1536
+# 1.23, 2048 1.67, 3072 2.46, 4096 2.06, and 512 0.25 (PERF.md section 6,
+# PR 38: the break-even). The gradient of a lookup into such a table is
+# summed in power-of-two groups of columns, one scatter-add each
+# (``_take_rows_apart``).
+_SLOW_ROW_LANES = 2048
+# rows of the sum padded to whole tiles: the slice and cast that follow are
+# then fused into their reader at its own rate (PR 38)
+_ROW_TILE = 128
+
+
+def _lane_groups(width: int):
+    """Columns of each scatter-add of a lookup's gradient summed apart, the
+    width's powers of two widest first (2560 -> (2048, 512)), or () where
+    one scatter-add of the whole row is fast."""
+    if width <= _SLOW_ROW_LANES or width // (width & -width) < 5:
+        return ()
+    return tuple(1 << b for b in reversed(range(width.bit_length()))
+                 if width >> b & 1)
+
+
+@jax.custom_vjp
+def _take_rows_apart(w, idx):
+    """``jnp.take(w, idx, axis=0)`` whose transpose sums the cotangent's rows
+    by id in float32, one scatter-add a group of ``_lane_groups`` columns
+    into arrays of its own, rows padded to whole tiles, and hands back the
+    table's gradient as one array in w's type."""
+    return jnp.take(w, idx, axis=0)
+
+
+def _take_rows_apart_fwd(w, idx):
+    # a [rows, 0] array carries the table's row count and type, no bytes
+    return jnp.take(w, idx, axis=0), (idx, jnp.zeros((w.shape[0], 0), w.dtype))
+
+
+def _take_rows_apart_bwd(res, ct):
+    idx, like = res
+    rows, width = like.shape[0], ct.shape[-1]
+    held = rows + -rows % _ROW_TILE
+    with jax.named_scope("rows_apart"):
+        # ids as jnp.take's transpose reads them: a negative id wraps once,
+        # one still out of range adds nothing (sent past the padded rows)
+        flat = idx.reshape(-1)
+        flat = jnp.where(flat < 0, flat + rows, flat)
+        flat = jnp.where((flat >= 0) & (flat < rows), flat, held)
+        # a narrow type is summed in float32 and rounded once, as the chip's
+        # own scatter-add of it does
+        wide = jnp.promote_types(like.dtype, jnp.float32)
+        ct = ct.reshape(-1, width).astype(wide)
+        groups, at = [], 0
+        for lanes in _lane_groups(width):
+            groups.append(jnp.zeros((held, lanes), wide).at[flat].add(
+                ct[:, at:at + lanes], mode="drop"))
+            at += lanes
+        summed = jnp.concatenate(groups, axis=1)
+        return summed[:rows].astype(like.dtype), None
+
+
+_take_rows_apart.defvjp(_take_rows_apart_fwd, _take_rows_apart_bwd)
+
+
 def embedding(x, weight, padding_idx=None, sparse=False, name=None):
     """Lookup rows of weight [vocab, dim] (reference: phi embedding kernel;
     vocab-parallel variant lives in distributed.fleet.meta_parallel)."""
@@ -55,7 +118,10 @@ def embedding(x, weight, padding_idx=None, sparse=False, name=None):
     weight = _t(weight)
 
     def fn(w):
-        out = jnp.take(w, idx, axis=0)
+        if _lane_groups(w.shape[-1]):
+            out = _take_rows_apart(w, idx)
+        else:
+            out = jnp.take(w, idx, axis=0)
         if padding_idx is not None and padding_idx >= 0:
             mask = (idx == padding_idx)[..., None]
             out = jnp.where(mask, jnp.zeros((), out.dtype), out)

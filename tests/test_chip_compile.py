@@ -454,6 +454,54 @@ def test_a_weight_gradient_and_its_adamw_update_stand_apart(
     assert compiled.memory_analysis().temp_size_in_bytes <= 1.01 * k * n * 2
 
 
+@pytest.mark.parametrize("rows,width,tokens", [(25008, 2560, 8192),
+                                               (50304, 1024, 12288)],
+                         ids=["phi4flash", "gpt2m"])
+def test_the_tied_lookups_gradient(one_chip, no_persistent_cache, rows, width,
+                                   tokens):
+    """A tied table's gradient: the head's weight gradient plus the lookup's
+    transpose. At ``phi4flash-pretrain-s8192``'s ``[25008, 2560]`` the
+    lookup's rows are summed apart (scope ``rows_apart``): one float32
+    scatter-add of 2048 columns and one of 512, onto rows padded to whole
+    tiles, where the chip's one scatter of 2560-wide rows took 10.5 ms
+    (PERF.md section 6, PR 38). At ``gpt2m-pretrain``'s
+    ``[50304, 1024]``, the parent's form: one bfloat16 scatter-add in place
+    onto the head's product."""
+    import re
+
+    import paddle_tpu.nn.functional as F
+
+    def grad(table, hidden, ids, ct, dlogits):
+        lookup_and_head = lambda t: (F.embedding(ids, t)._data,
+                                     jnp.dot(hidden, t.T))
+        return jax.vjp(lookup_and_head, table)[1]((ct, dlogits))[0]
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    text = jax.jit(grad).lower(
+        sds((rows, width), BF16), sds((tokens, width), BF16),
+        sds((1, tokens), jnp.int32), sds((1, tokens, width), BF16),
+        sds((tokens, rows), BF16)).compile().as_text()
+    comps, entry = _computations(text)
+    made_by = {line.split(" = ")[0].split()[-1]: line for line in comps[entry]
+               if " = " in line}
+    scatters = [line for line in comps[entry]
+                if " fusion(" in line and "kind=kCustom" in line
+                and "/scatter-add" in line]
+    shapes = sorted(re.sub(r"\{[^}]*\}", "", line.split(" = ")[1].split()[0])
+                    for line in scatters)
+    if width == 2560:
+        assert "rows_apart" in text
+        assert shapes == ["f32[25088,2048]", "f32[25088,512]"]
+    else:
+        assert "rows_apart" not in text
+        [line] = scatters
+        assert shapes == [f"bf16[{rows},{width}]"]
+        onto = re.search(r" fusion\((%[\w.\-]+)", line).group(1)
+        assert "dot_general" in made_by[onto]
+        assert '"aliasing_operands":{"lists":[{"indices":["0"' in line
+
+
 @pytest.mark.parametrize("batch,seq", [(1, 2048), (8, 1024), (8, 128)])
 def test_flash_attention_llama_prefill(chip, batch, seq):
     """The serve phase's prefill: ``F.flash_attention`` at head_dim 128,

@@ -37,20 +37,28 @@ rows of 128 channels (2560 channels: rows 0-7, 8-15 and the half group
   q delta`` and ``d delta += sum_n q A``; ``s = sum_n G B`` gives ``dx = s
   delta + D dy`` and ``d delta += s x``; ``dD += dy x``; ``dh <- exp(delta
   A) G``. ``dB_t[n] = sum_c G (delta x)`` and ``dC_t[n] = sum_c dy h_t`` are
-  sums over CHANNELS, across registers' lanes: the products are kept whole
-  in scratch (summed over the groups) and one matrix product with a row of
-  ones a chunk sums their lanes; the 8 sublanes are summed outside.
+  sums over CHANNELS, across registers' lanes: pass 2 stores each group's
+  products in a slab of scratch of its own (nothing read back), and once a
+  chunk they are summed in float32, ``_SUM_ROWS`` rows at a time: the slabs
+  added in registers, each register's sublane i added to sublane i + 4 and
+  two registers packed into one, the lanes of 16 such registers summed by a
+  transpose and a sum over sublanes. Four numbers a (position, state) leave
+  the kernel and are summed outside.
 
 Everything inside is float32: x is raised on load, ``delta`` comes in
 float32, outputs are float32 (the caller rounds). The recurrence is
 therefore the reference's to rounding order.
 
 VMEM per program at 2560 channels, 16 states, chunk 64: forward 2.4 MB
-(blocks double-buffered), backward 19 MB (``_footprint``: the kept states
-4.3 MB, the two product arrays 4 MB each, ``dh`` 0.2 MB, blocks 6 MB), so
-the backward raises the scoped limit to 48 MiB of the chip's 128.
+(blocks double-buffered), backward 39 MB (``_footprint``: the kept states
+4.3 MB, the product slabs 4.2 MB a group and array, 25 MB for 3 groups,
+``dh`` 0.2 MB, blocks 9.6 MB); 62 MB at 5120 channels (5 groups), so the
+backward raises the scoped limit to 64 MiB of the chip's 128, and so does
+the forward, whose blocks pass the default 16 MiB at the widest channels
+``supported`` admits with few states.
 
 Constraints (``supported``): channels a multiple of 128, at most 16 states,
+``chunk x states`` a multiple of 32 (whole blocks of ``_SUM_ROWS``),
 ``_footprint`` under ``_VMEM_BUDGET``. Any length: the tail is padded with
 positions that neither decay nor add (``delta = 0``). Off the chip, and at
 shapes the kernels refuse, ``selective_scan`` runs the same recurrence as a
@@ -70,8 +78,9 @@ from jax.experimental.pallas import tpu as pltpu
 CHUNK = 64
 LANES, ROWS = 128, 8
 _MAX_STATES = 16
-_VMEM_LIMIT = 48 * 1024 * 1024
-_VMEM_BUDGET = 40 * 1024 * 1024
+_VMEM_LIMIT = 64 * 1024 * 1024
+_VMEM_BUDGET = 60 * 1024 * 1024
+_SUM_ROWS = 256  # rows of the product slabs summed over channels at a time
 
 
 def _interpret() -> bool:
@@ -127,10 +136,10 @@ def _bwd_kernel(b_ref, c_ref, x_ref, dl_ref, a_ref, d_ref, hin_ref, dy_ref,
         dh_scr[...] = jnp.zeros_like(dh_scr)
         da_ref[...] = jnp.zeros_like(da_ref)
         dd_ref[...] = jnp.zeros_like(dd_ref)
-
-    if n_rows < ROWS:  # rows of the product arrays the one group leaves unwritten
-        pb_scr[...] = jnp.zeros_like(pb_scr)
-        pc_scr[...] = jnp.zeros_like(pc_scr)
+        if n_rows % ROWS:  # the rows a half group never writes; scratch persists
+            last, zero = pb_scr.shape[0] - 1, jnp.zeros(pb_scr.shape[1:], f32)
+            pb_scr[last] = zero
+            pc_scr[last] = zero
 
     def kept(t, i, r):  # state i entering position t, rows [0, r)
         return (pl.ds(pl.multiple_of((t * states + i) * ROWS, ROWS), r),
@@ -177,15 +186,11 @@ def _bwd_kernel(b_ref, c_ref, x_ref, dl_ref, a_ref, d_ref, hin_ref, dy_ref,
                 new_da.append(da[i] + q * dl)
                 ddl = ddl + q * a[i]
                 s = s + grad * b_ref[0, 0, 0, t * states + i]
-                at = kept(t, i, r)
-                pb, pc = grad * dx, dy * hs_scr[kept(t + 1, i, r)]
-                # tpulint: disable=TPL301 -- g is the group's static index, not a traced value
-                if g:
-                    pb, pc = pb_scr[at] + pb, pc_scr[at] + pc
-                # tpulint: disable=TPL402 -- the products wait for the chunk's one sum over lanes
-                pb_scr[at] = pb
-                # tpulint: disable=TPL402 -- the products wait for the chunk's one sum over lanes
-                pc_scr[at] = pc
+                at = (g,) + kept(t, i, r)
+                # tpulint: disable=TPL402 -- the products wait for the chunk's sums over channels
+                pb_scr[at] = grad * dx
+                # tpulint: disable=TPL402 -- the products wait for the chunk's sums over channels
+                pc_scr[at] = dy * hs_scr[kept(t + 1, i, r)]
                 new_dh.append(decay * grad)
             # tpulint: disable=TPL402 -- the position's gradients are written from inside the walk
             dx_ref[0, t, rows] = s * dl + skip * dy
@@ -203,15 +208,28 @@ def _bwd_kernel(b_ref, c_ref, x_ref, dl_ref, a_ref, d_ref, hin_ref, dy_ref,
             da_ref[0, i, rows] = da_ref[0, i, rows] + da[i]
         dd_ref[0, rows] = dd_ref[0, rows] + dd
 
-    # the sums over channels: lanes by one product with ones, the 8 sublanes
-    # (and the batch's rows) outside
-    ones = jnp.ones((ROWS, LANES), f32)
-    lanes = lambda p: jax.lax.dot_general(
-        ones, p, (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=f32)[0:1]
-    db_ref[0, 0] = lanes(pb_scr[...])
-    dc_ref[0, 0] = lanes(pc_scr[...])
+    # the sums over channels, _SUM_ROWS rows (a register a row of 8) at a
+    # time: the groups' slabs added, sublanes i and i + 4 of each register
+    # added and two registers packed into one, the lanes of the packed block
+    # summed by a transpose and a sum over sublanes
+    pairs = (_SUM_ROWS // ROWS // 2, ROWS, LANES)
+    first = jax.lax.broadcasted_iota(jnp.int32, pairs, 1) < ROWS // 2
+
+    def block(k, _):
+        at = pl.ds(pl.multiple_of(k * _SUM_ROWS, _SUM_ROWS), _SUM_ROWS)
+        to = pl.ds(pl.multiple_of(k * _SUM_ROWS // 2, LANES), _SUM_ROWS // 2)
+        for scr, out in ((pb_scr, db_ref), (pc_scr, dc_ref)):
+            p = scr[0, at]
+            for g in range(1, scr.shape[0]):
+                p = p + scr[g, at]
+            p = p.reshape(pairs[0], 2, ROWS, LANES)
+            p = p + pltpu.roll(p, ROWS // 2, 2)
+            p = jnp.where(first, p[:, 0], p[:, 1]).reshape(-1, LANES)
+            # tpulint: disable=TPL402 -- each block's sums land in lanes of their own
+            out[0, 0, :, to] = jnp.sum(p.T, axis=0, keepdims=True)
+        return 0
+
+    jax.lax.fori_loop(0, pb_scr.shape[1] // _SUM_ROWS, block, 0, unroll=True)
 
 
 # ------------------------------------------------------------ pallas calls
@@ -254,7 +272,8 @@ def _fwd_traced(interpret, chunk, bm, cm, x, dl, a, d):
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((states, rows, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="selective_scan_fwd",
     )(bm, cm, x, dl, a, d)
@@ -267,9 +286,10 @@ def _bwd_traced(interpret, chunk, bm, cm, x, dl, a, d, entering, dy):
     f32 = jnp.float32
     back = lambda ci: nc - 1 - ci
     smem, wide, a_spec, d_spec, state = _specs(chunk, states, rows, back)
-    summed = pl.BlockSpec((1, 1, 1, chunk * states * ROWS),
-                          lambda bi, ci: (bi, back(ci), 0, 0))
     products = chunk * states * ROWS
+    groups = len(_groups(rows))
+    summed = pl.BlockSpec((1, 1, 1, products // 2),
+                          lambda bi, ci: (bi, back(ci), 0, 0))
     dx, ddl, db, dc, da, dd = pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk, states=states),
         grid=(b, nc),
@@ -280,25 +300,23 @@ def _bwd_traced(interpret, chunk, bm, cm, x, dl, a, d, entering, dy):
                    pl.BlockSpec((1, rows, LANES), lambda bi, ci: (bi, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, f32),
                    jax.ShapeDtypeStruct(x.shape, f32),
-                   jax.ShapeDtypeStruct((b, nc, 1, chunk * states * ROWS),
-                                        f32),
-                   jax.ShapeDtypeStruct((b, nc, 1, chunk * states * ROWS),
-                                        f32),
+                   jax.ShapeDtypeStruct((b, nc, 1, products // 2), f32),
+                   jax.ShapeDtypeStruct((b, nc, 1, products // 2), f32),
                    jax.ShapeDtypeStruct((b, states, rows, LANES), f32),
                    jax.ShapeDtypeStruct((b, rows, LANES), f32)],
         scratch_shapes=[pltpu.VMEM((states, rows, LANES), f32),
                         pltpu.VMEM((products + states * ROWS, LANES), f32),
-                        pltpu.VMEM((products, LANES), f32),
-                        pltpu.VMEM((products, LANES), f32)],
+                        pltpu.VMEM((groups, products, LANES), f32),
+                        pltpu.VMEM((groups, products, LANES), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="selective_scan_bwd",
     )(bm, cm, x, dl, a, d, entering, dy)
-    # [b, chunks, 1, chunk states 8] -> [b, s, states]: the sublanes' sum
+    # [b, chunks, 1, chunk states 4] -> [b, s, states]: the last sum
     by_state = lambda t: jnp.sum(
-        t.reshape(b, s, states, ROWS), axis=-1)
+        t.reshape(b, s, states, ROWS // 2), axis=-1)
     return (by_state(db), by_state(dc), dx, ddl, jnp.sum(da, axis=0),
             jnp.sum(dd, axis=0))
 
@@ -370,19 +388,22 @@ def _scan_lax(x, delta, a, bm, cm, d, chunk):
 def _footprint(chunk, channels, states):
     """Bytes of VMEM one program of the backward (the larger of the two)
     holds in blocks, each double-buffered, and in scratch."""
-    rows = -(-(channels // LANES) // ROWS) * ROWS
+    groups = -(-(channels // LANES) // ROWS)
+    rows = groups * ROWS
     wide = 4 * chunk * rows * LANES                  # x, delta, dy, dx, ddelta
     state = 4 * states * rows * LANES                # A, entering, dA, dh
-    kept = 4 * (chunk + 1) * states * ROWS * LANES   # states, two products
-    summed = 4 * ROWS * chunk * states * ROWS
-    return 2 * (5 * wide + 3 * state + 2 * summed) + state + 3 * kept
+    kept = 4 * (chunk + 1) * states * ROWS * LANES   # the states
+    slabs = 2 * groups * 4 * chunk * states * ROWS * LANES  # the products
+    summed = 4 * ROWS * chunk * states * ROWS // 2
+    return 2 * (5 * wide + 3 * state + 2 * summed) + state + kept + slabs
 
 
 def supported(seq: int, channels: int, states: int,
               chunk: int = CHUNK) -> bool:
     """Whether the kernels take a scan of ``channels`` channels with
     ``states`` numbers each (any ``seq``: the tail is padded)."""
-    if seq < 1 or channels % LANES or not 1 <= states <= _MAX_STATES:
+    if (seq < 1 or channels % LANES or not 1 <= states <= _MAX_STATES
+            or chunk * states * ROWS % _SUM_ROWS):
         return False
     return _footprint(chunk, channels, states) <= _VMEM_BUDGET
 

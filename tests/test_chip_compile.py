@@ -249,19 +249,24 @@ def test_ssd_scan_pair(chip, monkeypatch, batch, seq, heads, groups, chunk,
 
 @pytest.mark.parametrize("batch,seq,channels,states,dtype", [
     (1, 8192, 2560, 16, BF16), (1, 4096, 5120, 16, BF16),
-    (2, 1000, 1280, 8, BF16), (2, 1024, 2560, 16, jnp.float32)],
+    (2, 1000, 1280, 8, BF16), (2, 1024, 2560, 16, jnp.float32),
+    (1, 1024, 19456, 1, BF16)],
     ids=["phi4flash-share", "uncut-5120-channels", "ragged-10-rows-8-states",
-         "float32"])
+         "float32", "widest-supported-1-state"])
 def test_selective_scan_pair(chip, monkeypatch, batch, seq, channels, states,
                              dtype):
     """The Mamba-1 scan of ``phi4flash-pretrain-s8192`` (2560 channels of 16
     states, one row of 8192: groups of 8 + 8 + 4 rows of 128 channels),
     forward and backward; the uncut layer's 5120 channels, whose blocks and
     kept states must still fit the raised scoped limit; a length that is no
-    whole number of chunks over a half-filled last group; float32."""
+    whole number of chunks over a half-filled last group; float32; the
+    widest channel count ``supported`` admits (at one state), whose forward
+    blocks pass the default scoped limit."""
     ss = _mod("selective_scan")
     monkeypatch.setattr(ss, "enabled", ss.supported)
     assert ss.supported(seq, channels, states)
+    if states == 1:
+        assert not ss.supported(seq, channels + ss.LANES, states)
 
     def both(x, delta, a, bm, cm, d, dy):
         y, vjp = jax.vjp(ss.selective_scan, x, delta, a, bm, cm, d)

@@ -5,8 +5,8 @@ gradients.
 
 Tolerance 2e-5 of each array's largest value: everything is float32 on the
 CPU and the three differ in the order of sums only (the kernels sum ``dB``
-and ``dC`` over lanes by a product with ones, the loop by ``jnp.sum``); the
-worst reading was 5e-7. A wrong carry across a chunk's boundary, a state
+and ``dC`` over channels by slabs, sublanes and a transpose, the loop by
+``jnp.sum``); the worst reading was 5e-7. A wrong carry across a chunk's boundary, a state
 read one position off or a missing ``D x`` reads 1e-2 and more.
 """
 import jax
@@ -80,12 +80,20 @@ def test_value_and_six_gradients_against_the_recurrence(form, a_log, seq,
 
 
 @DECAYS
-def test_rows_that_do_not_fill_a_register_and_several_groups(kernels, a_log):
-    """1280 channels are 10 rows of 128: a whole group of 8 and one of 2 (the
-    cell's 2560 are 8 + 8 + 4); 8 states; one row of the batch."""
-    args, dy = operands(1, 24, 1280, 8, a_log, seed=3)
-    got = value_and_grads(lambda *t: ss.selective_scan(*t, chunk=8), args, dy)
-    close(got, value_and_grads(by_position, args, dy), "10 rows, 8 states")
+@pytest.mark.parametrize("batch,seq,channels,states,chunk", [
+    (1, 24, 1280, 8, 8), (2, 48, 2560, 16, 16)],
+    ids=["10-rows-8-states", "cell-20-rows-16-states"])
+def test_rows_that_do_not_fill_a_register_and_several_groups(
+        kernels, a_log, batch, seq, channels, states, chunk):
+    """1280 channels are 10 rows of 128: a whole group of 8 and one of 2;
+    the cell's 2560 are 8 + 8 + 4, each group's products in a slab of its
+    own, the half group's unwritten rows zeroed once a row of the batch (two
+    rows here) and the slabs summed across groups in each of three chunks."""
+    args, dy = operands(batch, seq, channels, states, a_log, seed=3)
+    got = value_and_grads(lambda *t: ss.selective_scan(*t, chunk=chunk),
+                          args, dy)
+    close(got, value_and_grads(by_position, args, dy),
+          f"{channels // ss.LANES} rows, {states} states")
 
 
 def test_long_memory_is_carried_across_every_chunk(kernels):
@@ -132,6 +140,19 @@ def test_the_two_forms_keep_the_same_chunk_boundaries(monkeypatch):
 def test_supported_says_which_shapes_the_kernels_take():
     assert ss.supported(8192, 2560, 16)         # the cell's
     assert ss.supported(100, 5120, 16)          # the uncut layer, any length
+    # a group of 8 rows of 128 channels adds its blocks (x, delta, dy, dx,
+    # ddelta, A, the entering state, dA; double-buffered) and dh, and one
+    # slab of products for dB and one for dC: 4 MiB each at chunk 64
+    fp = lambda c: ss._footprint(ss.CHUNK, c, 16)
+    rows = lambda n: 4 * n * ss.ROWS * ss.LANES
+    slab = rows(ss.CHUNK * 16)
+    group = 2 * (5 * rows(ss.CHUNK) + 3 * rows(16)) + rows(16) + 2 * slab
+    assert fp(2048) - fp(1024) == fp(3072) - fp(2048) == group
+    assert fp(2560) == fp(3072)                 # the half group: a whole slab
+    assert fp(2560) < fp(5120) <= ss._VMEM_BUDGET
+    assert ss._footprint(ss.CHUNK, 5248, 16) > ss._VMEM_BUDGET
+    assert not ss.supported(8192, 5248, 16)     # six groups' slabs overflow
+    assert not ss.supported(64, 256, 1, chunk=8)    # no whole block to sum
     assert not ss.supported(8192, 2500, 16)     # channels off the lanes
     assert not ss.supported(8192, 2560, 32)     # more states than registers
     assert not ss.supported(8192, 128 * 1024, 16)   # past fast memory
